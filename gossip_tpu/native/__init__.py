@@ -5,55 +5,70 @@ Built on demand by :func:`load_eventsim` itself — a single ``g++ -O2
 Python<->C boundary is a flat C API).  ``load_eventsim()`` returns the
 shared library handle or None when no compiler is available — callers fall
 back to the pure-Python implementation.
+
+Every build output is named by a hash of its source and compile flags
+(:func:`built_path`), so a binary is trusted only when it was built from
+the source as it stands — never on its mtime, which a copied tree does
+not preserve.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
 from typing import Optional
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_DIR, "libeventsim.so")
 _SRC = os.path.join(_DIR, "eventsim.cpp")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
+_CXX = ["g++", "-O2", "-std=c++17"]
 
-def build_native(src: str, out: str, shared: bool = True) -> bool:
-    """One g++ invocation: compile ``src`` to ``out`` (shared lib or
-    binary) via a per-process temp path + os.replace, so concurrent
-    builders (parallel pytest workers, two CLIs on a fresh checkout) can
-    never interleave writes into a torn artifact.  Shared by the event
-    sim (.so) and the native router (binary)."""
+
+def _flags(shared: bool):
+    return _CXX + (["-shared", "-fPIC"] if shared else [])
+
+
+def built_path(src: str, shared: bool = True) -> str:
+    """Where the build of ``src`` lives: ``<stem>-<hash>`` beside it
+    (``lib<stem>-<hash>.so`` for a shared library), the hash over the
+    source bytes and the compile command."""
+    with open(src, "rb") as f:
+        h = hashlib.sha256(f.read())
+    h.update(" ".join(_flags(shared)).encode())
+    stem = os.path.splitext(os.path.basename(src))[0]
+    name = (f"lib{stem}-{h.hexdigest()[:16]}.so" if shared
+            else f"{stem}-{h.hexdigest()[:16]}")
+    return os.path.join(os.path.dirname(src), name)
+
+
+def build_native(src: str, shared: bool = True) -> Optional[str]:
+    """The path of ``src``'s build (:func:`built_path`), compiling it
+    first when absent; None when no compiler is available.  One g++
+    invocation via a per-process temp path + os.replace, so concurrent
+    builders (parallel pytest workers, two CLIs on a fresh checkout)
+    can never interleave writes into a torn artifact.  Shared by the
+    event sim (.so) and the native router (binary)."""
+    out = built_path(src, shared)
+    if os.path.exists(out):
+        return out
     tmp = f"{out}.{os.getpid()}.tmp"
-    cmd = ["g++", "-O2", "-std=c++17"]
-    if shared:
-        cmd += ["-shared", "-fPIC"]
-    cmd += [src, "-o", tmp]
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        subprocess.run(_flags(shared) + [src, "-o", tmp], check=True,
+                       capture_output=True, timeout=120)
         os.replace(tmp, out)
-        return True
+        return out
     except (subprocess.SubprocessError, FileNotFoundError, OSError):
         try:
             os.unlink(tmp)
         except OSError:
             pass
-        return False
-
-
-def native_fresh(src: str, out: str) -> bool:
-    """True when ``out`` exists and is at least as new as ``src``."""
-    return (os.path.exists(out)
-            and os.path.getmtime(out) >= os.path.getmtime(src))
-
-
-def _build() -> bool:
-    return build_native(_SRC, _SO, shared=True)
+        return None
 
 
 def load_eventsim() -> Optional[ctypes.CDLL]:
@@ -63,19 +78,14 @@ def load_eventsim() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not native_fresh(_SRC, _SO) and not _build():
+        so = build_native(_SRC, shared=True)
+        if so is None:
             return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
         except OSError:
-            # stale/truncated/wrong-arch .so (e.g. an interrupted build
-            # left a fresh mtime): rebuild once, else fall back to Python
-            if not _build():
-                return None
-            try:
-                lib = ctypes.CDLL(_SO)
-            except OSError:
-                return None
+            # truncated/wrong-arch .so: fall back to Python
+            return None
         c = ctypes
         lib.gsim_create.restype = c.c_void_p
         lib.gsim_create.argtypes = [c.c_int32]

@@ -62,8 +62,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from gossip_tpu.compat import (interpret_impl, pallas_compiler_params,
-                               pallas_interpret_mode)
 
 LANES = 128
 BITS = 32
@@ -146,14 +144,32 @@ def _rotate_rows_xla(table: jax.Array, sbits: jax.Array,
     return rot
 
 
-# interpret routing (compat.interpret_impl): True/'reference' -> the
-# pure-JAX reference lowerings below, 'mosaic' -> the real Mosaic
-# interpreter.  The reference path is why driver-level interpret runs
-# (CPU tests, the multichip dry run) execute as ordinary jitted programs
-# instead of paying a Python interpreter callback per pallas_call per
-# plane per round — the 8-device dry run's fused families sat at
-# ~360-460 ms steady for exactly that reason.
-_interpret_impl = interpret_impl
+def interpret_impl(interpret):
+    """Normalize the ``interpret`` argument of the Pallas entry points.
+
+    ``False`` -> None (compiled TPU lowering).  ``True``/'reference' ->
+    ``'reference'``: the pure-JAX lowering of the kernel math, with the
+    hardware PRNG reproduced as the Mosaic interpreter defines it
+    off-TPU (all-zero draws) — compiled by XLA, so driver-level
+    interpret runs (CPU tests, the multichip dry run) execute as
+    ordinary jitted programs instead of paying a Python interpreter
+    callback per pallas_call per plane per round (the 8-device dry
+    run's fused families sat at ~360-460 ms steady for exactly that
+    reason).  ``'mosaic'`` -> the real Mosaic interpreter (kernel-body
+    tests)."""
+    if not interpret:
+        return None
+    if interpret is True or interpret == "reference":
+        return "reference"
+    if interpret == "mosaic":
+        return "mosaic"
+    raise ValueError(f"interpret must be a bool, 'reference' or 'mosaic'; "
+                     f"got {interpret!r}")
+
+
+def interpret_params(on):
+    """The ``interpret=`` argument of a ``pallas_call``."""
+    return pltpu.InterpretParams() if on else False
 
 
 def _phantom_word_keep(rows: int, n_valid_words: int, tail_mask: int):
@@ -307,9 +323,9 @@ def _fused_call(kernel, rows: int, seed, round_, table, inject_bits,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         input_output_aliases={2: 0},
-        compiler_params=None if interpret else pallas_compiler_params(
+        compiler_params=None if interpret else pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
-        interpret=pallas_interpret_mode(interpret),
+        interpret=interpret_params(interpret),
     )(*operands)
 
 
@@ -437,7 +453,7 @@ def _fused_pull_round_jit(table, seed, round_, drop_threshold, n: int,
                           fanout: int, interpret, inject_bits,
                           alive_table, plane_sharing: int,
                           cut_words) -> jax.Array:
-    if _interpret_impl(interpret) == "reference":
+    if interpret_impl(interpret) == "reference":
         return _fused_round_ref(table, n, fanout, inject_bits,
                                 drop_threshold, alive_table,
                                 plane_sharing, cut_words)
@@ -481,7 +497,7 @@ def fused_pull_round(table: jax.Array, seed: jax.Array, round_: jax.Array,
     ``interpret`` may be a bool or an impl name: ``True``/'reference'
     is the pure-JAX reference lowering (fast, compiled by XLA — the
     driver-test and dry-run path), 'mosaic' the real Mosaic interpreter
-    (kernel-body tests; see :func:`_interpret_impl`).
+    (kernel-body tests; see :func:`interpret_impl`).
     """
     if plane_sharing not in (1, 2):
         raise ValueError(f"plane_sharing must be 1 or 2, "
@@ -739,7 +755,7 @@ def _fused_mr_round_big(table: jax.Array, seed, round_, n: int,
     draws on a table too big for VMEM."""
     rows = table.shape[0]
     block = min(_MR_GATHER_BLOCK, rows)
-    impl = _interpret_impl(interpret)
+    impl = interpret_impl(interpret)
 
     if inject_bits is not None:
         sbits_all = jnp.asarray(inject_bits[0], jnp.uint32)  # [F, 8, 128]
@@ -872,7 +888,7 @@ def _fused_mr_round_big(table: jax.Array, seed, round_, n: int,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((block, LANES), lambda i: (i, 0)),
             input_output_aliases={} if no_alias else {2: 0},
-            interpret=pallas_interpret_mode(interpret),
+            interpret=interpret_params(interpret),
         )(*operands)
     return acc_p[:rows] if rows_pad != rows else acc_p
 
@@ -970,7 +986,7 @@ def _fused_mr_round_jit(table, seed, round_, drop_threshold, n: int,
                                    drop_threshold=drop_threshold,
                                    alive_words=alive_words, fanout=fanout,
                                    cut_words=cut_words)
-    if _interpret_impl(interpret) == "reference":
+    if interpret_impl(interpret) == "reference":
         return _fused_mr_round_ref(table, n, fanout, inject_bits,
                                    drop_threshold, alive_words, cut_words)
     kernel = functools.partial(_fused_mr_kernel, rows=rows, fanout=fanout,

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from gossip_tpu.compat import interpret_impl, pallas_interpret_mode
+from gossip_tpu.ops.pallas_round import interpret_impl, interpret_params
 
 _BLOCK_ROWS = 4096          # fixed: part of the determinism contract
 
@@ -107,7 +107,7 @@ def sample_targets_pallas(seed: jax.Array, n_rows: int, n_total: int,
                                memory_space=pltpu.VMEM),
         # TPU-semantics interpreter (plain interpret=True lacks the TPU
         # PRNG primitives on CPU)
-        interpret=pallas_interpret_mode(interpret),
+        interpret=interpret_params(interpret),
     )(jnp.asarray([seed], jnp.int32))
     return out[:n_rows]
 

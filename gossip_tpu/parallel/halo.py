@@ -37,8 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
 
-from gossip_tpu.compat import axis_size, shard_map
 from gossip_tpu import config as C
 from gossip_tpu.config import FaultConfig, ProtocolConfig, RunConfig
 from gossip_tpu.models import si as si_mod
@@ -68,7 +68,7 @@ def _ring_perms(axis_name: str):
     """(to_right, to_left) ppermute pairs on the mesh ring — the single
     source of the neighbor convention for both the forward halo read and
     the reverse push write-back."""
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     to_right = [(i, (i + 1) % p) for i in range(p)]
     to_left = [(i, (i - 1) % p) for i in range(p)]
     return to_right, to_left

@@ -54,8 +54,8 @@ import time
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
 
-from gossip_tpu.compat import shard_map
 from gossip_tpu.config import RunConfig
 from gossip_tpu.ops.pallas_round import (
     BITS, LANES, coverage_words, coverage_words_alive, drop_threshold_for,

@@ -25,8 +25,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
 
-from gossip_tpu.compat import shard_map
 from gossip_tpu import config as C
 from gossip_tpu.config import (FaultConfig, LogConfig, ProtocolConfig,
                                RunConfig)

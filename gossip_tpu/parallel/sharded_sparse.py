@@ -56,8 +56,8 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
 
-from gossip_tpu.compat import pvary, shard_map
 from gossip_tpu import config as C
 from gossip_tpu.config import FaultConfig, ProtocolConfig, RunConfig
 from gossip_tpu.models.state import SimState
@@ -261,7 +261,7 @@ def make_sparse_pull_round(
                 # must carry the varying-manual-axes type: this is a
                 # cond-branch output matched against the quiescent
                 # branch's pvary'd zf when period > 1
-                lost = pvary(jnp.float32(0.0), (axis_name,))
+                lost = jax.lax.pcast(jnp.float32(0.0), (axis_name,), to="varying")
             rows_req = jnp.where(valid, rows_req, jnp.int32(-1))
 
             # Column c of the [cap, p] slot view holds group (c + o) % p;
@@ -310,7 +310,7 @@ def make_sparse_pull_round(
             on = (round_ % proto.period) == 0
             # the quiescent branch's constants must carry the same
             # varying-manual-axes type as the exchange outputs
-            zf = pvary(jnp.float32(0.0), (axis_name,))
+            zf = jax.lax.pcast(jnp.float32(0.0), (axis_name,), to="varying")
             quiet = (jnp.zeros_like(seen_l), zf, zf)
             pulled, n_req, lost_r = jax.lax.cond(on, exchange,
                                                  lambda _: quiet, None)
@@ -681,7 +681,7 @@ def make_sparse_topo_pull_round(
             on = (round_ % proto.period) == 0
             # the quiescent branch's constants must carry the same
             # varying-manual-axes type as the exchange outputs
-            zf = pvary(jnp.float32(0.0), (axis_name,))
+            zf = jax.lax.pcast(jnp.float32(0.0), (axis_name,), to="varying")
             quiet = (jnp.zeros_like(seen_l), zf, zf)
             pulled, n_sent, n_over = jax.lax.cond(on, exchange,
                                                   lambda _: quiet, None)

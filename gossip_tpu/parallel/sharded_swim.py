@@ -20,8 +20,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
 
-from gossip_tpu.compat import shard_map
 from gossip_tpu.config import FaultConfig, ProtocolConfig
 from gossip_tpu.models import swim as SW
 from gossip_tpu.models.state import bind_tables

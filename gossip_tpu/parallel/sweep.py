@@ -61,9 +61,9 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 
-from gossip_tpu.compat import shard_map
 from gossip_tpu import config as C
 from gossip_tpu.config import FaultConfig, ProtocolConfig, RunConfig
 from gossip_tpu.models import si as si_mod

@@ -724,13 +724,13 @@ def spawn_replica(workdir: str, name: str, extra_argv=(),
 
 
 def fleet_env(compile_cache_dir: Optional[str] = None,
-              platform: Optional[str] = "cpu",
+              platform: Optional[str] = None,
               devices: Optional[int] = None) -> dict:
-    """Replica-child environment: repo importable, platform pinned
-    (default CPU — N replica processes cannot share one TPU; pass
-    ``platform=None`` to inherit the ambient pin on a multi-chip
-    host), and an optional SHARED compile-cache dir so a respawned
-    replica starts warm from its predecessors' executables.
+    """Replica-child environment: repo importable, the ambient platform
+    unless ``platform`` pins one (``JAX_PLATFORMS``), and an optional
+    SHARED compile-cache dir so a respawned replica starts warm from
+    its predecessors' executables.  Whether N replicas may run on that
+    platform is :func:`check_replica_platform`'s call.
 
     ``devices`` threads the host-device-count env
     (``XLA_FLAGS=--xla_force_host_platform_device_count=K``) for
@@ -753,6 +753,20 @@ def fleet_env(compile_cache_dir: Optional[str] = None,
                 flags + " --xla_force_host_platform_device_count="
                 f"{devices}").strip()
     return env
+
+
+def check_replica_platform(env: dict, replicas: int) -> None:
+    """Refuse a fleet whose replicas would share one chip: a chip
+    belongs to one process, so more than one replica needs the CPU
+    platform (``JAX_PLATFORMS=cpu`` in the replicas' env); an unset
+    platform means JAX's default, the chip where there is one."""
+    platform = env.get("JAX_PLATFORMS", "").split(",")[0].strip()
+    if replicas > 1 and platform != "cpu":
+        raise ValueError(
+            f"{replicas} replica processes would share one chip "
+            f"(replica platform {platform or 'default'!r}); a chip "
+            "belongs to one process — run --replicas 1, or pin the "
+            "replicas to the CPU (--replica-platform cpu)")
 
 
 def _verify_replica_devices(addr: str, name: str, want: int,
@@ -806,6 +820,7 @@ class Fleet:
         self.workdir = workdir
         self.replica_argv = tuple(replica_argv)
         self.env = env if env is not None else fleet_env()
+        check_replica_platform(self.env, n)
         self._gen = [0] * n
         procs, addrs = [], []
         try:

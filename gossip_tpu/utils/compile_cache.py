@@ -1,41 +1,37 @@
 """Compile-once runtime: persistent XLA cache + AOT executable store.
 
-PR 1 made steady-state rounds device-resident, which left first-round
-compiles as the dominant cost: 559-5201 ms per dry-run family (~27 s
-aggregate per process, `artifacts/dryrun_steady_budget_r06.json`
-`first_ms`) and the same wall eats most of the tier-1 budget.  A
-serving system cannot pay full XLA compilation on every process start,
-so this module makes the SECOND process (and every later one) reuse
-executables instead of recompiling.  Two layers, one env var:
+A serving system cannot pay full XLA compilation on every process
+start, so this module makes the SECOND process (and every later one)
+reuse executables instead of recompiling.  Two layers, one directory:
 
   1. **JAX's persistent compilation cache** (``enable_persistent``):
-     ``jax_compilation_cache_dir`` pointed at the shared directory, so
-     every plain ``jit`` first call — the dry-run families, CLI runs,
-     tests — consults the on-disk cache before invoking XLA.  The
-     knobs are PROBED through ``compat.persistent_cache_knobs`` (they
-     moved across jax lines; CPU-backend caching was once gated behind
-     the enable-xla-caches flag) and a missing knob degrades to "no
-     cache", never to a crash or a silently-warm "cold" measurement.
+     every plain ``jit`` first call consults the on-disk cache before
+     invoking XLA.
 
   2. **An own-layer AOT store** (``load_or_compile``): explicit
-     ``lower().compile()`` callers — every sharded driver's
-     ``timing=`` path, through the ONE chokepoint in
-     ``utils/trace.aot_timed`` — serialize the compiled executable
+     ``lower().compile()`` callers — every driver's ``timing=`` path,
+     through the ONE chokepoint in ``utils/trace.aot_timed`` —
+     serialize the compiled executable
      (``jax.experimental.serialize_executable``) into
      ``<dir>/aot/<key>``.  A later process lowers, matches the key,
-     and DESERIALIZES instead of compiling: warm cost is
-     trace+lower+load.  The key is the sha256 of the **lowered HLO
-     text** plus jax version / backend / device count — shapes,
-     dtypes, mesh/axis specs, donation, and closed-over constants are
-     all part of the HLO by construction, so a hit can never pair a
-     stale executable with changed program semantics (warm-vs-cold
-     bitwise equality is pinned in tests/test_compile_cache.py,
-     including cross-process).
+     and DESERIALIZES onto the same devices instead of compiling:
+     warm cost is trace+lower+load.  The key is the sha256 of the
+     **lowered HLO text** plus jax version / backend / the process's
+     device ids (each entry records the devices it executes on) — shapes, dtypes, mesh/axis specs, donation, and
+     closed-over constants are all part of the HLO by construction,
+     so a hit can never pair a stale executable with changed program
+     semantics (warm-vs-cold bitwise equality is pinned in
+     tests/test_compile_cache.py).
 
-``GOSSIP_COMPILE_CACHE=<dir>`` is the ambient switch for both layers;
-``GOSSIP_COMPILE_CACHE=""`` explicitly disables them (bench's honest
-cold-compile policy; the same convention as GOSSIP_TELEMETRY).  Every
-compile through the chokepoint emits a telemetry ``compile`` span with
+Where the directory is (:func:`resolve_dir`): ``$JAX_COMPILATION_CACHE_DIR``
+when set — JAX reads it itself and this module never overrides it —
+else the caller's explicit dir (``--compile-cache`` /
+``$GOSSIP_COMPILE_CACHE``), else the one fixed in-checkout path
+:data:`DEFAULT_DIR`.  The path is part of the cache's key, so it
+never moves: no home dir, temp name, pid or timestamp.
+``GOSSIP_COMPILE_CACHE=""`` explicitly disables both layers (cold
+compiles; the same convention as GOSSIP_TELEMETRY).  Every compile
+through the chokepoint emits a telemetry ``compile`` span with
 ``cache: hit|miss|disabled`` and bumps a ``compile_cache_<status>``
 counter, so a run ledger shows exactly which process paid which
 compile (tools/telemetry_report.py renders the table).
@@ -43,22 +39,8 @@ compile (tools/telemetry_report.py renders the table).
 Trust note: the AOT store deserializes pickled executables from the
 cache directory — the same trust domain as the persistent XLA cache
 directory and the checkpoint files (a hostile cache dir is a hostile
-filesystem).  Corrupt or stale entries are treated as misses and
-overwritten, never raised to the driver.
-
-Toolchain caveat (measured on jax 0.4.37 / XLA CPU, and the reason
-every failure path here is non-fatal): the two layers interfere
-in-process.  An executable that was itself LOADED from the persistent
-XLA cache serializes WITHOUT its object files, and — worse — after a
-process has taken even one persistent-cache hit, every subsequent
-``deserialize_and_load`` in that process fails with "Symbols not
-found", even for freshly compiled unrelated programs.  The store
-therefore (a) verifies each blob round-trips before publishing it
-(:func:`_try_store`), (b) treats load failures as non-destructive
-misses (:func:`_try_load`), and (c) is at full strength exactly where
-it matters: a fresh process's warm start, before any persistent-cache
-hit has poisoned deserialization (the cross-process test in
-tests/test_compile_cache.py pins this).
+filesystem).  Corrupt, stale or unloadable entries are treated as
+misses, never raised to the driver.
 """
 
 from __future__ import annotations
@@ -71,15 +53,22 @@ import time
 from typing import Optional, Tuple
 
 ENV_VAR = "GOSSIP_COMPILE_CACHE"
+JAX_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+# the one default cache directory: fixed, inside the checkout
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _AOT_SUBDIR = "aot"
 # bumped when the store's on-disk format changes; part of every key so
-# old entries become misses instead of unpickle errors
-_STORE_VERSION = 1
+# old entries become misses instead of unpickle errors (v2: entries
+# carry their execution device ids)
+_STORE_VERSION = 2
 
 
 def cache_dir_from_env(default_path: Optional[str] = None) -> Optional[str]:
-    """The active cache directory: $GOSSIP_COMPILE_CACHE, else
+    """The active AOT-store directory: $GOSSIP_COMPILE_CACHE, else
     ``default_path``, else None.  An empty-string env var explicitly
     DISABLES the cache (overriding any default) — the GOSSIP_TELEMETRY
     convention."""
@@ -89,29 +78,38 @@ def cache_dir_from_env(default_path: Optional[str] = None) -> Optional[str]:
     return path or None
 
 
+def resolve_dir(path: Optional[str]) -> Optional[str]:
+    """The directory a cache enabled at ``path`` really uses:
+    ``$JAX_COMPILATION_CACHE_DIR`` when set (placed from outside —
+    it wins over any in-code choice), else ``path``.  None/"" means
+    disabled and stays so."""
+    if not path:
+        return None
+    return os.path.abspath(os.environ.get(JAX_ENV_VAR) or path)
+
+
 def enable_persistent(path: Optional[str],
                       min_compile_time_secs: float = 0.0,
                       min_entry_size_bytes: int = -1) -> dict:
-    """Point jax's persistent compilation cache at ``path`` (None/""
-    disables it, also overriding any ambient JAX_COMPILATION_CACHE_DIR
-    — an explicit disable must mean honestly-cold compiles).  Returns
-    a status dict — ``{"dir", "persistent", "knobs"}`` — that callers
-    ledger verbatim, so every artifact says whether its compiles could
-    have been warm.
+    """Turn jax's persistent compilation cache on at
+    :func:`resolve_dir` of ``path`` (None/"" turns it off — an
+    explicit disable must mean honestly-cold compiles, whatever
+    $JAX_COMPILATION_CACHE_DIR says).  Returns a status dict —
+    ``{"dir", "persistent"}`` — that callers ledger verbatim, so every
+    artifact says whether its compiles could have been warm.
 
+    ``jax_compilation_cache_dir`` is set here only when
+    $JAX_COMPILATION_CACHE_DIR is not: JAX reads that variable itself.
     ``min_compile_time_secs=0.0`` caches everything by default: the
     dry-run families compile in 0.5-5 s each and the disk round-trip
-    is microseconds by comparison; the CLI keeps its own 2 s threshold
-    for operator ~/.cache hygiene.  Both knobs (and the dir itself)
-    are set through ``compat.set_cache_knob`` — absent knobs on other
-    jax lines are recorded in ``knobs`` and skipped, never raised."""
-    from gossip_tpu import compat
-    status = {"dir": None, "persistent": False,
-              "knobs": compat.persistent_cache_knobs()}
+    is microseconds by comparison; the CLI keeps its own 2 s
+    threshold."""
+    import jax
+    status = {"dir": None, "persistent": False}
+    path = resolve_dir(path)
     if not path:
-        compat.set_cache_knob("jax_compilation_cache_dir", None)
+        jax.config.update("jax_enable_compilation_cache", False)
         return status
-    path = os.path.abspath(path)
     try:
         os.makedirs(path, exist_ok=True)
     except OSError as e:
@@ -119,29 +117,17 @@ def enable_persistent(path: Optional[str],
         # run the cache was meant to speed up
         sys.stderr.write(f"compile_cache: cannot create {path!r} ({e}); "
                          "persistent cache disabled\n")
-        compat.set_cache_knob("jax_compilation_cache_dir", None)
+        jax.config.update("jax_enable_compilation_cache", False)
         return status
-    ok = compat.set_cache_knob("jax_compilation_cache_dir", path)
-    # the master enable defaults True on every line that has it, but a
-    # caller (or sitecustomize) may have flipped it — "dir set" must
-    # mean "cache on", not "cache on unless someone disabled it
-    # upstream", or status would claim warm-capability for cold walls.
-    # A line WITHOUT the knob has no off state to reset, so its absence
-    # must not veto ``persistent`` (the dir knob alone enables there)
-    if ok:
-        compat.set_cache_knob("jax_enable_compilation_cache", True)
-    compat.set_cache_knob("jax_persistent_cache_min_compile_time_secs",
-                          min_compile_time_secs)
-    compat.set_cache_knob("jax_persistent_cache_min_entry_size_bytes",
-                          min_entry_size_bytes)
-    # jax_persistent_cache_enable_xla_caches is probed (reported in
-    # status["knobs"]) but left at its default: CPU-backend caching on
-    # this 0.4.37 container works without it (measured cross-process),
-    # and the knob's value vocabulary differs across lines — forcing a
-    # guess could disable a working cache.  The expect_warm dry-run
-    # guard is the end-to-end check that warmth actually happens.
+    if not os.environ.get(JAX_ENV_VAR):
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      min_compile_time_secs)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                      min_entry_size_bytes)
     status["dir"] = path
-    status["persistent"] = ok
+    status["persistent"] = True
     return status
 
 
@@ -157,29 +143,55 @@ def enable_from_env(default_path: Optional[str] = None,
 # -- the AOT executable store -----------------------------------------
 
 def _fingerprint(hlo_text: str) -> str:
-    """Store key: lowered-HLO hash + toolchain/topology context.  The
-    HLO carries shapes, dtypes, sharding/mesh specs, donation and
-    every closed-over constant; version/backend/device-count guard the
-    executable format itself (a serialized CPU executable must never
-    load into a TPU process or a different device count)."""
+    """Store key: lowered-HLO hash + toolchain/device context.  The
+    HLO carries shapes, dtypes, sharding/mesh specs (the partition
+    count among them), donation and every closed-over constant;
+    version/backend/device ids guard the executable format and the
+    devices it can be assigned to (a serialized CPU executable must
+    never load into a TPU process or onto another device set).  The
+    entry itself records the exact devices it executes on
+    (:func:`_execution_device_ids`)."""
     import jax
     h = hashlib.sha256()
     h.update(hlo_text.encode())
     h.update(f"|v{_STORE_VERSION}|{jax.__version__}"
-             f"|{jax.default_backend()}|{jax.device_count()}".encode())
+             f"|{jax.default_backend()}"
+             f"|{[d.id for d in jax.devices()]}".encode())
     return h.hexdigest()[:40]
+
+
+def _execution_device_ids(compiled) -> Tuple[int, ...]:
+    """Ids of the devices a compiled executable runs on (its argument
+    and result shardings' device set, else the default device) — what
+    ``deserialize_and_load`` must be handed back as
+    ``execution_devices``, or a sharded executable loads onto every
+    device of the process and refuses its arguments."""
+    import jax
+    ids = set()
+    for s in jax.tree_util.tree_leaves((compiled.input_shardings,
+                                        compiled.output_shardings)):
+        ids.update(d.id for d in s.device_set)
+    return tuple(sorted(ids)) or (jax.devices()[0].id,)
 
 
 def _entry_path(cache_dir: str, key: str) -> str:
     return os.path.join(cache_dir, _AOT_SUBDIR, key + ".xbin")
 
 
-def _try_load(path: str, fns):
+def _load(payload, in_tree, out_tree, device_ids):
+    import jax
+    from jax.experimental.serialize_executable import deserialize_and_load
+    by_id = {d.id: d for d in jax.devices()}
+    return deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
+
+
+def _try_load(path: str):
     """Deserialized executable, or None — a miss, by contract, never an
     error.  A pickle-corrupt file (torn write from a pre-atomic-rename
     crash, disk damage) is deleted; an entry that unpickles but will
-    not LOAD here is KEPT — loadability is process-state-dependent
-    (another process may load it fine) and the writer verified it once
+    not LOAD here is KEPT — the writer verified it once
     (:func:`_try_store`), so deleting would let one odd process evict
     everyone's warm start."""
     try:
@@ -191,7 +203,7 @@ def _try_load(path: str, fns):
         # an entry other processes may be warm-starting from
         return None
     try:
-        payload, in_tree, out_tree = pickle.loads(data)
+        payload, in_tree, out_tree, device_ids = pickle.loads(data)
     except Exception as e:
         sys.stderr.write(f"compile_cache: dropping corrupt AOT entry "
                          f"{os.path.basename(path)} "
@@ -202,7 +214,7 @@ def _try_load(path: str, fns):
             pass
         return None
     try:
-        return fns[1](payload, in_tree, out_tree)
+        return _load(payload, in_tree, out_tree, device_ids)
     except Exception as e:
         sys.stderr.write(f"compile_cache: AOT entry "
                          f"{os.path.basename(path)} did not load in "
@@ -211,30 +223,28 @@ def _try_load(path: str, fns):
         return None
 
 
-def _try_store(path: str, compiled, fns) -> None:
+def _try_store(path: str, compiled) -> None:
     """Serialize ``compiled`` to ``path`` atomically (tmp + rename, so
     a killed writer can never leave a torn entry a sibling process
     would then deserialize).  The blob is VERIFIED by deserializing it
-    before the rename: an executable that was itself loaded from the
-    XLA persistent cache serializes to a truncated payload missing its
-    object files ("Symbols not found" on load — measured on jax
-    0.4.37/CPU), and the store must never publish an entry its own
+    before the rename: the store must never publish an entry its own
     writer cannot read back.  Failures degrade to "not cached" (the
     persistent-cache layer still serves the program)."""
+    from jax.experimental.serialize_executable import serialize
     try:
-        payload, in_tree, out_tree = fns[0](compiled)
-        fns[1](payload, in_tree, out_tree)          # verify round-trip
+        device_ids = _execution_device_ids(compiled)
+        payload, in_tree, out_tree = serialize(compiled)
+        _load(payload, in_tree, out_tree, device_ids)   # verify
     except Exception as e:
         sys.stderr.write(f"compile_cache: executable does not "
                          f"round-trip ({type(e).__name__}); not "
-                         "storing (persistent-cache-loaded executables "
-                         "cannot be re-serialized)\n")
+                         "storing\n")
         return
     try:
         os.makedirs(os.path.dirname(path), exist_ok=True)
         tmp = f"{path}.tmp.{os.getpid()}"
         with open(tmp, "wb") as f:
-            pickle.dump((payload, in_tree, out_tree), f)
+            pickle.dump((payload, in_tree, out_tree, device_ids), f)
         os.replace(tmp, path)
     except Exception as e:
         sys.stderr.write(f"compile_cache: could not store AOT entry "
@@ -316,7 +326,7 @@ def load_or_compile(jitted, *args, cache_dir: Optional[str] = None,
     """(compiled, status): the AOT chokepoint.  Lower ``jitted`` for
     ``args``, then either deserialize a stored executable (``"hit"``)
     or compile and store it (``"miss"``); ``"disabled"`` when no cache
-    dir is active or this jax cannot serialize executables.  The whole
+    dir is active.  The whole
     operation is one telemetry ``compile`` span carrying ``cache``/
     ``fn``/``key`` — a run killed mid-compile shows WHERE in the span
     tree, and the ledger's span walls decompose warm vs cold without
@@ -337,11 +347,9 @@ def load_or_compile(jitted, *args, cache_dir: Optional[str] = None,
     :func:`last_compile` keeps the most recent record for the live
     Metrics surface."""
     global _LAST_COMPILE
-    from gossip_tpu import compat
     from gossip_tpu.utils import telemetry
     if cache_dir is None:
         cache_dir = cache_dir_from_env()
-    fns = compat.serialize_executable_fns()
     led = telemetry.current()
     name = getattr(jitted, "__name__", None) or type(jitted).__name__
     key = None
@@ -351,18 +359,18 @@ def load_or_compile(jitted, *args, cache_dir: Optional[str] = None,
         # from span_end lines (span_start attrs don't ride along)
         ext["fn"] = name
         lowered = jitted.lower(*args)
-        if not cache_dir or fns is None:
+        if not cache_dir:
             compiled = lowered.compile()
             status = "disabled"
         else:
             key = _fingerprint(lowered.as_text())
             path = _entry_path(cache_dir, key)
-            compiled = _try_load(path, fns)
+            compiled = _try_load(path)
             if compiled is not None:
                 status = "hit"
             else:
                 compiled = lowered.compile()
-                _try_store(path, compiled, fns)
+                _try_store(path, compiled)
                 status = "miss"
             ext["key"] = key
         ext["cache"] = status
@@ -467,54 +475,3 @@ def entry_count(cache_dir: Optional[str]) -> Optional[int]:
     for _, _, files in os.walk(cache_dir):
         total += len(files)
     return total
-
-
-def timed_split(jitted, *args, cache_dir: str):
-    """(compiled, cold_s, warm_s, (status0, status1)): one honest cold
-    compile into a fresh store, then the SAME lower+compile warm from
-    it, with jax's in-memory caches cleared in between so the warm
-    number measures the store (trace+lower+deserialize), not a
-    Python-side memo.  The bench's reproducible CPU-side compile-split
-    signal; also a convenient self-test that a store round-trips on
-    this toolchain.  The statuses travel WITH the walls — a pair other
-    than ("miss", "hit") means warm_s is not a store round-trip (a
-    write/load failure made it a second full compile) and the consumer
-    must say so rather than publish it as warm; with the store
-    unavailable entirely the warm leg is SKIPPED (warm_s None,
-    statuses ("disabled", "skipped")) instead of paying a meaningless
-    second compile.
-
-    jax's PERSISTENT cache is suspended for the duration (config
-    saved/restored): with it active the cold compile could be served
-    warm — and a persistent-cache-loaded executable cannot even enter
-    the store (_try_store's round-trip verify) — so the split would
-    silently measure nothing."""
-    import jax
-
-    from gossip_tpu import compat
-    prev = getattr(jax.config, "jax_compilation_cache_dir", None)
-    compat.set_cache_knob("jax_compilation_cache_dir", None)
-    try:
-        t0 = time.perf_counter()
-        compiled, status0 = load_or_compile(jitted, *args,
-                                            cache_dir=cache_dir)
-        cold_s = time.perf_counter() - t0
-        if status0 == "disabled":
-            # no store on this toolchain/dir: a second compile would
-            # measure nothing but another cold compile (minutes for
-            # the big programs) — report the warm leg as absent
-            return compiled, cold_s, None, (status0, "skipped")
-        jax.clear_caches()
-        t0 = time.perf_counter()
-        compiled, status1 = load_or_compile(jitted, *args,
-                                            cache_dir=cache_dir)
-        warm_s = time.perf_counter() - t0
-    finally:
-        compat.set_cache_knob("jax_compilation_cache_dir", prev)
-    if (status0, status1) != ("miss", "hit"):
-        # a dirty dir (cold was already warm) or a store failure (warm
-        # recompiled) silently corrupts the split — report it instead
-        sys.stderr.write(f"compile_cache: timed_split statuses "
-                         f"({status0}, {status1}) != (miss, hit); "
-                         "walls may not be a true cold/warm pair\n")
-    return compiled, cold_s, warm_s, (status0, status1)

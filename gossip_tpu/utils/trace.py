@@ -47,11 +47,9 @@ def trace(logdir: Optional[str]) -> Iterator[None]:
 
 @contextlib.contextmanager
 def annotate(name: str) -> Iterator[None]:
-    """Named region inside an active trace (host + device timeline);
-    probed via compat so a jax without TraceAnnotation degrades to a
-    plain block instead of crashing the run it was meant to observe."""
-    from gossip_tpu import compat
-    with compat.trace_annotation(name):
+    """Named region inside an active trace (host + device timeline)."""
+    import jax
+    with jax.profiler.TraceAnnotation(name):
         yield
 
 
@@ -60,9 +58,8 @@ def profile(tag: Optional[str] = None) -> Iterator[None]:
     """The $GOSSIP_PROFILE hook: capture a jax.profiler trace of the
     enclosed block into the ambient directory, with an optional named
     annotation around the whole block.  A no-op (zero jax import) when
-    GOSSIP_PROFILE is unset, and a plain block when this jax lacks the
-    profiler API (compat.profiler_trace_fns probe) — the profiled
-    surfaces (dry-run families, bench legs) wrap unconditionally.
+    GOSSIP_PROFILE is unset — the profiled surfaces (dry-run
+    families, bench legs) wrap unconditionally.
 
     One capture per ``profile()`` block: jax traces do not nest, so the
     callers wrap the OUTER program (the dry-run body, one bench leg)
@@ -71,18 +68,13 @@ def profile(tag: Optional[str] = None) -> Iterator[None]:
     if not logdir:
         yield
         return
-    from gossip_tpu import compat
-    fns = compat.profiler_trace_fns()
-    if fns is None:
-        yield
-        return
-    start, stop = fns
-    start(logdir)
+    import jax
+    jax.profiler.start_trace(logdir)
     try:
         with annotate(tag) if tag else contextlib.nullcontext():
             yield
     finally:
-        stop()
+        jax.profiler.stop_trace()
 
 
 def aot_timed(jitted, *args, label=None):

@@ -15,8 +15,10 @@ Three layers under test:
   kill (``extra['dropped']`` -> ``lost_prefix``).
 * **No-churn checkpointed trajectories are unchanged**: the
   ``ckpt-static:*`` fingerprints in tests/data/churn_fingerprints_r06
-  .json were captured from the PRE-lift tree (PR 6, git 2f4d850);
-  the lifted drivers must reproduce them bitwise.
+  .json were captured from the PRE-lift tree (PR 6, git 2f4d850) and
+  re-pinned on jax 0.9.0 (PR 21, after checking that the SI and
+  fused-planes states equal their un-checkpointed runs and resume ==
+  straight bitwise); the drivers must reproduce them bitwise.
 
 The live SIGKILL harness is tools/crashloop.py (single-kill smoke at
 the bottom; the committed 3-kill record is

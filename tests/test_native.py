@@ -5,6 +5,8 @@ must reproduce its deliveries (times, nodes, hops), logs, message counts,
 and hop depths exactly on shared scenarios — including partitions and both
 context-bug modes — or it has no business existing."""
 
+import os
+
 import pytest
 
 from gossip_tpu.runtime.gonative import (GoNativeSim, NetConfig,
@@ -103,3 +105,20 @@ def test_factory_fallback():
     assert isinstance(sim, GoNativeSim)
     sim2 = make_event_sim({0: [1], 1: [0]}, prefer_native=True)
     assert isinstance(sim2, NativeGoSim)
+
+
+def test_native_build_is_keyed_on_the_source_hash(tmp_path):
+    """A build is trusted only for the exact source it came from: its
+    name carries the source hash, so an edited source (or a copy whose
+    mtimes say nothing) names a new file, and a present build of the
+    same source is reused without compiling."""
+    from gossip_tpu.native import build_native, built_path
+    src = tmp_path / "tiny.cpp"
+    src.write_text("int f() { return 1; }\n")
+    first = built_path(str(src), shared=True)
+    assert os.path.basename(first).startswith("libtiny-")
+    assert built_path(str(src), shared=False) != first
+    open(first, "w").close()         # a "build" of this exact source
+    assert build_native(str(src), shared=True) == first
+    src.write_text("int f() { return 2; }\n")
+    assert built_path(str(src), shared=True) != first

@@ -732,8 +732,7 @@ class TestHardwarePRNGFaultMasksMultirumor:
 # driver/dry-run path); ``interpret="mosaic"`` forces the real Mosaic
 # interpreter.  These tests pin them bitwise-equal on injected bits, so the
 # kernel BODIES stay executed in CI and the reference can never drift.
-# (Injected bits only: the 0.4.x Mosaic interpreter has no CPU lowering for
-# the TPU PRNG primitives — gossip_tpu/compat.py module doc.)
+# (Injected bits only: off-TPU the interpreter stubs the hardware PRNG.)
 
 @pytest.mark.parametrize("fanout,sharing,drop_p,death",
                          [(1, 1, 0.0, 0.0),

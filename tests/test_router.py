@@ -440,7 +440,7 @@ def test_fleet_env_threads_host_device_count(monkeypatch):
     respected, never duplicated."""
     from gossip_tpu.rpc.router import fleet_env
     monkeypatch.delenv("XLA_FLAGS", raising=False)
-    env = fleet_env(devices=4)
+    env = fleet_env(platform="cpu", devices=4)
     assert env["JAX_PLATFORMS"] == "cpu"
     assert env["XLA_FLAGS"] == \
         "--xla_force_host_platform_device_count=4"
@@ -455,6 +455,40 @@ def test_fleet_env_threads_host_device_count(monkeypatch):
     monkeypatch.setenv("XLA_FLAGS", "--xla_dump_to=/tmp/x")
     assert fleet_env(devices=4)["XLA_FLAGS"] == \
         "--xla_dump_to=/tmp/x --xla_force_host_platform_device_count=4"
+
+
+def test_fleet_env_inherits_the_ambient_platform(monkeypatch):
+    """No CPU default: the replicas run where the caller's JAX would
+    (a pin only when asked for one)."""
+    from gossip_tpu.rpc.router import fleet_env
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    assert fleet_env()["JAX_PLATFORMS"] == "tpu"
+    assert fleet_env(platform="cpu")["JAX_PLATFORMS"] == "cpu"
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert "JAX_PLATFORMS" not in fleet_env()
+
+
+@pytest.mark.parametrize("platform,replicas,ok", [
+    ("tpu", 2, False), ("", 2, False), ("tpu,cpu", 3, False),
+    ("cpu", 2, True), ("tpu", 1, True), ("", 1, True)])
+def test_replicas_never_share_a_chip(platform, replicas, ok):
+    from gossip_tpu.rpc.router import check_replica_platform
+    env = {"JAX_PLATFORMS": platform} if platform else {}
+    if ok:
+        check_replica_platform(env, replicas)
+    else:
+        with pytest.raises(ValueError, match="share one chip"):
+            check_replica_platform(env, replicas)
+
+
+def test_route_refuses_replicas_on_one_chip(capsys):
+    """`route` refuses before it spawns anything when its replicas
+    would share the chip."""
+    from gossip_tpu import cli
+    rc = cli.main(["route", "--replicas", "2", "--port", "0",
+                   "--replica-platform", "tpu"])
+    assert rc == 2
+    assert "share one chip" in capsys.readouterr().err
 
 
 def test_replica_device_verification_refuses_degraded_mesh():

@@ -244,18 +244,9 @@ def test_topo_sparse_matches_dense_statistically():
     """Same ER pull protocol through the sparse exchange and the dense
     sharded path: rounds-to-99% must agree within a seed-stream-aware
     margin (the two engines draw from DIFFERENT RNG streams, so the
-    agreement is statistical, not bitwise).
-
-    The margin is a property of the random stream, and jax.random's
-    stream semantics differ between the modern line and the 0.4.x
-    fallback toolchain (compat module doc): +/-2 was tuned on the
-    modern stream, where this seed lands <=2 apart; the 0.4.x stream
-    lands the same seed 3 apart (16 vs 19) — a real stream difference,
-    not an engine regression, so legacy jax widens the margin to +/-3
-    instead of standing red (the bitwise-parity tests above are the
-    correctness gate; this one only guards against gross divergence
-    like a lost round of mixing)."""
-    from gossip_tpu.compat import legacy_jax
+    agreement is statistical, not bitwise).  This only guards against
+    gross divergence like a lost round of mixing; the bitwise-parity
+    tests above are the correctness gate."""
     from gossip_tpu.parallel.sharded import simulate_until_sharded
     n = 2048
     topo = G.erdos_renyi(n, 12.0 / n, seed=9)
@@ -265,8 +256,7 @@ def test_topo_sparse_matches_dense_statistically():
         proto, topo, run, _mesh())
     r_d, cov_d, _, _ = simulate_until_sharded(proto, topo, run, _mesh())
     assert cov_s >= 0.99 and cov_d >= 0.99
-    margin = 3 if legacy_jax() else 2
-    assert abs(r_s - r_d) <= margin, (r_s, r_d, margin)
+    assert abs(r_s - r_d) <= 2, (r_s, r_d)
 
 
 @pytest.mark.slow

@@ -175,28 +175,25 @@ def test_round_timer_percentiles():
 def test_trace_smoke(tmp_path, monkeypatch):
     """One real profiler capture smokes the whole observability
     surface: the $GOSSIP_PROFILE ambient hook (trace.profile — what
-    the dry run and bench wrap), a named annotation inside it, and the
-    compat probes it degrades through.  trace(logdir) shares the same
+    the dry run and bench wrap) and a named annotation inside it.  trace(logdir) shares the same
     jax.profiler machinery (its CLI path runs under `-m slow`)."""
-    from gossip_tpu import compat
     from gossip_tpu.utils.trace import profile, profile_dir
     prof = str(tmp_path / "prof")
     monkeypatch.setenv("GOSSIP_PROFILE", prof)
     assert profile_dir() == prof
-    assert compat.profiler_trace_fns() is not None   # this jax has it
     with profile("smoke"):
         with annotate("round"):
             jax.block_until_ready(jax.numpy.arange(8) * 2)
     # trace files land under the ambient dir
     assert any(os.scandir(prof))
     # unset/empty = strictly off (the GOSSIP_TELEMETRY convention):
-    # the profiler probe must never even be consulted
+    # the profiler must never even be started
     monkeypatch.setenv("GOSSIP_PROFILE", "")
     assert profile_dir() is None
 
-    def _probed():
-        raise AssertionError("profiler probed while GOSSIP_PROFILE off")
-    monkeypatch.setattr(compat, "profiler_trace_fns", _probed)
+    def _started(*a, **k):
+        raise AssertionError("profiler started while GOSSIP_PROFILE off")
+    monkeypatch.setattr(jax.profiler, "start_trace", _started)
     with profile("dark"):
         pass
     t = RoundTimer()

@@ -1,8 +1,7 @@
 """Single-source loader for ``gossip_tpu.utils.telemetry`` from tools/
 scripts (which run by path with tools/, not the repo root, on
-sys.path) — the same one-definition pattern as tools/_bench.py, so the
-ledger-bootstrap idiom cannot drift between hw_refresh and the
-watchdog."""
+sys.path) — one definition, so the ledger-bootstrap idiom cannot drift
+between the capture tools."""
 
 import os
 import sys

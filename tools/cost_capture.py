@@ -24,7 +24,7 @@ or the artifact is red.
 Everything lands in ONE run ledger (provenance first line), so the
 committed artifact passes tools/validate_artifacts.py's
 cost/xprof/attribution provenance gate; tools/cost_report.py renders
-it; bench.costs_for_headline() rides it.
+it.
 
     python tools/cost_capture.py [OUT.jsonl]   # default
         artifacts/ledger_cost_r24.jsonl

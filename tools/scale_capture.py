@@ -50,10 +50,9 @@ scale/plan/budget provenance gate.
         plans against the DETECTED device topology and executes, into
         its own artifact (ledger_scale_full.jsonl — the structural
         record's run="last" readers must keep seeing a scale_record);
-        refuses rc 1 off-TPU (real HBM only; rc 2 stays the hw_refresh
-        wedge signature — ROADMAP item 3's hardware-capture remainder,
-        run by the hw_refresh scale_plan step at the first healthy
-        window)
+        refuses rc 1 off-TPU (real HBM only — ROADMAP item 3's
+        hardware-capture remainder, run by the hw_refresh scale_plan
+        step on the chip)
     python tools/scale_capture.py --multislice   # the DETECTED-
         topology multislice executor leg: plans N = 2^20 against the
         real chip/HBM/slice topology and fans the tile stream across
@@ -115,15 +114,13 @@ def forced_plan(n, rounds, *, tiles_at_least=4):
 def full_scale(led) -> int:
     """The 100M-node hardware leg: plan against the DETECTED topology
     and execute.  Gated on real TPU HBM — on any other backend this is
-    a structural no-op refused rc 1 (rc 2 would read as the hw_refresh
-    wedge signature; the hw_refresh step only passes --full-scale at a
-    TPU window)."""
+    a structural no-op refused rc 1 (the hw_refresh step only passes
+    --full-scale on the chip)."""
     import jax
     from gossip_tpu.planner import budget as PB
     from gossip_tpu.planner.stream import run_at_scale
     if jax.default_backend() != "tpu":
-        # rc 1, not 2: off-TPU --full-scale is an operator error, and
-        # rc 2 is the hw_refresh wedge-signature convention
+        # off-TPU --full-scale is an operator error
         print(json.dumps({"error": "full-scale needs real TPU HBM",
                           "backend": jax.default_backend()}))
         return 1
@@ -157,8 +154,7 @@ def multislice_leg(led) -> int:
     tile stream across the reported DCN slices (per-slice segments
     merging into the one host cursor, zero cross-slice bytes).  Gated
     on a real TPU backend reporting >= 2 slices — anywhere else this
-    is an operator error refused rc 1 (rc 2 stays the hw_refresh
-    wedge-signature convention; the hw_refresh step only passes
+    is an operator error refused rc 1 (the hw_refresh step only passes
     --multislice when the structural record reports slices > 1)."""
     import jax
     from gossip_tpu.planner import budget as PB

@@ -157,7 +157,7 @@ def load_budgets(path=BUDGETS_PATH, table="steady_ms"):
 def compile_cache_table(events):
     """The compile-once read-out: ``{"status", "rows", "totals"}`` from
     a run's cache events.  ``status`` is the last ``compile_cache``
-    enable event (dir/persistent/knobs); ``rows`` is one entry per
+    enable event (dir/persistent); ``rows`` is one entry per
     compile — the dry-run body's per-family ``compile`` events and the
     chokepoint's ``compile`` span_ends (utils/compile_cache) — each
     carrying ``cache: hit|miss|disabled``; ``totals`` counts rows by

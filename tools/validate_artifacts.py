@@ -8,7 +8,7 @@ toolchain, and run produced them — the provenance keys ``run_id``,
 ``git_commit``, ``captured`` (utils/telemetry.provenance).  Ledger
 JSONLs carry them on their first ``provenance`` event line; plain-JSON
 artifacts embed the dict under a ``"provenance"`` key (or the three
-keys at top level, the bench ``last_tpu`` style).
+keys at top level).
 
 Artifacts that predate the ledger are ALLOWLISTED BY NAME below — an
 explicit, reviewable list, not a silent grandfather clause: adding a
@@ -62,8 +62,6 @@ LEGACY = frozenset({
     # swim_steady_ablation_r05.smoke.json left this list in the
     # observability PR: the tool now embeds provenance and the
     # committed smoke artifact was regenerated with it
-    "tunnel_health_r04.jsonl",
-    "tunnel_health_r05.jsonl",
 })
 
 
