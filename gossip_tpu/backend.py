@@ -289,35 +289,38 @@ def _run_fused(proto: ProtocolConfig, tc: TopologyConfig, run: RunConfig,
                   "vmem_table_bytes": table_bytes,
                   **_timing_meta(timing, wall)})
 
-    if proto.rumors == 1:
-        loop, init = compiled_until_fused(
-            n, seed=run.seed, fanout=proto.fanout,
-            target_coverage=run.target_coverage, max_rounds=run.max_rounds,
-            origin=run.origin, fault=fault)
-        # the SAME weighting chooser the loop's cond uses — cannot drift
-        from gossip_tpu.ops.pallas_round import fused_cov_fn
-        cov_fn = fused_cov_fn(n, fault, run.origin)
-    else:
-        loop, init = compiled_until_fused_multirumor(
-            n, proto.rumors, seed=run.seed, fanout=proto.fanout,
-            target_coverage=run.target_coverage, max_rounds=run.max_rounds,
-            origin=run.origin, fault=fault)
-        from gossip_tpu.ops.pallas_round import fused_mr_cov_fn
-        cov_fn = fused_mr_cov_fn(n, proto.rumors, fault, run.origin)
+    from gossip_tpu.utils.trace import BUILD, READBACK, maybe_aot_timed, span
+    with span(BUILD):
+        if proto.rumors == 1:
+            loop, init = compiled_until_fused(
+                n, seed=run.seed, fanout=proto.fanout,
+                target_coverage=run.target_coverage,
+                max_rounds=run.max_rounds, origin=run.origin, fault=fault)
+            # the SAME weighting chooser the loop's cond uses — cannot drift
+            from gossip_tpu.ops.pallas_round import fused_cov_fn
+            cov_fn = fused_cov_fn(n, fault, run.origin)
+        else:
+            loop, init = compiled_until_fused_multirumor(
+                n, proto.rumors, seed=run.seed, fanout=proto.fanout,
+                target_coverage=run.target_coverage,
+                max_rounds=run.max_rounds, origin=run.origin, fault=fault)
+            from gossip_tpu.ops.pallas_round import fused_mr_cov_fn
+            cov_fn = fused_mr_cov_fn(n, proto.rumors, fault, run.origin)
 
-    from gossip_tpu.utils.trace import maybe_aot_timed
     timing: Dict[str, float] = {}
     t0 = time.perf_counter()
     final = maybe_aot_timed(loop, timing, init, label="solo")
     wall = time.perf_counter() - t0
-    cov = float(cov_fn(final.table))
-    rounds = int(final.round)
+    with span(READBACK):
+        cov = float(cov_fn(final.table))
+        rounds = int(final.round)
+        msgs = float(final.msgs)
     # float32 target compare, same threshold the loop's cond used
     hit = cov >= float(jnp.float32(run.target_coverage))
     return RunReport(
         backend="jax-tpu", mode=proto.mode, n=n,
         rounds=rounds if hit else -1, coverage=cov,
-        msgs=float(final.msgs), wall_s=round(wall, 4),
+        msgs=msgs, wall_s=round(wall, 4),
         meta={"clock": "rounds", "devices": 1,
               "msgs_counts": "transmissions", "engine": "fused-pallas",
               "layout": ("node-packed bitmap" if proto.rumors == 1
@@ -475,11 +478,14 @@ def run_jax(proto: ProtocolConfig, tc: TopologyConfig, run: RunConfig,
             "engine='native' is the go-native backend's C++ event core; "
             "jax-tpu engines are auto|xla|fused (use --backend go-native)")
     import jax as _jax
-    t0_build = time.perf_counter()
-    topo = G.build(tc)
-    if topo.nbrs is not None:
-        _jax.block_until_ready((topo.nbrs, topo.deg))
-    topo_build_s = time.perf_counter() - t0_build
+
+    from gossip_tpu.utils.trace import BUILD, span
+    with span(BUILD):
+        t0_build = time.perf_counter()
+        topo = G.build(tc)
+        if topo.nbrs is not None:
+            _jax.block_until_ready((topo.nbrs, topo.deg))
+        topo_build_s = time.perf_counter() - t0_build
     rep = _run_jax_with_topo(proto, tc, run, fault, mesh_cfg, want_curve,
                              topo)
     rep.meta["topo_build_s"] = round(topo_build_s, 4)

@@ -40,6 +40,8 @@ from gossip_tpu.ops.bitpack import coverage_packed, pack, unpack
 from gossip_tpu.ops.propagate import push_delta
 from gossip_tpu.ops.sampling import apply_drop, sample_peers
 from gossip_tpu.topology.generators import Topology
+from gossip_tpu.utils.trace import (BUILD, COVERAGE, EXCHANGE, READBACK,
+                                    SAMPLE, span)
 
 
 def init_packed_state(run: RunConfig, proto: ProtocolConfig,
@@ -107,71 +109,80 @@ def make_packed_round(proto: ProtocolConfig, topo: Topology,
         tbl, sched = NE.split_tables(ch, tbl)
         nbrs_t, deg_t = tbl if tbl else (None, None)
         ids = jnp.arange(n, dtype=jnp.int32)
-        rkey = jax.random.fold_in(state.base_key, state.round)
         packed = state.seen
-        if ch is not None:
-            # churn path: per-round liveness / drop prob / cut from the
-            # schedule operands (models/si.py twin)
-            alive = NE.alive_rows(sched, NE.base_alive_or_ones(
-                fault, n, origin), state.round)
-            dp = NE.drop_at(sched, state.round)
-            cut = NE.cut_at(sched, state.round)
-        else:
-            alive = alive_mask(fault, n, origin)  # in-trace
-            dp, cut = drop_prob, None
-        lost = jnp.float32(0.0)
-        visible = packed if alive is None else jnp.where(
-            alive[:, None], packed, jnp.uint32(0))
-        if sampler == "pallas":
-            from gossip_tpu.ops.pallas_sampling import sample_peers_fast
-            partners = sample_peers_fast(sampler_seed, state.round, n, n, k,
-                                         proto.exclude_self)
-        else:
-            qkey = jax.random.fold_in(rkey, si_mod.PULL_TAG)
-            partners = sample_peers(qkey, ids, topo, k, proto.exclude_self,
-                                    local_nbrs=nbrs_t, local_deg=deg_t)
-        partners0 = partners
-        partners = apply_drop(rkey, si_mod.PULL_DROP_TAG, ids,
-                              partners0, dp, n, force=ch is not None)
-        if ch is not None:
-            partners = NE.partition_targets(cut, ids, partners, n)
-        pulled = pull_merge_packed(visible, partners, n)
-        if alive is not None:
-            partners = jnp.where(alive[:, None], partners, n)
-        n_req = jnp.sum(partners < n).astype(jnp.float32)
-        if ch is not None:
-            lost_pull = NE.lost_count(partners0, partners, alive, n)
-            if mode == C.ANTI_ENTROPY and proto.period > 1:
-                # quiescent rounds send nothing, so nothing is lost
-                lost_pull = jnp.where(
-                    (state.round % proto.period) == 0, lost_pull, 0.0)
-            lost = lost + lost_pull
-        if mode == C.ANTI_ENTROPY:
-            # Bidirectional reconciliation (twin of models/si.py): the
-            # initiator's digest also scatters back into the partner's row.
-            # XLA has no scatter-OR on words, so the push-back unpacks to
-            # bools for the scatter and repacks — lax.cond confines that
-            # cost to exchange rounds; the pull direction stays a pure
-            # word gather.
-            def reverse_delta(_):
-                return pack(push_delta(n, partners,
-                                       unpack(visible, proto.rumors)))
-
-            mfac = 3.0    # request + digest response + reverse delta
-            if proto.period > 1:
-                on = (state.round % proto.period) == 0
-                back = jax.lax.cond(on, reverse_delta,
-                                    lambda _: jnp.zeros_like(pulled), None)
-                pulled = jnp.where(on, pulled, jnp.uint32(0))
-                n_req = jnp.where(on, n_req, 0.0)
+        # the round's fault masks decide, like the drop coins, which
+        # pulls happen: they ride the sample scope
+        with jax.named_scope(SAMPLE):
+            if ch is not None:
+                # churn path: per-round liveness / drop prob / cut from the
+                # schedule operands (models/si.py twin)
+                alive = NE.alive_rows(sched, NE.base_alive_or_ones(
+                    fault, n, origin), state.round)
+                dp = NE.drop_at(sched, state.round)
+                cut = NE.cut_at(sched, state.round)
             else:
-                back = reverse_delta(None)
-            pulled = pulled | back
-        else:
-            mfac = 2.0    # request + digest response
-        if alive is not None:
-            pulled = jnp.where(alive[:, None], pulled, jnp.uint32(0))
-        out = SimState(seen=packed | pulled, round=state.round + 1,
+                alive = alive_mask(fault, n, origin)  # in-trace
+                dp, cut = drop_prob, None
+        lost = jnp.float32(0.0)
+        with jax.named_scope(SAMPLE):
+            rkey = jax.random.fold_in(state.base_key, state.round)
+            if sampler == "pallas":
+                from gossip_tpu.ops.pallas_sampling import sample_peers_fast
+                partners = sample_peers_fast(sampler_seed, state.round, n, n,
+                                             k, proto.exclude_self)
+            else:
+                qkey = jax.random.fold_in(rkey, si_mod.PULL_TAG)
+                partners = sample_peers(qkey, ids, topo, k,
+                                        proto.exclude_self,
+                                        local_nbrs=nbrs_t, local_deg=deg_t)
+            partners0 = partners
+            partners = apply_drop(rkey, si_mod.PULL_DROP_TAG, ids,
+                                  partners0, dp, n, force=ch is not None)
+            if ch is not None:
+                partners = NE.partition_targets(cut, ids, partners, n)
+        with jax.named_scope(EXCHANGE):
+            visible = packed if alive is None else jnp.where(
+                alive[:, None], packed, jnp.uint32(0))
+            pulled = pull_merge_packed(visible, partners, n)
+        with jax.named_scope(COVERAGE):
+            if alive is not None:
+                partners = jnp.where(alive[:, None], partners, n)
+            n_req = jnp.sum(partners < n).astype(jnp.float32)
+            if ch is not None:
+                lost_pull = NE.lost_count(partners0, partners, alive, n)
+                if mode == C.ANTI_ENTROPY and proto.period > 1:
+                    # quiescent rounds send nothing, so nothing is lost
+                    lost_pull = jnp.where(
+                        (state.round % proto.period) == 0, lost_pull, 0.0)
+                lost = lost + lost_pull
+        with jax.named_scope(EXCHANGE):
+            if mode == C.ANTI_ENTROPY:
+                # Bidirectional reconciliation (twin of models/si.py): the
+                # initiator's digest also scatters back into the partner's row.
+                # XLA has no scatter-OR on words, so the push-back unpacks to
+                # bools for the scatter and repacks — lax.cond confines that
+                # cost to exchange rounds; the pull direction stays a pure
+                # word gather.
+                def reverse_delta(_):
+                    return pack(push_delta(n, partners,
+                                           unpack(visible, proto.rumors)))
+
+                mfac = 3.0    # request + digest response + reverse delta
+                if proto.period > 1:
+                    on = (state.round % proto.period) == 0
+                    back = jax.lax.cond(on, reverse_delta,
+                                        lambda _: jnp.zeros_like(pulled), None)
+                    pulled = jnp.where(on, pulled, jnp.uint32(0))
+                    n_req = jnp.where(on, n_req, 0.0)
+                else:
+                    back = reverse_delta(None)
+                pulled = pulled | back
+            else:
+                mfac = 2.0    # request + digest response
+            if alive is not None:
+                pulled = jnp.where(alive[:, None], pulled, jnp.uint32(0))
+            seen = packed | pulled
+        out = SimState(seen=seen, round=state.round + 1,
                        base_key=state.base_key,
                        msgs=state.msgs + mfac * n_req)
         return (out, lost) if ch is not None else out
@@ -187,29 +198,32 @@ def simulate_until_packed(proto: ProtocolConfig, topo: Topology,
     Returns (rounds, coverage, msgs, final_state).  ``timing``: pass a
     dict for the compile/steady AOT split (utils.trace.aot_timed)."""
     from gossip_tpu.ops import nemesis as NE
-    step, tables = make_packed_round(proto, topo, fault, run.origin,
-                                     tabled=True)
-    step = NE.drop_lost(step, NE.get(fault))
-    alive = NE.metric_alive(fault, topo.n, run.origin)
-    init = init_packed_state(run, proto, topo.n)
-    target = jnp.float32(run.target_coverage)
+    with span(BUILD):
+        step, tables = make_packed_round(proto, topo, fault, run.origin,
+                                         tabled=True)
+        step = NE.drop_lost(step, NE.get(fault))
+        alive = NE.metric_alive(fault, topo.n, run.origin)
+        init = init_packed_state(run, proto, topo.n)
+        target = jnp.float32(run.target_coverage)
     r = proto.rumors
 
     @jax.jit
     def loop(state, *tbl):
         alive_t = NE.metric_alive(fault, topo.n, run.origin)
         def cond(s):
-            return ((coverage_packed(s.seen, r, alive_t) < target)
-                    & (s.round < run.max_rounds))
+            with jax.named_scope(COVERAGE):
+                return ((coverage_packed(s.seen, r, alive_t) < target)
+                        & (s.round < run.max_rounds))
         def body(s):
             return step(s, *tbl)
         return jax.lax.while_loop(cond, body, state)
 
     from gossip_tpu.utils.trace import maybe_aot_timed
     final = maybe_aot_timed(loop, timing, init, *tables)
-    return (int(final.round),
-            float(coverage_packed(final.seen, r, alive)),
-            float(final.msgs), final)
+    with span(READBACK):
+        return (int(final.round),
+                float(coverage_packed(final.seen, r, alive)),
+                float(final.msgs), final)
 
 
 def compiled_until_packed(proto: ProtocolConfig, topo: Topology,
@@ -232,8 +246,9 @@ def compiled_until_packed(proto: ProtocolConfig, topo: Topology,
     def loop(state, *tbl):
         alive = NE.metric_alive(fault, topo.n, run.origin)
         def cond(s):
-            return ((coverage_packed(s.seen, r, alive) < target)
-                    & (s.round < run.max_rounds))
+            with jax.named_scope(COVERAGE):
+                return ((coverage_packed(s.seen, r, alive) < target)
+                        & (s.round < run.max_rounds))
         def body(s):
             return step(s, *tbl)
         return jax.lax.while_loop(cond, body, state)

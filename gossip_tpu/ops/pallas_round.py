@@ -62,6 +62,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from gossip_tpu.utils.trace import COVERAGE, EXCHANGE, SAMPLE
+
 
 LANES = 128
 BITS = 32
@@ -761,10 +763,11 @@ def _fused_mr_round_big(table: jax.Array, seed, round_, n: int,
         sbits_all = jnp.asarray(inject_bits[0], jnp.uint32)  # [F, 8, 128]
         rbits_all = jnp.asarray(inject_bits[1], jnp.uint32)  # [F, rows, 128]
     else:
-        base = jax.random.PRNGKey(
-            jnp.uint32(jnp.asarray(seed, jnp.int32)) * jnp.uint32(_ROUND_MIX)
-            + jnp.uint32(0x5D0))
-        rkey = jax.random.fold_in(base, jnp.asarray(round_, jnp.int32))
+        with jax.named_scope(SAMPLE):
+            base = jax.random.PRNGKey(
+                jnp.uint32(jnp.asarray(seed, jnp.int32))
+                * jnp.uint32(_ROUND_MIX) + jnp.uint32(0x5D0))
+            rkey = jax.random.fold_in(base, jnp.asarray(round_, jnp.int32))
 
     rows_pad = -(-rows // block) * block
     zpad = (jnp.zeros((rows_pad - rows, LANES), jnp.uint32)
@@ -788,8 +791,9 @@ def _fused_mr_round_big(table: jax.Array, seed, round_, n: int,
         else:
             # draw 0 keeps the pre-round-5 stream byte-identical; later
             # draws fold the static draw index into the round key
-            kf = rkey if f == 0 else jax.random.fold_in(rkey, f)
-            sbits = jax.random.bits(kf, (8, LANES), jnp.uint32)
+            with jax.named_scope(SAMPLE):
+                kf = rkey if f == 0 else jax.random.fold_in(rkey, f)
+                sbits = jax.random.bits(kf, (8, LANES), jnp.uint32)
 
         # Stage 1 (XLA): per-lane row rotation, binary decomposition —
         # always from the PRE-round serve-masked table.  The partition
@@ -1119,17 +1123,19 @@ def compiled_until_fused_multirumor(n: int, rumors: int, seed: int,
         # O(N) constant baked into the donated jit below
         alive_words = (fault_masks_word(fault, n, origin)[0]
                        if has_alive else None)
-        tab = fused_multirumor_pull_round(st.table, seed, st.round, n,
-                                          fanout, interpret,
-                                          drop_threshold=drop_threshold,
-                                          alive_words=alive_words)
+        with jax.named_scope(EXCHANGE):
+            tab = fused_multirumor_pull_round(st.table, seed, st.round, n,
+                                              fanout, interpret,
+                                              drop_threshold=drop_threshold,
+                                              alive_words=alive_words)
         return FusedState(table=tab, round=st.round + 1,
                           msgs=st.msgs + 2.0 * fanout * n)
 
     @functools.partial(jax.jit, donate_argnums=0)
     def loop(st: FusedState) -> FusedState:
         def cond(s):
-            return (cov(s.table) < target) & (s.round < max_rounds)
+            with jax.named_scope(COVERAGE):
+                return (cov(s.table) < target) & (s.round < max_rounds)
         return jax.lax.while_loop(cond, step, st)
 
     return loop, init_multirumor_state(n, rumors, origin)
@@ -1255,16 +1261,18 @@ def compiled_until_fused(n: int, seed: int, fanout: int = 1,
         # O(N) constant baked into the donated jit below
         alive_tab = (fault_masks_node_packed(fault, n, origin)[0]
                      if has_alive else None)
-        tab = fused_pull_round(st.table, seed, st.round, n, fanout,
-                               interpret, drop_threshold=drop_threshold,
-                               alive_table=alive_tab)
+        with jax.named_scope(EXCHANGE):
+            tab = fused_pull_round(st.table, seed, st.round, n, fanout,
+                                   interpret, drop_threshold=drop_threshold,
+                                   alive_table=alive_tab)
         return FusedState(table=tab, round=st.round + 1,
                           msgs=st.msgs + 2.0 * fanout * n)
 
     @functools.partial(jax.jit, donate_argnums=0)
     def loop(st: FusedState) -> FusedState:
         def cond(s):
-            return (cov(s.table) < target) & (s.round < max_rounds)
+            with jax.named_scope(COVERAGE):
+                return (cov(s.table) < target) & (s.round < max_rounds)
         return jax.lax.while_loop(cond, step, st)
 
     return loop, init_fused_state(n, origin)

@@ -28,6 +28,8 @@ from gossip_tpu.ops.sampling import apply_drop, sample_peers
 from gossip_tpu.parallel.sharded import (_pad_rows, pad_to_mesh,
                                          sharded_alive)
 from gossip_tpu.topology.generators import Topology
+from gossip_tpu.utils.trace import (BUILD, COVERAGE, EXCHANGE, READBACK,
+                                    SAMPLE, span)
 
 
 def make_sharded_packed_round(
@@ -56,74 +58,83 @@ def make_sharded_packed_round(
         table, sched = NE.split_tables(ch, table)
         shard = jax.lax.axis_index(axis_name)
         gids = shard * nl + jnp.arange(nl, dtype=jnp.int32)
-        rkey = jax.random.fold_in(base_key, round_)
-        # liveness in-trace (replicated compute, no O(N) inline constant)
-        if ch is not None:
-            # schedule operands from the table tail (ops/nemesis doc)
-            base_pad = _pad_rows(
-                NE.base_alive_or_ones(fault, n, origin), n_pad, False)
-            alive_l = NE.alive_rows(sched, base_pad, round_)[gids]
-            dp = NE.drop_at(sched, round_)
-            cut = NE.cut_at(sched, round_)
-        else:
-            alive_l = sharded_alive(fault, n, n_pad, origin)[gids]
-            dp, cut = drop_prob, None
+        # the round's fault masks decide, like the drop coins, which
+        # pulls happen: they ride the sample scope.  Liveness in-trace
+        # (replicated compute, no O(N) inline constant)
+        with jax.named_scope(SAMPLE):
+            if ch is not None:
+                # schedule operands from the table tail (ops/nemesis doc)
+                base_pad = _pad_rows(
+                    NE.base_alive_or_ones(fault, n, origin), n_pad, False)
+                alive_l = NE.alive_rows(sched, base_pad, round_)[gids]
+                dp = NE.drop_at(sched, round_)
+                cut = NE.cut_at(sched, round_)
+            else:
+                alive_l = sharded_alive(fault, n, n_pad, origin)[gids]
+                dp, cut = drop_prob, None
         lost = jnp.float32(0.0)
-        visible = jnp.where(alive_l[:, None], packed_l, jnp.uint32(0))
-        packed_all = jax.lax.all_gather(visible, axis_name, tiled=True)
+        with jax.named_scope(EXCHANGE):
+            visible = jnp.where(alive_l[:, None], packed_l, jnp.uint32(0))
+            packed_all = jax.lax.all_gather(visible, axis_name, tiled=True)
         nbrs_l, deg_l = table if have_table else (None, None)
 
-        qkey = jax.random.fold_in(rkey, si_mod.PULL_TAG)
-        partners0 = sample_peers(qkey, gids, topo, k, proto.exclude_self,
-                                 local_nbrs=nbrs_l, local_deg=deg_l)
-        partners = apply_drop(rkey, si_mod.PULL_DROP_TAG, gids,
-                              partners0, dp, n, force=ch is not None)
-        if ch is not None:
-            partners = NE.partition_targets(cut, gids, partners, n)
-        pulled = pull_merge_packed(packed_all, partners, n)
-        partners = jnp.where(alive_l[:, None], partners, n)
-        n_req = jnp.sum(partners < n).astype(jnp.float32)
-        if ch is not None:
-            lost_pull = NE.lost_count(partners0, partners, alive_l, n)
-            if mode == C.ANTI_ENTROPY and proto.period > 1:
-                # quiescent rounds send nothing, so nothing is lost
-                lost_pull = jnp.where((round_ % proto.period) == 0,
-                                      lost_pull, 0.0)
-            lost = lost + lost_pull
-        if mode == C.ANTI_ENTROPY:
-            # Bidirectional reconciliation (twin of models/si_packed.py):
-            # the reverse delta scatters bool contributions and reduces
-            # them with psum_scatter (int counts, OR = count > 0), then
-            # repacks — the pull direction keeps the packed-word
-            # all_gather.  On off-period rounds a lax.cond skips the
-            # collective entirely (replicated predicate, uniform branch).
-            bt = jnp.where(partners < n, partners, n_pad)
+        with jax.named_scope(SAMPLE):
+            rkey = jax.random.fold_in(base_key, round_)
+            qkey = jax.random.fold_in(rkey, si_mod.PULL_TAG)
+            partners0 = sample_peers(qkey, gids, topo, k, proto.exclude_self,
+                                     local_nbrs=nbrs_l, local_deg=deg_l)
+            partners = apply_drop(rkey, si_mod.PULL_DROP_TAG, gids,
+                                  partners0, dp, n, force=ch is not None)
+            if ch is not None:
+                partners = NE.partition_targets(cut, gids, partners, n)
+        with jax.named_scope(EXCHANGE):
+            pulled = pull_merge_packed(packed_all, partners, n)
+        with jax.named_scope(COVERAGE):
+            partners = jnp.where(alive_l[:, None], partners, n)
+            n_req = jnp.sum(partners < n).astype(jnp.float32)
+            if ch is not None:
+                lost_pull = NE.lost_count(partners0, partners, alive_l, n)
+                if mode == C.ANTI_ENTROPY and proto.period > 1:
+                    # quiescent rounds send nothing, so nothing is lost
+                    lost_pull = jnp.where((round_ % proto.period) == 0,
+                                          lost_pull, 0.0)
+                lost = lost + lost_pull
+        with jax.named_scope(EXCHANGE):
+            if mode == C.ANTI_ENTROPY:
+                # Bidirectional reconciliation (twin of models/si_packed.py):
+                # the reverse delta scatters bool contributions and reduces
+                # them with psum_scatter (int counts, OR = count > 0), then
+                # repacks — the pull direction keeps the packed-word
+                # all_gather.  On off-period rounds a lax.cond skips the
+                # collective entirely (replicated predicate, uniform branch).
+                bt = jnp.where(partners < n, partners, n_pad)
 
-            def reverse_delta(_):
-                bcounts = push_counts(n_pad, bt,
-                                      unpack(visible, proto.rumors))
-                return pack(jax.lax.psum_scatter(bcounts, axis_name,
-                                                 scatter_dimension=0,
-                                                 tiled=True) > 0)
+                def reverse_delta(_):
+                    bcounts = push_counts(n_pad, bt,
+                                          unpack(visible, proto.rumors))
+                    return pack(jax.lax.psum_scatter(bcounts, axis_name,
+                                                     scatter_dimension=0,
+                                                     tiled=True) > 0)
 
-            mfac = 3.0
-            if proto.period > 1:
-                on = (round_ % proto.period) == 0
-                back = jax.lax.cond(on, reverse_delta,
-                                    lambda _: jnp.zeros_like(pulled), None)
-                pulled = jnp.where(on, pulled, jnp.uint32(0))
-                n_req = jnp.where(on, n_req, 0.0)
+                mfac = 3.0
+                if proto.period > 1:
+                    on = (round_ % proto.period) == 0
+                    back = jax.lax.cond(on, reverse_delta,
+                                        lambda _: jnp.zeros_like(pulled), None)
+                    pulled = jnp.where(on, pulled, jnp.uint32(0))
+                    n_req = jnp.where(on, n_req, 0.0)
+                else:
+                    back = reverse_delta(None)
+                pulled = pulled | back
             else:
-                back = reverse_delta(None)
-            pulled = pulled | back
-        else:
-            mfac = 2.0
-        pulled = jnp.where(alive_l[:, None], pulled, jnp.uint32(0))
-        msgs_new = msgs + jax.lax.psum(mfac * n_req, axis_name)
-        if ch is not None:
-            return (packed_l | pulled, msgs_new,
-                    jax.lax.psum(lost, axis_name))
-        return packed_l | pulled, msgs_new
+                mfac = 2.0
+            pulled = jnp.where(alive_l[:, None], pulled, jnp.uint32(0))
+            seen_l = packed_l | pulled
+        with jax.named_scope(COVERAGE):
+            msgs_new = msgs + jax.lax.psum(mfac * n_req, axis_name)
+            if ch is not None:
+                return seen_l, msgs_new, jax.lax.psum(lost, axis_name)
+        return seen_l, msgs_new
 
     sh2 = P(axis_name, None)
     rep = P()
@@ -300,21 +311,22 @@ def simulate_until_packed_sharded(proto: ProtocolConfig, topo: Topology,
     from gossip_tpu.ops import round_metrics as RM
     from gossip_tpu.parallel.sharded import _churn_observables
     from gossip_tpu.utils.trace import maybe_aot_timed
-    step, tables = make_sharded_packed_round(proto, topo, mesh, fault,
-                                             run.origin, axis_name,
-                                             tabled=True)
-    n_pad = pad_to_mesh(topo.n, mesh, axis_name)
-    ch = NE.get(fault)
-    alive_pad = (NE.eventual_alive_pad(fault, topo.n, n_pad, run.origin)
-                 if ch is not None
-                 else sharded_alive(fault, topo.n, n_pad, run.origin))
-    init = init_sharded_packed_state(run, proto, topo, mesh, axis_name)
-    target = jnp.float32(run.target_coverage)
-    r = proto.rumors
-    n_shards = mesh.shape[axis_name]
-    rec = (_packed_recorder(proto, n_pad, n_shards)
-           if RM.wanted() else None)
-    obs = _churn_observables(fault, topo.n, n_pad, run.origin)
+    with span(BUILD):
+        step, tables = make_sharded_packed_round(proto, topo, mesh, fault,
+                                                 run.origin, axis_name,
+                                                 tabled=True)
+        n_pad = pad_to_mesh(topo.n, mesh, axis_name)
+        ch = NE.get(fault)
+        alive_pad = (NE.eventual_alive_pad(fault, topo.n, n_pad, run.origin)
+                     if ch is not None
+                     else sharded_alive(fault, topo.n, n_pad, run.origin))
+        init = init_sharded_packed_state(run, proto, topo, mesh, axis_name)
+        target = jnp.float32(run.target_coverage)
+        r = proto.rumors
+        n_shards = mesh.shape[axis_name]
+        rec = (_packed_recorder(proto, n_pad, n_shards)
+               if RM.wanted() else None)
+        obs = _churn_observables(fault, topo.n, n_pad, run.origin)
 
     @jax.jit
     def loop(state, *tbl):
@@ -327,8 +339,9 @@ def simulate_until_packed_sharded(proto: ProtocolConfig, topo: Topology,
         c0 = RM.count_packed(state.seen, alive_t) if rec else None
         def cond(carry):
             s, _, _ = carry
-            return ((coverage_packed(s.seen, r, alive_t) < target)
-                    & (s.round < run.max_rounds))
+            with jax.named_scope(COVERAGE):
+                return ((coverage_packed(s.seen, r, alive_t) < target)
+                        & (s.round < run.max_rounds))
         def body(carry):
             s0, m, cnt = carry
             round0, msgs0 = s0.round, s0.msgs
@@ -345,6 +358,7 @@ def simulate_until_packed_sharded(proto: ProtocolConfig, topo: Topology,
         return jax.lax.while_loop(cond, body, (state, m0, c0))
 
     final, _, _ = maybe_aot_timed(loop, timing, init, *tables, label="packed")
-    return (int(final.round),
-            float(coverage_packed(final.seen, r, alive_pad)),
-            float(final.msgs), final)
+    with span(READBACK):
+        return (int(final.round),
+                float(coverage_packed(final.seen, r, alive_pad)),
+                float(final.msgs), final)

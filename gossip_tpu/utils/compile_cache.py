@@ -49,8 +49,13 @@ import hashlib
 import os
 import pickle
 import sys
+import threading
 import time
 from typing import Optional, Tuple
+
+from gossip_tpu.utils.trace import (ATTRIBUTION, BACKEND_COMPILE, CACHE_KEY,
+                                    COMPILE, EXEC_LOAD, EXEC_STORE, LOWER,
+                                    span)
 
 ENV_VAR = "GOSSIP_COMPILE_CACHE"
 JAX_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
@@ -261,6 +266,11 @@ ATTRIBUTION_FIELDS = ("flops", "bytes_accessed", "argument_bytes",
                       "output_bytes", "temp_bytes", "peak_bytes")
 
 _LAST_COMPILE: Optional[dict] = None
+# (record, executable) whose attribution fields last_compile() fills on
+# its first read: an acquisition outside an active ledger pays no
+# cost/memory analysis unless someone asks for it
+_PENDING_ATTRIBUTION: Optional[tuple] = None
+_ATTRIBUTION_LOCK = threading.Lock()
 
 
 def last_compile() -> Optional[dict]:
@@ -269,8 +279,16 @@ def last_compile() -> Optional[dict]:
     compiled through the chokepoint yet — the sidecar's ``Metrics``
     reply reads this so a steady-state fleet that compiles shows WHAT
     compiled (absent-not-wrong: no compile means no field, never a
-    fabricated one)."""
-    return _LAST_COMPILE
+    fabricated one).  The attribution fields of an acquisition made
+    outside an active ledger are computed here, on the first read."""
+    global _PENDING_ATTRIBUTION
+    with _ATTRIBUTION_LOCK:
+        pending = _PENDING_ATTRIBUTION
+        if pending is not None and pending[0] is _LAST_COMPILE:
+            with span(ATTRIBUTION):
+                _LAST_COMPILE.update(xla_attribution(pending[1]))
+            _PENDING_ATTRIBUTION = None
+        return _LAST_COMPILE
 
 
 def xla_attribution(compiled) -> dict:
@@ -326,12 +344,15 @@ def load_or_compile(jitted, *args, cache_dir: Optional[str] = None,
     """(compiled, status): the AOT chokepoint.  Lower ``jitted`` for
     ``args``, then either deserialize a stored executable (``"hit"``)
     or compile and store it (``"miss"``); ``"disabled"`` when no cache
-    dir is active.  The whole
-    operation is one telemetry ``compile`` span carrying ``cache``/
-    ``fn``/``key`` — a run killed mid-compile shows WHERE in the span
-    tree, and the ledger's span walls decompose warm vs cold without
-    any driver plumbing (utils/trace.aot_timed is the one caller the
-    sharded drivers go through).
+    dir is active.  The whole operation is one ``compile`` span
+    (utils/trace.span: a ``gossip.compile`` profiler event, and a
+    ledger span carrying ``cache``/``fn``/``key`` when the ambient
+    ledger is active), with one span per step inside it — ``lower``,
+    ``cache_key``, ``exec_load``, ``backend_compile``, ``exec_store``,
+    ``attribution`` — so a run killed mid-compile shows WHERE, and a
+    profiler trace names each step's share of the acquisition
+    (utils/trace.aot_timed is the one caller the sharded drivers go
+    through).
 
     The lowering runs unconditionally: it IS the key (module doc), so
     a warm process still pays trace+lower — that residual is exactly
@@ -343,10 +364,11 @@ def load_or_compile(jitted, *args, cache_dir: Optional[str] = None,
     wall, the cache verdict, and the executable's own cost/memory
     attribution (:func:`xla_attribution`, explicit nulls on backends
     without the analyses) — the self-attribution plane
-    docs/OBSERVABILITY.md "XLA cost & memory attribution" documents;
-    :func:`last_compile` keeps the most recent record for the live
-    Metrics surface."""
-    global _LAST_COMPILE
+    docs/OBSERVABILITY.md "XLA cost & memory attribution" documents.
+    The attribution is computed only under an active ledger;
+    otherwise :func:`last_compile`, which keeps the most recent record
+    for the live Metrics surface, computes it on its first read."""
+    global _LAST_COMPILE, _PENDING_ATTRIBUTION
     from gossip_tpu.utils import telemetry
     if cache_dir is None:
         cache_dir = cache_dir_from_env()
@@ -354,23 +376,29 @@ def load_or_compile(jitted, *args, cache_dir: Optional[str] = None,
     name = getattr(jitted, "__name__", None) or type(jitted).__name__
     key = None
     t0 = time.perf_counter()
-    with led.span("compile", fn=name) as ext:
-        # on the END event too: the report's cache table reads rows
-        # from span_end lines (span_start attrs don't ride along)
+    with span(COMPILE) as ext:
+        # on the END event: the report's cache table reads rows from
+        # span_end lines
         ext["fn"] = name
-        lowered = jitted.lower(*args)
+        with span(LOWER):
+            lowered = jitted.lower(*args)
         if not cache_dir:
-            compiled = lowered.compile()
+            with span(BACKEND_COMPILE):
+                compiled = lowered.compile()
             status = "disabled"
         else:
-            key = _fingerprint(lowered.as_text())
+            with span(CACHE_KEY):
+                key = _fingerprint(lowered.as_text())
             path = _entry_path(cache_dir, key)
-            compiled = _try_load(path)
+            with span(EXEC_LOAD):
+                compiled = _try_load(path)
             if compiled is not None:
                 status = "hit"
             else:
-                compiled = lowered.compile()
-                _try_store(path, compiled)
+                with span(BACKEND_COMPILE):
+                    compiled = lowered.compile()
+                with span(EXEC_STORE):
+                    _try_store(path, compiled)
                 status = "miss"
             ext["key"] = key
         ext["cache"] = status
@@ -378,9 +406,14 @@ def load_or_compile(jitted, *args, cache_dir: Optional[str] = None,
     led.counter(f"compile_cache_{status}")
     record = {"label": label or _default_label(jitted, name),
               "fn": name, "key": key, "cache": status,
-              "compile_ms": round(wall_ms, 3),
-              **xla_attribution(compiled)}
-    _LAST_COMPILE = dict(record)
+              "compile_ms": round(wall_ms, 3)}
+    active = getattr(led, "active", False)
+    with _ATTRIBUTION_LOCK:
+        if active:
+            with span(ATTRIBUTION):
+                record.update(xla_attribution(compiled))
+        _LAST_COMPILE = dict(record)
+        _PENDING_ATTRIBUTION = None if active else (_LAST_COMPILE, compiled)
     led.event("xla_compile", sync=False, **record)
     return compiled, status
 

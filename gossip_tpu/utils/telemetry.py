@@ -511,8 +511,7 @@ def percentile(values, q: float) -> float:
     with no samples — the ONE latency-quantile definition the serving
     layer shares: the admission batcher's per-tick ``batch`` events
     (rpc/batcher wait walls) and the load harness's p50/p95/p99 gates
-    (tools/load_harness) must mean the same thing by construction.
-    Same nearest-rank convention as utils/trace.RoundTimer."""
+    (tools/load_harness) must mean the same thing by construction."""
     if not 0.0 <= q <= 1.0:
         raise ValueError(f"q={q} outside [0, 1]")
     vals = sorted(values)

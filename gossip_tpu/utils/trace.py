@@ -1,15 +1,22 @@
-"""Profiling hooks: jax.profiler wrappers for round-level tracing.
+"""Profiling hooks: the program's spans and device scopes, and
+jax.profiler captures.
 
 The reference has no tracing at all (SURVEY.md §5).  These helpers wrap
 ``jax.profiler`` so any driver can capture an XLA trace viewable in
-TensorBoard / Perfetto (`trace(...)`) or annotate host-side phases
-(`annotate(...)`) without importing profiler plumbing everywhere.
+TensorBoard / Perfetto (`trace(...)`) and mark host-side phases with
+:func:`span`, the program's one host span: a ``gossip.<name>``
+``TraceAnnotation`` on the profiler's clock (the clock the device
+planes share) and, when the ambient run ledger is active, the ledger
+span of the same name.  Device work is named with ``jax.named_scope``
+under the scope names below; docs/OBSERVABILITY.md "Program spans and
+device scopes" has the vocabulary.
 """
 
 from __future__ import annotations
 
 import contextlib
 import os
+import sys
 import time
 from typing import Iterator, Optional
 
@@ -19,6 +26,45 @@ from typing import Iterator, Optional
 # backend.py/cli.py).
 
 PROFILE_ENV = "GOSSIP_PROFILE"
+
+# -- span and scope names: the one vocabulary -------------------------
+# Host spans (span(NAME) -> profiler event "gossip.NAME", ledger span NAME).
+SPAN_PREFIX = "gossip."
+BUILD = "build"                    # topology, step/tables, init state
+COMPILE = "compile"                # the whole AOT acquisition
+LOWER = "lower"                    # jitted.lower(*args)
+CACHE_KEY = "cache_key"            # HLO text + store fingerprint
+EXEC_LOAD = "exec_load"            # read + deserialize a stored executable
+BACKEND_COMPILE = "backend_compile"  # lowered.compile()
+EXEC_STORE = "exec_store"          # serialize, verify, write
+ATTRIBUTION = "attribution"        # cost_analysis + memory_analysis
+EXECUTE = "execute"                # compiled(*args) + block_until_ready
+READBACK = "readback"              # coverage and host conversions
+# Device scopes (jax.named_scope), around XLA ops and pallas_call only.
+SAMPLE = "gossip.sample"           # partner draws, drop coins, fault masks
+EXCHANGE = "gossip.exchange"       # gather/all_gather, merge, fused call
+COVERAGE = "gossip.coverage"       # loop-condition coverage, msgs sums
+
+
+@contextlib.contextmanager
+def span(name: str) -> Iterator[dict]:
+    """The program's one host span.  Opens the profiler annotation
+    ``gossip.<name>`` whenever jax is already imported (it never
+    imports jax itself), and, when the ambient ledger is active, the
+    ledger span ``name``; yields that span's end-event dict (a
+    throwaway dict otherwise).  Name only: a ``TraceAnnotation`` with
+    keyword arguments appends ``#k=v#`` to its name, and trace
+    reductions match names."""
+    from gossip_tpu.utils import telemetry
+    jax = sys.modules.get("jax")
+    led = telemetry.current()
+    with (jax.profiler.TraceAnnotation(SPAN_PREFIX + name)
+          if jax is not None else contextlib.nullcontext()):
+        if getattr(led, "active", False):
+            with led.span(name) as ext:
+                yield ext
+        else:
+            yield {}
 
 
 def profile_dir() -> Optional[str]:
@@ -46,24 +92,16 @@ def trace(logdir: Optional[str]) -> Iterator[None]:
 
 
 @contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named region inside an active trace (host + device timeline)."""
-    import jax
-    with jax.profiler.TraceAnnotation(name):
-        yield
-
-
-@contextlib.contextmanager
 def profile(tag: Optional[str] = None) -> Iterator[None]:
     """The $GOSSIP_PROFILE hook: capture a jax.profiler trace of the
-    enclosed block into the ambient directory, with an optional named
-    annotation around the whole block.  A no-op (zero jax import) when
-    GOSSIP_PROFILE is unset — the profiled surfaces (dry-run
+    enclosed block into the ambient directory, with an optional
+    :func:`span` around the whole block.  A no-op (zero jax import)
+    when GOSSIP_PROFILE is unset — the profiled surfaces (dry-run
     families, bench legs) wrap unconditionally.
 
     One capture per ``profile()`` block: jax traces do not nest, so the
     callers wrap the OUTER program (the dry-run body, one bench leg)
-    and mark inner phases with :func:`annotate`."""
+    and mark inner phases with :func:`span`."""
     logdir = profile_dir()
     if not logdir:
         yield
@@ -71,7 +109,7 @@ def profile(tag: Optional[str] = None) -> Iterator[None]:
     import jax
     jax.profiler.start_trace(logdir)
     try:
-        with annotate(tag) if tag else contextlib.nullcontext():
+        with span(tag) if tag else contextlib.nullcontext():
             yield
     finally:
         jax.profiler.stop_trace()
@@ -103,10 +141,11 @@ def aot_timed(jitted, *args, label=None):
     compiled, cache = compile_cache.load_or_compile(jitted, *args,
                                                     label=label)
     compile_s = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    out = compiled(*args)
-    jax.block_until_ready(out)
-    steady_s = time.perf_counter() - t0
+    with span(EXECUTE):
+        t0 = time.perf_counter()
+        out = compiled(*args)
+        jax.block_until_ready(out)
+        steady_s = time.perf_counter() - t0
     return out, compile_s, steady_s, cache
 
 
@@ -117,10 +156,12 @@ def steady_timed(jitted, *args):
     twin of :func:`aot_timed` (whose lower+compile deliberately
     bypasses the executable cache to measure a real compile)."""
     import jax
-    t0 = time.perf_counter()
-    out = jitted(*args)
-    jax.block_until_ready(out)
-    return out, time.perf_counter() - t0
+    with span(EXECUTE):
+        t0 = time.perf_counter()
+        out = jitted(*args)
+        jax.block_until_ready(out)
+        steady_s = time.perf_counter() - t0
+    return out, steady_s
 
 
 def maybe_aot_timed(jitted, timing, *args, label=None):
@@ -189,44 +230,3 @@ def _emit_round_metrics(out, fn_name: str):
     from gossip_tpu.ops import round_metrics
     round_metrics.emit(out, led, fn=fn_name)
 
-
-class RoundTimer:
-    """Wall-clock per-round timing for python-driven loops (the scan/while
-    drivers time whole programs instead — this is for stepwise drivers like
-    utils/checkpoint.run_with_checkpoints)."""
-
-    def __init__(self):
-        self.times: list = []
-        self._t0: Optional[float] = None
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.times.append(time.perf_counter() - self._t0)
-        return False
-
-    @property
-    def mean_ms(self) -> float:
-        return 1e3 * sum(self.times) / max(1, len(self.times))
-
-    def percentile_ms(self, q: float) -> float:
-        """Nearest-rank percentile (q in [0, 1]) of the recorded round
-        walls, in ms; 0.0 with no samples (mean_ms convention).
-        Delegates to the ONE quantile definition
-        (utils/telemetry.percentile — shared with the serving layer's
-        batch events and load-harness gates)."""
-        from gossip_tpu.utils.telemetry import percentile
-        return 1e3 * percentile(self.times, q)
-
-    @property
-    def p50_ms(self) -> float:
-        return self.percentile_ms(0.50)
-
-    @property
-    def p95_ms(self) -> float:
-        """Stepwise drivers report means that hide stragglers (a single
-        wedged round disappears into 100 fast ones); the tail
-        percentile is the straggler detector."""
-        return self.percentile_ms(0.95)

@@ -16,7 +16,8 @@ from gossip_tpu.utils.checkpoint import (load_meta, load_state,
                                          run_with_checkpoints, save_state)
 from gossip_tpu.utils.metrics import (curve_gap, dump_curve_jsonl,
                                       load_curve_jsonl, summarize_curve)
-from gossip_tpu.utils.trace import RoundTimer, annotate, trace
+from gossip_tpu.utils.telemetry import percentile
+from gossip_tpu.utils.trace import span, trace
 
 
 def test_checkpoint_resume_is_bitwise_identical(tmp_path):
@@ -150,39 +151,34 @@ def test_curve_jsonl_rejects_length_mismatch(tmp_path):
     assert not os.path.exists(p), "nothing may be written on rejection"
 
 
-def test_round_timer_percentiles():
-    """p50/p95 alongside mean: stepwise drivers report means that hide
-    stragglers — one wedged round in 100 fast ones moves p95, not the
-    mean."""
-    t = RoundTimer()
-    assert t.mean_ms == t.p50_ms == t.p95_ms == 0.0   # no samples yet
-    t.times = [0.001 * v for v in range(1, 101)]      # 1..100 ms
-    assert t.p50_ms == pytest.approx(50.0)
-    assert t.p95_ms == pytest.approx(95.0)
-    assert t.mean_ms == pytest.approx(50.5)
-    # a straggler dominates the tail, barely moves the mean
-    t.times = [0.001] * 99 + [1.0]
-    assert t.p50_ms == pytest.approx(1.0)
-    assert t.p95_ms == pytest.approx(1.0)
-    assert t.percentile_ms(1.0) == pytest.approx(1000.0)
+def test_percentile_nearest_rank():
+    """The one latency-quantile definition (batch events, load-harness
+    gates): nearest rank, 0.0 with no samples — one straggler in 100
+    fast samples moves p95 only when it is in the top 5%."""
+    assert percentile([], 0.95) == 0.0
+    vals = [0.001 * v for v in range(1, 101)]          # 1..100 ms
+    assert 1e3 * percentile(vals, 0.50) == pytest.approx(50.0)
+    assert 1e3 * percentile(vals, 0.95) == pytest.approx(95.0)
+    vals = [0.001] * 99 + [1.0]
+    assert 1e3 * percentile(vals, 0.95) == pytest.approx(1.0)
+    assert 1e3 * percentile(vals, 1.0) == pytest.approx(1000.0)
     # single sample: every percentile is that sample
-    t.times = [0.004]
-    assert t.p50_ms == t.p95_ms == pytest.approx(4.0)
+    assert percentile([0.004], 0.5) == percentile([0.004], 0.95) == 0.004
     with pytest.raises(ValueError, match="outside"):
-        t.percentile_ms(1.5)
+        percentile(vals, 1.5)
 
 
 def test_trace_smoke(tmp_path, monkeypatch):
     """One real profiler capture smokes the whole observability
     surface: the $GOSSIP_PROFILE ambient hook (trace.profile — what
-    the dry run and bench wrap) and a named annotation inside it.  trace(logdir) shares the same
+    the dry run and bench wrap) and a program span inside it.  trace(logdir) shares the same
     jax.profiler machinery (its CLI path runs under `-m slow`)."""
     from gossip_tpu.utils.trace import profile, profile_dir
     prof = str(tmp_path / "prof")
     monkeypatch.setenv("GOSSIP_PROFILE", prof)
     assert profile_dir() == prof
     with profile("smoke"):
-        with annotate("round"):
+        with span("round"):
             jax.block_until_ready(jax.numpy.arange(8) * 2)
     # trace files land under the ambient dir
     assert any(os.scandir(prof))
@@ -196,11 +192,6 @@ def test_trace_smoke(tmp_path, monkeypatch):
     monkeypatch.setattr(jax.profiler, "start_trace", _started)
     with profile("dark"):
         pass
-    t = RoundTimer()
-    for _ in range(2):
-        with t:
-            pass
-    assert len(t.times) == 2 and t.mean_ms >= 0
 
 
 def test_run_with_checkpoints_named_curve_channels(tmp_path):
