@@ -169,6 +169,10 @@ def _timing_meta(timing: Dict[str, float],
         # drivers that decompose further (sharded_fused._init_and_masks)
         # report the state/mask build — a named slice of the overhead
         out["init_build_s"] = round(timing["init_build_s"], 4)
+    if "exec_memo" in timing:
+        # whether the compile layer's in-process memo served the
+        # executable (utils/compile_cache)
+        out["exec_memo"] = timing["exec_memo"]
     if wall is not None:
         out["driver_overhead_s"] = round(
             max(0.0, wall - timing["compile_s"] - timing["steady_s"]), 4)

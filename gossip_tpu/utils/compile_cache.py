@@ -15,13 +15,26 @@ reuse executables instead of recompiling.  Two layers, one directory:
      (``jax.experimental.serialize_executable``) into
      ``<dir>/aot/<key>``.  A later process lowers, matches the key,
      and DESERIALIZES onto the same devices instead of compiling:
-     warm cost is trace+lower+load.  The key is the sha256 of the
-     **lowered HLO text** plus jax version / backend / the process's
-     device ids (each entry records the devices it executes on) — shapes, dtypes, mesh/axis specs, donation, and
+     warm cost is trace+lower+load, and trace+lower for a key this
+     process already acquired (the memo below).  The key is the
+     sha256 of the **lowered HLO text** plus jax version / backend /
+     the process's device ids (each entry records the devices it
+     executes on) — shapes, dtypes, mesh/axis specs, donation, and
      closed-over constants are all part of the HLO by construction,
      so a hit can never pair a stale executable with changed program
      semantics (warm-vs-cold bitwise equality is pinned in
      tests/test_compile_cache.py).
+
+     In front of the store sits an in-process **memo** of acquired
+     executables: a read-through of the store entry, not a cache of
+     its own.  Each memo entry records the ``(st_ino, st_size,
+     st_mtime_ns)`` of the ``.xbin`` it was loaded from or stored to;
+     a lookup serves it only while one ``os.stat`` of the entry still
+     matches, so a rewritten, corrupt or deleted entry falls through to
+     the disk path exactly as without the memo.  It is filled on a
+     store hit (the loaded executable) and on a miss whose entry was
+     written (the compiled one), holds :data:`MEMO_ENTRIES` executables
+     (least recently used goes first), and is off with the store.
 
 Where the directory is (:func:`resolve_dir`): ``$JAX_COMPILATION_CACHE_DIR``
 when set — JAX reads it itself and this module never overrides it —
@@ -32,9 +45,10 @@ never moves: no home dir, temp name, pid or timestamp.
 ``GOSSIP_COMPILE_CACHE=""`` explicitly disables both layers (cold
 compiles; the same convention as GOSSIP_TELEMETRY).  Every compile
 through the chokepoint emits a telemetry ``compile`` span with
-``cache: hit|miss|disabled`` and bumps a ``compile_cache_<status>``
-counter, so a run ledger shows exactly which process paid which
-compile (tools/telemetry_report.py renders the table).
+``cache: hit|miss|disabled`` and ``memo: true|false`` and bumps a
+``compile_cache_<status>`` counter (and ``compile_cache_memo`` when
+the memo served it), so a run ledger shows exactly which process paid
+which compile (tools/telemetry_report.py renders the table).
 
 Trust note: the AOT store deserializes pickled executables from the
 cache directory — the same trust domain as the persistent XLA cache
@@ -51,6 +65,7 @@ import pickle
 import sys
 import threading
 import time
+from collections import OrderedDict
 from typing import Optional, Tuple
 
 from gossip_tpu.utils.trace import (ATTRIBUTION, BACKEND_COMPILE, CACHE_KEY,
@@ -228,13 +243,15 @@ def _try_load(path: str):
         return None
 
 
-def _try_store(path: str, compiled) -> None:
+def _try_store(path: str, compiled) -> Optional[tuple]:
     """Serialize ``compiled`` to ``path`` atomically (tmp + rename, so
     a killed writer can never leave a torn entry a sibling process
     would then deserialize).  The blob is VERIFIED by deserializing it
     before the rename: the store must never publish an entry its own
-    writer cannot read back.  Failures degrade to "not cached" (the
-    persistent-cache layer still serves the program)."""
+    writer cannot read back.  Returns the written entry's
+    :func:`_identity` (the rename keeps it), or None when nothing was
+    written: failures degrade to "not cached" (the persistent-cache
+    layer still serves the program)."""
     from jax.experimental.serialize_executable import serialize
     try:
         device_ids = _execution_device_ids(compiled)
@@ -244,16 +261,73 @@ def _try_store(path: str, compiled) -> None:
         sys.stderr.write(f"compile_cache: executable does not "
                          f"round-trip ({type(e).__name__}); not "
                          "storing\n")
-        return
+        return None
     try:
         os.makedirs(os.path.dirname(path), exist_ok=True)
         tmp = f"{path}.tmp.{os.getpid()}"
         with open(tmp, "wb") as f:
             pickle.dump((payload, in_tree, out_tree, device_ids), f)
+        ident = _identity(tmp)
         os.replace(tmp, path)
+        return ident
     except Exception as e:
         sys.stderr.write(f"compile_cache: could not store AOT entry "
                          f"({type(e).__name__}: {e})\n")
+        return None
+
+
+# -- the in-process memo of acquired executables ----------------------
+
+# executables kept per process: a few loops a workload alternates
+# between, and a bound for seed studies that compile a program per seed
+MEMO_ENTRIES = 8
+# entry path -> (identity of the .xbin when acquired, executable)
+_MEMO: "OrderedDict[str, tuple]" = OrderedDict()
+_MEMO_LOCK = threading.Lock()
+_THREAD = threading.local()      # this thread's last chokepoint record
+
+
+def _identity(path: str) -> Optional[tuple]:
+    """``(st_ino, st_size, st_mtime_ns)`` of a store entry, or None
+    when it cannot be stat'ed."""
+    try:
+        st = os.stat(path)
+    except OSError:
+        return None
+    return (st.st_ino, st.st_size, st.st_mtime_ns)
+
+
+def _memo_get(path: str):
+    """(executable or None, the entry's identity now).  The memo serves
+    an executable only while the entry it came from is the same file;
+    an entry rewritten, replaced or deleted since drops its memo."""
+    ident = _identity(path)
+    with _MEMO_LOCK:
+        held = _MEMO.get(path)
+        if held is None:
+            return None, ident
+        if ident is None or held[0] != ident:
+            del _MEMO[path]
+            return None, ident
+        _MEMO.move_to_end(path)
+        return held[1], ident
+
+
+def _memo_put(path: str, ident: Optional[tuple], compiled) -> None:
+    if ident is None:
+        return
+    with _MEMO_LOCK:
+        _MEMO[path] = (ident, compiled)
+        _MEMO.move_to_end(path)
+        while len(_MEMO) > MEMO_ENTRIES:
+            _MEMO.popitem(last=False)
+
+
+def last_memo() -> bool:
+    """Whether the calling thread's most recent chokepoint acquisition
+    was served by the memo (``memo`` of its ``xla_compile`` record)."""
+    record = getattr(_THREAD, "record", None)
+    return bool(record and record["memo"])
 
 
 # -- XLA cost & memory attribution (the observability PR) -------------
@@ -356,7 +430,14 @@ def load_or_compile(jitted, *args, cache_dir: Optional[str] = None,
 
     The lowering runs unconditionally: it IS the key (module doc), so
     a warm process still pays trace+lower — that residual is exactly
-    what the dry run's ``first_warm_ms`` budgets bound.
+    what the dry run's ``first_warm_ms`` budgets bound.  A key this
+    process acquired before costs only that: the memo (module doc)
+    hands back the same executable while one ``os.stat`` finds its
+    entry unchanged, and ``exec_load`` then spans the stat and the
+    lookup.  A memo hit is still ``"hit"`` (a hit on the store entry),
+    with ``memo: true`` on the ``compile`` span's end event and the
+    ``xla_compile`` record and a ``compile_cache_memo`` counter;
+    every other acquisition reads ``memo: false``.
 
     Every acquisition here additionally emits one ``xla_compile``
     event (sync=False — this runs inside callers' timed windows) with
@@ -375,6 +456,7 @@ def load_or_compile(jitted, *args, cache_dir: Optional[str] = None,
     led = telemetry.current()
     name = getattr(jitted, "__name__", None) or type(jitted).__name__
     key = None
+    memo = False
     t0 = time.perf_counter()
     with span(COMPILE) as ext:
         # on the END event: the report's cache table reads rows from
@@ -391,22 +473,33 @@ def load_or_compile(jitted, *args, cache_dir: Optional[str] = None,
                 key = _fingerprint(lowered.as_text())
             path = _entry_path(cache_dir, key)
             with span(EXEC_LOAD):
-                compiled = _try_load(path)
+                compiled, ident = _memo_get(path)
+                memo = compiled is not None
+                if not memo:
+                    # the identity stat'ed BEFORE the read: an entry
+                    # replaced in between fails the next lookup's stat
+                    compiled = _try_load(path)
+                    if compiled is not None:
+                        _memo_put(path, ident, compiled)
             if compiled is not None:
                 status = "hit"
             else:
                 with span(BACKEND_COMPILE):
                     compiled = lowered.compile()
                 with span(EXEC_STORE):
-                    _try_store(path, compiled)
+                    _memo_put(path, _try_store(path, compiled), compiled)
                 status = "miss"
             ext["key"] = key
         ext["cache"] = status
+        ext["memo"] = memo
     wall_ms = (time.perf_counter() - t0) * 1e3
     led.counter(f"compile_cache_{status}")
+    if memo:
+        led.counter("compile_cache_memo")
     record = {"label": label or _default_label(jitted, name),
-              "fn": name, "key": key, "cache": status,
+              "fn": name, "key": key, "cache": status, "memo": memo,
               "compile_ms": round(wall_ms, 3)}
+    _THREAD.record = record
     active = getattr(led, "active", False)
     with _ATTRIBUTION_LOCK:
         if active:

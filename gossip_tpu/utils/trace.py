@@ -34,7 +34,7 @@ BUILD = "build"                    # topology, step/tables, init state
 COMPILE = "compile"                # the whole AOT acquisition
 LOWER = "lower"                    # jitted.lower(*args)
 CACHE_KEY = "cache_key"            # HLO text + store fingerprint
-EXEC_LOAD = "exec_load"            # read + deserialize a stored executable
+EXEC_LOAD = "exec_load"            # memo lookup, else read + deserialize
 BACKEND_COMPILE = "backend_compile"  # lowered.compile()
 EXEC_STORE = "exec_store"          # serialize, verify, write
 ATTRIBUTION = "attribution"        # cost_analysis + memory_analysis
@@ -129,7 +129,8 @@ def aot_timed(jitted, *args, label=None):
     goes through the ONE chokepoint ``utils/compile_cache
     .load_or_compile`` — a real XLA compile on a cache miss (or with
     the cache disabled: bitwise the old behavior), a deserialization
-    of the stored executable on a hit — and ``cache`` says which
+    of the stored executable on a hit (or, for a key this process
+    acquired before, the same executable again) — and ``cache`` says which
     (``hit|miss|disabled``), so a warm compile_s can never masquerade
     as a cold one in an artifact.  ``steady_s`` is the device
     execution of one call, identical either way (warm-vs-cold output
@@ -179,10 +180,11 @@ def maybe_aot_timed(jitted, timing, *args, label=None):
     recompile the real re-entry never pays.
 
     On the AOT path ``timing["compile_cache"]`` records the executable
-    store's verdict (``hit|miss|disabled`` — utils/compile_cache):
-    this is the chokepoint every sharded driver's compile goes
-    through, so enabling GOSSIP_COMPILE_CACHE warms them all with no
-    per-driver plumbing."""
+    store's verdict (``hit|miss|disabled`` — utils/compile_cache) and
+    ``timing["exec_memo"]`` whether the in-process memo served it (the
+    chokepoint record's ``memo``): this is the chokepoint every sharded
+    driver's compile goes through, so enabling GOSSIP_COMPILE_CACHE
+    warms them all with no per-driver plumbing."""
     fn_name = getattr(jitted, "__name__", None) or type(jitted).__name__
     if timing is None:
         out = jitted(*args)
@@ -192,8 +194,10 @@ def maybe_aot_timed(jitted, timing, *args, label=None):
         out, timing["steady_s"] = steady_timed(jitted, *args)
         timing.setdefault("compile_s", 0.0)
     else:
+        from gossip_tpu.utils import compile_cache
         (out, timing["compile_s"], timing["steady_s"],
          timing["compile_cache"]) = aot_timed(jitted, *args, label=label)
+        timing["exec_memo"] = compile_cache.last_memo()
     # every driver's wall decomposition reaches the ambient run ledger
     # (utils/telemetry) with no per-driver plumbing; a NullLedger makes
     # this a no-op.  The emit happens AFTER this call's own timed

@@ -4,7 +4,10 @@ Three layers of proof:
 
   1. **Store mechanics** — a miss writes a serialized executable, the
      next identical lookup hits it, corrupt entries degrade to misses,
-     and GOSSIP_COMPILE_CACHE="" disables cleanly.
+     and GOSSIP_COMPILE_CACHE="" disables cleanly.  The in-process memo
+     in front of the store serves a repeated key without a deserialize
+     only while the entry's file is unchanged, and keeps a bounded
+     number of executables.
   2. **Warm-vs-cold bitwise equality, per driver** — every sharded
      driver whose ``timing=`` path goes through the
      ``utils/trace.aot_timed`` chokepoint (sharded / sharded_sparse /
@@ -22,6 +25,7 @@ import json
 import os
 import subprocess
 import sys
+from collections import OrderedDict
 
 import jax
 import jax.numpy as jnp
@@ -60,7 +64,36 @@ def own_cache(tmp_path, monkeypatch, no_persistent_cache):
     return d
 
 
-def test_store_miss_then_hit_bitwise(own_cache):
+@pytest.fixture
+def fresh_memo(monkeypatch):
+    """An empty in-process memo, so no earlier test's executables are
+    in it."""
+    monkeypatch.setattr(compile_cache, "_MEMO", OrderedDict())
+
+
+@pytest.fixture
+def no_memo(monkeypatch, fresh_memo):
+    """A memo that keeps nothing: every store hit deserializes, as a
+    fresh process does."""
+    monkeypatch.setattr(compile_cache, "MEMO_ENTRIES", 0)
+
+
+@pytest.fixture
+def load_calls(monkeypatch):
+    """Every compile_cache._load call (a deserialize onto the devices:
+    a store hit's load, or the store's verify), counted."""
+    calls = []
+    real = compile_cache._load
+
+    def counted(*a, **kw):
+        calls.append(1)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(compile_cache, "_load", counted)
+    return calls
+
+
+def test_store_miss_then_hit_bitwise(own_cache, no_memo):
     f = jax.jit(lambda x: jnp.cumsum(jnp.sin(x) * 3.0))
     x = jnp.arange(64, dtype=jnp.float32)
     c1, s1 = compile_cache.load_or_compile(f, x)
@@ -136,6 +169,166 @@ def test_compile_span_and_counters_reach_ledger(own_cache, tmp_path):
     assert [e["cache"] for e in dts] == ["miss", "hit"]
 
 
+# -- the in-process memo in front of the store -------------------------
+
+def _entry(cache_dir):
+    aot = os.path.join(cache_dir, "aot")
+    (name,) = os.listdir(aot)
+    return os.path.join(aot, name)
+
+
+@pytest.mark.parametrize("filled_on", ["miss", "hit"])
+def test_memo_serves_a_repeated_acquisition(filled_on, own_cache,
+                                            fresh_memo, load_calls,
+                                            monkeypatch):
+    """The second acquisition of a key in one process deserializes
+    nothing: the memo hands back the executable the first one compiled
+    (filled on a miss) or loaded from the store (filled on a hit)."""
+    f = jax.jit(lambda x: jnp.cumsum(jnp.cos(x) * 5.0))
+    x = jnp.arange(64, dtype=jnp.float32)
+    if filled_on == "hit":
+        # a store written by an earlier process: nothing memoized yet
+        bound = compile_cache.MEMO_ENTRIES
+        monkeypatch.setattr(compile_cache, "MEMO_ENTRIES", 0)
+        compile_cache.load_or_compile(f, x)
+        monkeypatch.setattr(compile_cache, "MEMO_ENTRIES", bound)
+    c1, s1 = compile_cache.load_or_compile(f, x)
+    assert (s1, compile_cache.last_memo()) == (filled_on, False)
+    before = len(load_calls)
+    c2, s2 = compile_cache.load_or_compile(f, x)
+    assert len(load_calls) == before          # no _load at all
+    assert (s2, compile_cache.last_memo()) == ("hit", True)
+    assert c2 is c1
+    np.testing.assert_array_equal(np.asarray(c1(x)), np.asarray(c2(x)))
+
+
+def _rewrite(path):
+    """The same bytes in a new file renamed over the entry."""
+    with open(path, "rb") as fh:
+        data = fh.read()
+    with open(path + ".new", "wb") as fh:
+        fh.write(data)
+    os.replace(path + ".new", path)
+
+
+def _corrupt(path):
+    with open(path, "wb") as fh:
+        fh.write(b"not a pickled executable")
+
+
+@pytest.mark.parametrize("change,status,loaded,dropped", [
+    (_rewrite, "hit", True, False),      # reloaded from the disk
+    (_corrupt, "miss", False, True),     # dropped, recompiled, stored
+    (os.remove, "miss", False, False)])  # recompiled, stored
+def test_changed_entry_falls_through_to_the_disk(change, status, loaded,
+                                                 dropped, own_cache,
+                                                 fresh_memo, load_calls,
+                                                 capsys):
+    """A memo entry serves only the file it came from: after the entry
+    is rewritten, corrupted or deleted, the lookup takes the store's
+    own path, and the memo then holds what that path acquired."""
+    f = jax.jit(lambda x: x * 3 + 1)
+    x = jnp.arange(8)
+    c1, _ = compile_cache.load_or_compile(f, x)
+    change(_entry(own_cache))
+    capsys.readouterr()
+    before = len(load_calls)
+    c2, s2 = compile_cache.load_or_compile(f, x)
+    # a reload deserializes the entry; a miss deserializes to verify
+    # the entry it stores
+    assert len(load_calls) == before + 1
+    assert (s2, compile_cache.last_memo()) == (status, False)
+    assert (c2 is not c1) and os.path.exists(_entry(own_cache))
+    assert ("dropping corrupt" in capsys.readouterr().err) == dropped
+    assert (status == "hit") == loaded
+    np.testing.assert_array_equal(np.asarray(c2(x)), np.arange(8) * 3 + 1)
+    c3, s3 = compile_cache.load_or_compile(f, x)
+    assert c3 is c2 and (s3, compile_cache.last_memo()) == ("hit", True)
+
+
+def test_memo_bound_evicts_the_oldest_key(own_cache, fresh_memo,
+                                          load_calls, monkeypatch):
+    monkeypatch.setattr(compile_cache, "MEMO_ENTRIES", 2)
+    x = jnp.arange(8, dtype=jnp.float32)
+    fns = [jax.jit(lambda v, k=k: v * k) for k in (2.0, 3.0, 5.0)]
+    for fn in fns:
+        assert compile_cache.load_or_compile(fn, x)[1] == "miss"
+    assert len(compile_cache._MEMO) == 2
+    before = len(load_calls)
+    _, s = compile_cache.load_or_compile(fns[0], x)   # evicted: disk
+    assert (s, compile_cache.last_memo()) == ("hit", False)
+    assert len(load_calls) == before + 1
+    _, s = compile_cache.load_or_compile(fns[0], x)   # memoized again
+    assert (s, compile_cache.last_memo()) == ("hit", True)
+    assert len(load_calls) == before + 1
+
+
+def _refuse_to_store(monkeypatch):
+    def no_round_trip(compiled):
+        raise RuntimeError("no round trip")
+    monkeypatch.setattr(compile_cache, "_execution_device_ids",
+                        no_round_trip)
+
+
+@pytest.mark.parametrize("store,status", [("disabled", "disabled"),
+                                          ("refused", "miss")])
+def test_nothing_memoized_without_a_written_entry(store, status, own_cache,
+                                                  fresh_memo, monkeypatch):
+    """A disabled store keeps meaning honest cold compiles, and a miss
+    whose entry the store would not write is not memoized either."""
+    if store == "disabled":
+        monkeypatch.setenv(compile_cache.ENV_VAR, "")
+    else:
+        _refuse_to_store(monkeypatch)
+    f = jax.jit(lambda x: x * 11)
+    for _ in range(2):
+        _, s = compile_cache.load_or_compile(f, jnp.arange(4))
+        assert (s, compile_cache.last_memo()) == (status, False)
+    assert len(compile_cache._MEMO) == 0
+
+
+def test_memo_reaches_ledger_and_timing(own_cache, fresh_memo, tmp_path):
+    p = str(tmp_path / "led.jsonl")
+    led = telemetry.Ledger(p)
+    prev = telemetry.activate(led)
+    try:
+        f = jax.jit(lambda x: x - 9)
+        timings = [{}, {}]
+        for timing in timings:
+            maybe_aot_timed(f, timing, jnp.arange(4))
+    finally:
+        telemetry.activate(prev)
+        led.close()
+    assert [t["exec_memo"] for t in timings] == [False, True]
+    assert [t["compile_cache"] for t in timings] == ["miss", "hit"]
+    events = telemetry.load_ledger(p)
+    spans = [e for e in events if e["ev"] == "span_end"
+             and e["name"] == "compile"]
+    assert [e["memo"] for e in spans] == [False, True]
+    records = [e for e in events if e["ev"] == "xla_compile"]
+    assert [e["memo"] for e in records] == [False, True]
+    counters = {e["name"]: e["total"] for e in events
+                if e["ev"] == "counter"}
+    assert counters["compile_cache_memo"] == 1
+    assert counters["compile_cache_hit"] == 1
+
+
+def test_report_meta_says_the_memo_served_a_fresh_seed(own_cache,
+                                                       fresh_memo):
+    """The packed loop does not depend on the seed: a second
+    simulation on another seed is served by the memo, and the report's
+    meta says so (the benchmark's exec_memo_share reads it)."""
+    from gossip_tpu.backend import run_simulation
+    from gossip_tpu.config import TopologyConfig
+    proto = ProtocolConfig(mode="pull", fanout=1)
+    tc = TopologyConfig(family="complete", n=4096)
+    metas = [run_simulation("jax-tpu", proto, tc,
+                            RunConfig(seed=seed, max_rounds=64)).meta
+             for seed in (5, 6)]
+    assert metas[0]["engine"] == metas[1]["engine"] == "bit-packed"
+    assert [m["exec_memo"] for m in metas] == [False, True]
+
+
 # -- warm-vs-cold bitwise equality, driver by driver -------------------
 
 def _mesh(n_devices=4):
@@ -197,7 +390,7 @@ DRIVERS = {"sharded": _drive_sharded, "sharded_sparse": _drive_sparse,
     "name", [pytest.param(n, marks=pytest.mark.slow)
              if n == "pod_sweep_2d" else n for n in sorted(DRIVERS)])
 def test_driver_warm_vs_cold_bitwise(name, tmp_path, monkeypatch,
-                                     no_persistent_cache):
+                                     no_persistent_cache, no_memo):
     """Cold (store-miss: a real XLA compile) and warm (store-hit: the
     deserialized executable) executions of the same driver call must
     agree BITWISE on every output — the warm path can change walls,
