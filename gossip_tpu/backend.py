@@ -607,6 +607,7 @@ def _run_jax_with_topo(proto: ProtocolConfig, tc: TopologyConfig,
 
         from gossip_tpu.models.rumor import (simulate_curve_rumor,
                                              simulate_until_rumor)
+        timing: Dict[str, float] = {}
         t0 = time.perf_counter()
         if want_curve:
             if n_dev > 1:
@@ -637,28 +638,32 @@ def _run_jax_with_topo(proto: ProtocolConfig, tc: TopologyConfig,
                 mesh = make_mesh(n_dev)
                 rounds_ext, cov, residue, msgs_f, final = (
                     simulate_until_rumor_sharded(proto, topo, run, mesh,
-                                                 fault))
+                                                 fault, timing=timing))
             else:
                 rounds_ext, cov, residue, msgs_f, final = (
-                    simulate_until_rumor(proto, topo, run, fault))
+                    simulate_until_rumor(proto, topo, run, fault,
+                                         timing=timing))
             wall = time.perf_counter() - t0
             curve = None
             # rounds reports rounds-to-extinction; -1 only if hot pairs
             # survived to max_rounds (no self-termination).  Slice to the
             # real n rows: the sharded state pads to the mesh.
-            hot_left = float(jnp.mean(jnp.any(final.hot[:tc.n], axis=1)
-                                      .astype(jnp.float32)))
+            from gossip_tpu.utils.trace import READBACK, span
+            with span(READBACK):
+                hot_left = float(jnp.mean(jnp.any(final.hot[:tc.n], axis=1)
+                                          .astype(jnp.float32)))
             rounds = rounds_ext if hot_left == 0.0 else -1
         return RunReport(
             backend="jax-tpu", mode="rumor", n=tc.n, rounds=rounds,
             coverage=cov, msgs=msgs_f, wall_s=round(wall, 4), curve=curve,
             meta={"clock": "rounds", "devices": n_dev,
-                  "msgs_counts": "transmissions",
+                  "msgs_counts": "transmissions", "engine": "rumor-xla",
                   "rounds_semantics": "extinction",
                   "variant": proto.rumor_variant, "rumor_k": proto.rumor_k,
                   "residue": round(residue, 6),
                   "hot_fraction_final": hot_left,
-                  "terminated": hot_left == 0.0})
+                  "terminated": hot_left == 0.0,
+                  **_timing_meta(timing, wall)})
 
     if n_dev > 1 and _exchange == "sparse":
         from gossip_tpu.parallel.sharded import make_mesh

@@ -126,7 +126,9 @@ class ProtocolConfig:
     # (node, rumor) stops spreading — becomes removed, SIR — once its
     # unnecessary-contact counter reaches `rumor_k` (Demers et al. §1.4
     # counter death).  'feedback' counts only pushes whose recipient
-    # already knew the rumor; 'blind' counts every push.
+    # already knew the rumor; 'blind' counts every attempted push, lost
+    # ones included, so a pair sends exactly `rumor_k` times
+    # (memberlist's retransmit limit).
     rumor_k: int = 2
     rumor_variant: str = "feedback"
 

@@ -11,8 +11,12 @@ unnecessary-contact counter:
 
 * ``feedback``: a push whose recipient ALREADY knew the rumor increments
   the sender's counter for it; ``rumor_k`` such hits remove it.
-* ``blind``: every push increments the counter — removal after exactly
-  ``rumor_k`` pushes, regardless of outcome.
+* ``blind``: every attempted push increments the counter, whether the
+  link or a partition then loses it (the sender cannot tell) — removal
+  after exactly ``rumor_k`` pushes.  In the round that would pass
+  ``rumor_k`` a pair pushes to its first ``rumor_k - cnt`` targets only.
+  This is memberlist's ``TransmitLimitedQueue``: a broadcast leaves the
+  queue after its ``retransmitLimit``-th send.
 
 The run self-terminates when the hot set is empty.  The classic quality
 metric is the **residue** s(infinity): the fraction of nodes never
@@ -48,6 +52,8 @@ from gossip_tpu.models.state import alive_mask, bind_tables
 from gossip_tpu.ops.propagate import push_delta
 from gossip_tpu.ops.sampling import apply_drop, sample_peers
 from gossip_tpu.topology.generators import Topology
+from gossip_tpu.utils.trace import (BUILD, COVERAGE, EXCHANGE, READBACK,
+                                    SAMPLE, SIR, span)
 
 RUMOR_PUSH_TAG, RUMOR_DROP_TAG = 11, 12
 
@@ -74,6 +80,18 @@ def init_rumor_state(run: RunConfig, proto: ProtocolConfig,
                       msgs=jnp.float32(0.0))
 
 
+def blind_sends(payload: jax.Array, targets0: jax.Array, cnt: jax.Array,
+                rumor_k: int, n: int) -> jax.Array:
+    """bool[N, k, R]: the blind counter's pushes this round — pair
+    (i, r) sends to its j-th drawn target iff it is hot on an alive
+    node, the target exists, and ``j < rumor_k - cnt[i, r]``.
+    ``targets0`` are the targets before the drop coin and the cut: a
+    send counts whether or not it arrives."""
+    slot = jnp.arange(targets0.shape[1], dtype=jnp.int32)[None, :, None]
+    return (payload[:, None, :] & (targets0 < n)[:, :, None]
+            & (slot < (rumor_k - cnt)[:, None, :]))
+
+
 def make_rumor_round(proto: ProtocolConfig, topo: Topology,
                      fault: Optional[FaultConfig] = None,
                      origin: int = 0, tabled: bool = False):
@@ -98,67 +116,85 @@ def make_rumor_round(proto: ProtocolConfig, topo: Topology,
         tbl, sched = NE.split_tables(ch, tbl)
         nbrs_t, deg_t = tbl if tbl else (None, None)
         ids = jnp.arange(n, dtype=jnp.int32)
-        rkey = jax.random.fold_in(state.base_key, state.round)
         seen, hot, cnt = state.seen, state.hot, state.cnt
-        if ch is not None:
-            # churn path: per-round liveness / drop prob / cut from the
-            # schedule operands.  A churn-down node loses its hot
-            # (forwarding) state like a process crash; its seen set
-            # persists (the durable dedup store, main.go:22-26).
-            alive = NE.alive_rows(sched, NE.base_alive_or_ones(
-                fault, n, origin), state.round)
-            dp = NE.drop_at(sched, state.round)
-            cut = NE.cut_at(sched, state.round)
-        else:
-            alive = alive_mask(fault, n, origin)
-            dp, cut = drop_prob, None
-        lost = jnp.float32(0.0)
+        # the round's fault masks decide, like the drop coins, which
+        # pushes arrive: they ride the sample scope
+        with jax.named_scope(SAMPLE):
+            rkey = jax.random.fold_in(state.base_key, state.round)
+            if ch is not None:
+                # churn path: per-round liveness / drop prob / cut from
+                # the schedule operands.  A churn-down node loses its hot
+                # (forwarding) state like a process crash; its seen set
+                # persists (the durable dedup store, main.go:22-26).
+                alive = NE.alive_rows(sched, NE.base_alive_or_ones(
+                    fault, n, origin), state.round)
+                dp = NE.drop_at(sched, state.round)
+                cut = NE.cut_at(sched, state.round)
+            else:
+                alive = alive_mask(fault, n, origin)
+                dp, cut = drop_prob, None
 
-        # What this node forwards this round: its hot rumors (dead nodes
-        # go dark — neither send nor count).
-        payload = hot if alive is None else hot & alive[:, None]   # [N, R]
+            # What this node forwards this round: its hot rumors (dead
+            # nodes go dark — neither send nor count).
+            payload = hot if alive is None else hot & alive[:, None]
 
-        pkey = jax.random.fold_in(rkey, RUMOR_PUSH_TAG)
-        targets0 = sample_peers(pkey, ids, topo, k, proto.exclude_self,
-                                local_nbrs=nbrs_t, local_deg=deg_t)
-        targets = apply_drop(rkey, RUMOR_DROP_TAG, ids, targets0,
-                             dp, n, force=ch is not None)         # [N, k]
-        if ch is not None:
-            targets = NE.partition_targets(cut, ids, targets, n)
-        sender_active = jnp.any(payload, axis=1)                   # [N]
-        valid = (targets < n) & sender_active[:, None]             # [N, k]
-        safe_t = jnp.where(valid, targets, 0)
+            pkey = jax.random.fold_in(rkey, RUMOR_PUSH_TAG)
+            targets0 = sample_peers(pkey, ids, topo, k, proto.exclude_self,
+                                    local_nbrs=nbrs_t, local_deg=deg_t)
+            targets = apply_drop(rkey, RUMOR_DROP_TAG, ids, targets0,
+                                 dp, n, force=ch is not None)     # [N, k]
+            if ch is not None:
+                targets = NE.partition_targets(cut, ids, targets, n)
+            sender_active = jnp.any(payload, axis=1)               # [N]
 
-        # Deliveries: scatter-OR of the hot payload into the targets.
-        delta = push_delta(n, jnp.where(valid, targets, n), payload)
-        if alive is not None:
-            delta = delta & alive[:, None]     # dead nodes receive nothing
+        # What goes to each target: feedback pushes the whole hot row to
+        # every target; blind only within each pair's remaining quota.
+        with jax.named_scope(SIR):
+            if feedback:
+                sends, sent = payload, sender_active[:, None]
+            else:
+                sends = blind_sends(payload, targets0, cnt, kk, n)
+                sent = jnp.any(sends, axis=2)                      # [N, k]
+        valid = (targets < n) & sent                               # [N, k]
 
-        # Counter update against ROUND-START knowledge (synchronous
-        # semantics: all pushes observe the same snapshot).
-        #   feedback: count pushes whose recipient already knew the rumor;
-        #   blind:    count every push of a hot rumor.
-        if feedback:
-            knew = seen[safe_t] & valid[:, :, None]                # [N,k,R]
-            hits = jnp.sum(knew, axis=1, dtype=jnp.int32)          # [N, R]
-        else:
-            hits = jnp.sum(valid, axis=1, dtype=jnp.int32)[:, None]
-        cnt = cnt + jnp.where(payload, hits, 0)
+        with jax.named_scope(EXCHANGE):
+            # Deliveries: scatter-OR of the pushed rows into the targets.
+            delta = push_delta(n, jnp.where(valid, targets, n), sends)
+            if alive is not None:
+                delta = delta & alive[:, None]  # dead nodes receive nothing
+            if feedback:
+                # recipients' ROUND-START knowledge (synchronous
+                # semantics: all pushes observe the same snapshot)
+                safe_t = jnp.where(valid, targets, 0)
+                knew = seen[safe_t] & valid[:, :, None]            # [N,k,R]
 
-        # Loss of interest (removal) + fresh infections become hot.  Dead
-        # nodes can hold no hot bits (a dead multi-rumor origin would
-        # otherwise stay "hot" forever with its payload masked, and the
-        # extinction loop would never terminate); like SI, a rumor whose
-        # origin is dead simply never spreads.
-        new = delta & ~seen
-        hot = (hot & (cnt < kk)) | new
-        if alive is not None:
-            hot = hot & alive[:, None]
-        msgs = state.msgs + jnp.sum(valid).astype(jnp.float32)
-        if ch is not None:
-            lost = lost + NE.lost_count(targets0, targets,
-                                        sender_active, n)
-        out = RumorState(seen=seen | delta, hot=hot, cnt=cnt,
+        with jax.named_scope(SIR):
+            # feedback counts pushes whose recipient already knew the
+            # rumor; blind counts every attempted push
+            if feedback:
+                hits = jnp.sum(knew, axis=1, dtype=jnp.int32)      # [N, R]
+                cnt = cnt + jnp.where(payload, hits, 0)
+            else:
+                cnt = cnt + jnp.sum(sends, axis=1, dtype=jnp.int32)
+
+            # Loss of interest (removal) + fresh infections become hot.
+            # Dead nodes can hold no hot bits (a dead multi-rumor origin
+            # would otherwise stay "hot" forever with its payload masked,
+            # and the extinction loop would never terminate); like SI, a
+            # rumor whose origin is dead simply never spreads.
+            new = delta & ~seen
+            hot = (hot & (cnt < kk)) | new
+            if alive is not None:
+                hot = hot & alive[:, None]
+            seen = seen | delta
+
+        with jax.named_scope(COVERAGE):
+            msgs = state.msgs + jnp.sum(valid).astype(jnp.float32)
+            if ch is not None:
+                lost = NE.lost_count(jnp.where(sent, targets0, n),
+                                     jnp.where(sent, targets, n),
+                                     sender_active, n)
+        out = RumorState(seen=seen, hot=hot, cnt=cnt,
                          round=state.round + 1,
                          base_key=state.base_key, msgs=msgs)
         return (out, lost) if ch is not None else out
@@ -176,36 +212,51 @@ def rumor_coverage(seen: jax.Array,
     return jnp.min(per_rumor)
 
 
+@jax.jit
+def _final_coverage(seen: jax.Array, alive: Optional[jax.Array]):
+    with jax.named_scope(COVERAGE):
+        return rumor_coverage(seen, alive)
+
+
 def simulate_until_rumor(proto: ProtocolConfig, topo: Topology,
                          run: RunConfig,
-                         fault: Optional[FaultConfig] = None):
+                         fault: Optional[FaultConfig] = None,
+                         timing: Optional[dict] = None):
     """Run to extinction (no hot pairs left) or max_rounds, one compiled
     while_loop.  Returns (rounds, coverage, residue, msgs, final_state):
     ``residue`` is the never-informed fraction at termination — the
-    rumor-mongering quality metric (worst rumor)."""
+    rumor-mongering quality metric (worst rumor).  ``timing``: pass a
+    dict for the compile/steady AOT split (utils.trace.aot_timed).  The
+    seed rides the init state's key, so one executable serves every
+    seed of a configuration."""
     from gossip_tpu.ops import nemesis as NE
-    step, tbl = make_rumor_round(proto, topo, fault, run.origin, tabled=True)
-    step = NE.drop_lost(step, NE.get(fault))
-    init = init_rumor_state(run, proto, topo.n)
+    from gossip_tpu.utils.trace import maybe_aot_timed
+    with span(BUILD):
+        step, tbl = make_rumor_round(proto, topo, fault, run.origin,
+                                     tabled=True)
+        step = NE.drop_lost(step, NE.get(fault))
+        init = init_rumor_state(run, proto, topo.n)
+        # alive_mask, NOT static_death_draw: the kernel pins the origin
+        # alive, so the metric weighting must too (matches the sharded
+        # twin and every SI curve path); under churn the eventual alive
+        # set (ops/nemesis.metric_alive — heal-convergence denominator)
+        alive = NE.metric_alive(fault, topo.n, run.origin)
 
     @jax.jit
     def loop(state, *tables):
         def cond(s):
-            return jnp.any(s.hot) & (s.round < run.max_rounds)
+            with jax.named_scope(COVERAGE):
+                return jnp.any(s.hot) & (s.round < run.max_rounds)
 
         def body(s):
             return step(s, *tables)
 
         return jax.lax.while_loop(cond, body, state)
 
-    final = loop(init, *tbl)
-    # alive_mask, NOT static_death_draw: the kernel pins the origin alive,
-    # so the metric weighting must too (matches the sharded twin and
-    # every SI curve path); under churn the eventual alive set
-    # (ops/nemesis.metric_alive — heal-convergence denominator)
-    alive = NE.metric_alive(fault, topo.n, run.origin)
-    cov = float(rumor_coverage(final.seen, alive))
-    return (int(final.round), cov, 1.0 - cov, float(final.msgs), final)
+    final = maybe_aot_timed(loop, timing, init, *tbl, label="rumor")
+    with span(READBACK):
+        cov = float(_final_coverage(final.seen, alive))
+        return (int(final.round), cov, 1.0 - cov, float(final.msgs), final)
 
 
 def checkpointed_rumor(proto: ProtocolConfig, topo: Topology,

@@ -26,11 +26,14 @@ import jax.numpy as jnp
 
 
 def _flat_payload(targets: jax.Array, payload: jax.Array, dtype) -> tuple:
-    """[Nl,k] targets + [Nl,R] payload -> flat ([Nl*k], [Nl*k,R]) pairs."""
+    """[Nl,k] targets + [Nl,R] (or per-target [Nl,k,R]) payload -> flat
+    ([Nl*k], [Nl*k,R]) pairs."""
     nl, k = targets.shape
-    r = payload.shape[1]
+    r = payload.shape[-1]
     flat_t = targets.reshape(-1)
-    flat_p = jnp.broadcast_to(payload[:, None, :], (nl, k, r))
+    if payload.ndim == 2:
+        payload = payload[:, None, :]
+    flat_p = jnp.broadcast_to(payload, (nl, k, r))
     return flat_t, flat_p.reshape(nl * k, r).astype(dtype)
 
 
@@ -38,10 +41,11 @@ def push_delta(n: int, targets: jax.Array, payload: jax.Array) -> jax.Array:
     """Single-device push: bool[N,R] delta via scatter-max.
 
     ``targets`` holds global ids in [0, n) or the sentinel ``n`` (dropped).
-    ``payload[i]`` is what node i pushes (its active digest row).
+    ``payload[i]`` is what node i pushes (its active digest row), or
+    ``payload[i, j]`` what it pushes to its j-th target.
     """
     flat_t, flat_p = _flat_payload(targets, payload, jnp.bool_)
-    zero = jnp.zeros((n, payload.shape[1]), jnp.bool_)
+    zero = jnp.zeros((n, payload.shape[-1]), jnp.bool_)
     return zero.at[flat_t].max(flat_p, mode="drop")
 
 
@@ -52,7 +56,7 @@ def push_counts(n: int, targets: jax.Array, payload: jax.Array) -> jax.Array:
     int32 because several pushers may hit the same row in the same round.
     """
     flat_t, flat_p = _flat_payload(targets, payload, jnp.int32)
-    zero = jnp.zeros((n, payload.shape[1]), jnp.int32)
+    zero = jnp.zeros((n, payload.shape[-1]), jnp.int32)
     return zero.at[flat_t].add(flat_p, mode="drop")
 
 

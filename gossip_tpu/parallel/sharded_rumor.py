@@ -25,8 +25,8 @@ from jax import shard_map
 from gossip_tpu import config as C
 from gossip_tpu.config import FaultConfig, ProtocolConfig, RunConfig
 from gossip_tpu.models.rumor import (RUMOR_DROP_TAG, RUMOR_PUSH_TAG,
-                                     RumorState, init_rumor_state,
-                                     rumor_coverage)
+                                     RumorState, blind_sends,
+                                     init_rumor_state, rumor_coverage)
 from gossip_tpu.models.state import bind_tables
 from gossip_tpu.ops.propagate import push_counts
 from gossip_tpu.ops.sampling import apply_drop, sample_peers
@@ -85,11 +85,16 @@ def make_sharded_rumor_round(proto: ProtocolConfig, topo: Topology,
         if ch is not None:
             targets = NE.partition_targets(cut, gids, targets, n)
         sender_active = jnp.any(payload, axis=1)
-        valid = (targets < n) & sender_active[:, None]
+        if feedback:
+            sends, sent = payload, sender_active[:, None]
+        else:
+            sends = blind_sends(payload, targets0, cnt_l, kk, n)
+            sent = jnp.any(sends, axis=2)
+        valid = (targets < n) & sent
 
-        # Deliveries: scatter counts of the hot payload, reduce-scatter.
+        # Deliveries: scatter counts of the pushed rows, reduce-scatter.
         counts = push_counts(n_pad, jnp.where(valid, targets, n_pad),
-                             payload)
+                             sends)
         counts_l = jax.lax.psum_scatter(counts, axis_name,
                                         scatter_dimension=0, tiled=True)
         delta = (counts_l > 0) & alive_l[:, None]
@@ -101,9 +106,9 @@ def make_sharded_rumor_round(proto: ProtocolConfig, topo: Topology,
             safe_t = jnp.where(valid, targets, 0)
             knew = seen_all[safe_t] & valid[:, :, None]        # [nl,k,R]
             hits = jnp.sum(knew, axis=1, dtype=jnp.int32)
+            cnt_l = cnt_l + jnp.where(payload, hits, 0)
         else:
-            hits = jnp.sum(valid, axis=1, dtype=jnp.int32)[:, None]
-        cnt_l = cnt_l + jnp.where(payload, hits, 0)
+            cnt_l = cnt_l + jnp.sum(sends, axis=1, dtype=jnp.int32)
 
         new = delta & ~seen_l
         # dead nodes hold no hot bits (extinction-loop liveness; matches
@@ -112,7 +117,8 @@ def make_sharded_rumor_round(proto: ProtocolConfig, topo: Topology,
         msgs_new = msgs + jax.lax.psum(
             jnp.sum(valid).astype(jnp.float32), axis_name)
         if ch is not None:
-            lost = lost + NE.lost_count(targets0, targets,
+            lost = lost + NE.lost_count(jnp.where(sent, targets0, n),
+                                        jnp.where(sent, targets, n),
                                         sender_active, n)
             return (seen_l | delta, hot_l, cnt_l, msgs_new,
                     jax.lax.psum(lost, axis_name))
@@ -166,8 +172,8 @@ def _rumor_recorder(proto: ProtocolConfig, n_pad: int,
     (ops/round_metrics).  The kernel's own hit counters make ``dup``
     EXACT for the feedback variant — ``cnt`` grows by precisely the
     contacts whose recipient already knew — while blind's counter
-    counts all contacts, so there the estimator subtracts the round's
-    new infections (module-doc upper bound).  The previous round's
+    counts all attempted contacts, so there the estimator subtracts the
+    round's new infections (module-doc upper bound).  The previous round's
     seen/cnt totals ride the carry as two scalars
     (parallel/sharded._dense_recorder liveness rationale)."""
     from gossip_tpu.ops import round_metrics as RM

@@ -44,6 +44,7 @@ READBACK = "readback"              # coverage and host conversions
 SAMPLE = "gossip.sample"           # partner draws, drop coins, fault masks
 EXCHANGE = "gossip.exchange"       # gather/all_gather, merge, fused call
 COVERAGE = "gossip.coverage"       # loop-condition coverage, msgs sums
+SIR = "gossip.sir"                 # rumor counters, removal, hot/seen update
 
 
 @contextlib.contextmanager

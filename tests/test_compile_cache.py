@@ -329,6 +329,28 @@ def test_report_meta_says_the_memo_served_a_fresh_seed(own_cache,
     assert [m["exec_memo"] for m in metas] == [False, True]
 
 
+def test_rumor_report_meta_says_the_memo_served_a_fresh_seed(own_cache,
+                                                             fresh_memo):
+    """The rumor loop keeps the seed in its init state's key, so the
+    push engine's seed study is served by the memo too, under a
+    nemesis schedule, with the compile/steady split in the report."""
+    from gossip_tpu.backend import run_simulation
+    from gossip_tpu.config import ChurnConfig, TopologyConfig
+    proto = ProtocolConfig(mode="rumor", rumor_variant="blind", fanout=3,
+                           rumor_k=8)
+    tc = TopologyConfig(family="complete", n=2048)
+    fault = FaultConfig(drop_prob=0.02, churn=ChurnConfig(
+        events=((1, 1, 4), (2, 2, -1)), partitions=((0, 6, 1024),),
+        ramp=(0, 4, 0.0, 0.1)))
+    metas = [run_simulation("jax-tpu", proto, tc,
+                            RunConfig(seed=seed, max_rounds=64), fault).meta
+             for seed in (5, 6)]
+    assert metas[0]["engine"] == metas[1]["engine"] == "rumor-xla"
+    assert [m["exec_memo"] for m in metas] == [False, True]
+    for m in metas:
+        assert m["compile_s"] >= 0.0 and m["steady_wall_s"] > 0.0
+
+
 # -- warm-vs-cold bitwise equality, driver by driver -------------------
 
 def _mesh(n_devices=4):

@@ -53,17 +53,70 @@ def test_terminates_with_low_residue_feedback():
 
 
 def test_blind_message_bound_and_more_residue():
-    # Blind counter k: every (node, rumor) pushes at most
-    # fanout * ceil(k / fanout) <= k + fanout - 1 times — a hard traffic
-    # bound SI push has no analog of.
+    # Blind counter k: every (node, rumor) pushes exactly k times (the
+    # last round's fanout is cut to the quota) — a hard traffic bound SI
+    # push has no analog of.
     n, k, fanout = 4096, 2, 2
     rounds, cov_b, residue_b, msgs, _ = _run(n=n, variant="blind", k=k,
                                              fanout=fanout)
-    assert msgs <= n * (k + fanout - 1)
+    assert msgs <= n * k
     # feedback at the same k informs at least as many nodes (it only
     # stops on evidence of redundancy, blind stops unconditionally)
     _, cov_f, _, _, _ = _run(n=n, variant="feedback", k=k, fanout=fanout)
     assert cov_f >= cov_b
+
+
+@pytest.mark.parametrize("drop_prob", [0.0, 0.5])
+def test_blind_counter_sends_exactly_rumor_k(drop_prob):
+    """memberlist's retransmit limit: with fanout 3 and rumor_k 5 every
+    informed pair spends exactly 5 sends — 3 then 2, never 6 — and a
+    send lost to the link counts like one that arrives.  Fault-free,
+    every send is a message."""
+    fault = FaultConfig(drop_prob=drop_prob) if drop_prob else None
+    rounds, cov, _, msgs, final = _run(n=1500, variant="blind", k=5,
+                                       fanout=3, fault=fault, seed=3)
+    seen, cnt = np.asarray(final.seen), np.asarray(final.cnt)
+    assert not np.asarray(final.hot).any() and rounds > 0
+    assert (cnt[seen] == 5).all() and (cnt[~seen] == 0).all()
+    if drop_prob:
+        assert msgs < 5 * seen.sum()
+    else:
+        assert msgs == 5.0 * seen.sum()
+
+
+def test_sharded_blind_counter_bitwise_parity():
+    """The sharded twin cuts the last round's fanout to the same quota
+    and counts the same lost sends, bitwise, under a partition
+    nemesis with drops, on a padded 8-device mesh."""
+    from gossip_tpu.config import ChurnConfig
+    from gossip_tpu.parallel.sharded import make_mesh
+    from gossip_tpu.parallel.sharded_rumor import (
+        init_sharded_rumor_state, make_sharded_rumor_round)
+
+    n = 500                        # NOT divisible by 8: padding exercised
+    proto = ProtocolConfig(mode="rumor", fanout=3, rumor_k=4,
+                           rumor_variant="blind")
+    fault = FaultConfig(drop_prob=0.3, churn=ChurnConfig(
+        events=((1, 1, 4), (2, 2, -1)), partitions=((0, 3, n // 2),)))
+    topo = G.complete(n)
+    run = RunConfig(seed=11, max_rounds=32)
+    step_1, t1 = make_rumor_round(proto, topo, fault, tabled=True)
+    st1 = init_rumor_state(run, proto, n)
+    step_8, t8 = make_sharded_rumor_round(proto, topo, make_mesh(8), fault,
+                                          tabled=True)
+    st8 = init_sharded_rumor_state(run, proto, topo, make_mesh(8))
+    lost_1 = lost_8 = 0.0
+    for _ in range(6):
+        st1, l1 = step_1(st1, *t1)
+        st8, l8 = step_8(st8, *t8)
+        lost_1, lost_8 = lost_1 + float(l1), lost_8 + float(l8)
+    for field in ("seen", "hot", "cnt"):
+        np.testing.assert_array_equal(np.asarray(getattr(st1, field)),
+                                      np.asarray(getattr(st8, field))[:n],
+                                      err_msg=field)
+    assert float(st1.msgs) == float(st8.msgs)
+    assert lost_1 == lost_8 > 0.0
+    assert np.asarray(st1.cnt).max() == 4
 
 
 def test_monotone_seen_and_curve_matches_until():

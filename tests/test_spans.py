@@ -10,7 +10,7 @@
 * the packed, sharded-packed and fused loops carry the ``gossip.sample``,
   ``gossip.exchange`` and ``gossip.coverage`` scopes in the op-name
   metadata of their compiled HLO, which is what the profiler reports
-  for each device op.
+  for each device op; the rumor loop carries ``gossip.sir`` besides.
 """
 
 import glob
@@ -162,6 +162,21 @@ def test_packed_loops_carry_the_device_scopes(monkeypatch, devices):
                                           _partition_fault(n), timing={})
     text = _compiled_loop_text(monkeypatch, drive)
     for scope in SCOPES:
+        assert f"/{scope}/" in text, scope
+
+
+def test_rumor_loop_carries_the_device_scopes(monkeypatch):
+    from gossip_tpu.models.rumor import simulate_until_rumor
+    n = 512
+    proto = ProtocolConfig(mode="rumor", rumor_variant="blind", fanout=3,
+                           rumor_k=4)
+
+    def drive():
+        simulate_until_rumor(proto, G.complete(n),
+                             RunConfig(seed=7, max_rounds=32),
+                             _partition_fault(n), timing={})
+    text = _compiled_loop_text(monkeypatch, drive)
+    for scope in SCOPES + (tr.SIR,):
         assert f"/{scope}/" in text, scope
 
 
