@@ -44,6 +44,7 @@ from gossip_tpu.config import FaultConfig, ProtocolConfig, RunConfig
 from gossip_tpu.models import si as si_mod
 from gossip_tpu.models.state import SimState, alive_mask, bind_tables
 from gossip_tpu.ops.sampling import apply_drop, drop_mask, sample_peers
+from gossip_tpu.parallel.sharded import local_rows
 from gossip_tpu.topology.generators import Topology
 
 
@@ -140,7 +141,7 @@ def make_halo_round(proto: ProtocolConfig, topo: Topology, mesh: Mesh,
                           else alive)
             dp, cut = drop_prob, None
         lost = jnp.float32(0.0)
-        alive_l = alive_full[gids]
+        alive_l = local_rows(alive_full, axis_name, nl)
         visible = seen_l & alive_l[:, None]
         ext = _exchange_halos(visible, band, axis_name)   # [nl+2B, R]
         base = shard * nl - band

@@ -86,6 +86,43 @@ def sharded_alive(fault: Optional[FaultConfig], n: int, n_pad: int,
     return _pad_rows(alive, n_pad, False)
 
 
+def local_rows(x: jax.Array, axis_name: str, nl: int) -> jax.Array:
+    """This shard's own ``nl`` rows of a replicated per-node array
+    (leading axis, rows ``shard*nl`` on) — the ONE way a shard reads its
+    own block.  A contiguous slice, never ``x[gids]``: XLA lowers that
+    index to a general gather and pays the random-access rate on every
+    row (~19 ms a round for 2.5M rows on a TPU v5e) for a block the
+    slice reads at copy speed."""
+    return jax.lax.dynamic_slice_in_dim(
+        x, jax.lax.axis_index(axis_name) * nl, nl)
+
+
+def shard_liveness(fault: Optional[FaultConfig], sched, n: int,
+                   n_pad: int, origin: int, round_, axis_name: str):
+    """``(alive_l, drop_p, cut)`` for this shard's rows at ``round_``
+    inside ``shard_map`` — the liveness block every node-sharded pull /
+    push driver opens its round with.  Under churn (``sched`` from
+    ``NE.split_tables``) the schedule's per-node tables and the base
+    mask are sliced FIRST, so the down-window compares touch ``nl``
+    rows, not ``n_pad``; the schedule operands themselves stay
+    replicated (the loop recorders read them whole)."""
+    from gossip_tpu.ops import nemesis as NE
+    nl = n_pad // jax.lax.axis_size(axis_name)
+
+    def own(x):
+        return local_rows(x, axis_name, nl)
+
+    if sched is None:
+        drop_p = 0.0 if fault is None else fault.drop_prob
+        return own(sharded_alive(fault, n, n_pad, origin)), drop_p, None
+    base_pad = _pad_rows(NE.base_alive_or_ones(fault, n, origin), n_pad,
+                         False)
+    mine = NE.Schedule(own(sched.die), own(sched.rec), sched.cut_tbl,
+                       sched.drop_tbl)
+    return (NE.alive_rows(mine, own(base_pad), round_),
+            NE.drop_at(sched, round_), NE.cut_at(sched, round_))
+
+
 def make_sharded_si_round(
         proto: ProtocolConfig, topo: Topology, mesh: Mesh,
         fault: Optional[FaultConfig] = None, origin: int = 0,
@@ -126,19 +163,11 @@ def make_sharded_si_round(
         shard = jax.lax.axis_index(axis_name)
         gids = shard * nl + jnp.arange(nl, dtype=jnp.int32)
         rkey = jax.random.fold_in(base_key, round_)
-        # liveness in-trace (replicated compute, no O(N) inline constant)
-        if ch is not None:
-            # churn path: per-round liveness / drop prob / cut from the
-            # schedule OPERANDS, indexed by the loop counter (ops/nemesis
-            # module doc — the compiled loop carries no schedule content)
-            base_pad = _pad_rows(
-                NE.base_alive_or_ones(fault, n, origin), n_pad, False)
-            alive_l = NE.alive_rows(sched, base_pad, round_)[gids]
-            dp = NE.drop_at(sched, round_)
-            cut = NE.cut_at(sched, round_)
-        else:
-            alive_l = sharded_alive(fault, n, n_pad, origin)[gids]
-            dp, cut = drop_prob, None
+        # under churn: liveness / drop prob / cut from the schedule
+        # OPERANDS, indexed by the loop counter (ops/nemesis module doc
+        # — the compiled loop carries no schedule content)
+        alive_l, dp, cut = shard_liveness(fault, sched, n, n_pad, origin,
+                                          round_, axis_name)
         lost = jnp.float32(0.0)
         visible = seen_l & alive_l[:, None]
         delta = jnp.zeros_like(seen_l)
@@ -258,8 +287,8 @@ def make_sharded_si_round(
         in_specs += [sh2, sh]
         tables = (nbrs_pad, deg_pad)
     if ch is not None:
-        # schedule operands replicated over the mesh (tiny tables; the
-        # per-shard slice happens via gids inside local_round)
+        # schedule operands replicated over the mesh (the loop recorders
+        # read them whole; local_round slices its own rows: local_rows)
         in_specs += [rep] * NE.N_SCHED_OPERANDS
         tables = tables + NE.sched_args(NE.build(fault, n, n_pad))
 

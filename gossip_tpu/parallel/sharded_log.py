@@ -38,7 +38,7 @@ from gossip_tpu.models.state import bind_tables
 from gossip_tpu.ops import logs as LG
 from gossip_tpu.ops.sampling import apply_drop, sample_peers
 from gossip_tpu.parallel.sharded import (_churn_observables, _pad_rows,
-                                         pad_to_mesh, sharded_alive)
+                                         pad_to_mesh, shard_liveness)
 from gossip_tpu.topology.generators import Topology
 
 
@@ -53,7 +53,6 @@ def make_sharded_log_round(
     n, k = topo.n, proto.fanout
     n_pad = pad_to_mesh(n, mesh, axis_name)
     nl = n_pad // mesh.shape[axis_name]
-    drop_prob = 0.0 if fault is None else fault.drop_prob
     from gossip_tpu.ops import nemesis as NE
     ch = NE.get(fault)
     # capability row: full schedule feature set on the log fabric
@@ -71,15 +70,8 @@ def make_sharded_log_round(
         shard = jax.lax.axis_index(axis_name)
         gids = shard * nl + jnp.arange(nl, dtype=jnp.int32)
         rkey = jax.random.fold_in(base_key, round_)
-        if ch is not None:
-            base_pad = _pad_rows(
-                NE.base_alive_or_ones(fault, n, origin), n_pad, False)
-            alive_l = NE.alive_rows(sched, base_pad, round_)[gids]
-            dp = NE.drop_at(sched, round_)
-            cut = NE.cut_at(sched, round_)
-        else:
-            alive_l = sharded_alive(fault, n, n_pad, origin)[gids]
-            dp, cut = drop_prob, None
+        alive_l, dp, cut = shard_liveness(fault, sched, n, n_pad, origin,
+                                          round_, axis_name)
         lost = jnp.float32(0.0)
         # local appends/commits first (models/log.py twin); padding
         # rows (gids >= n) own no send/commit, so inject_rows is zero

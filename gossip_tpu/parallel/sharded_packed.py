@@ -26,7 +26,7 @@ from gossip_tpu.ops.bitpack import coverage_packed, pack, unpack
 from gossip_tpu.ops.propagate import push_counts
 from gossip_tpu.ops.sampling import apply_drop, sample_peers
 from gossip_tpu.parallel.sharded import (_pad_rows, pad_to_mesh,
-                                         sharded_alive)
+                                         shard_liveness, sharded_alive)
 from gossip_tpu.topology.generators import Topology
 from gossip_tpu.utils.trace import (BUILD, COVERAGE, EXCHANGE, READBACK,
                                     SAMPLE, span)
@@ -45,7 +45,6 @@ def make_sharded_packed_round(
         raise ValueError("packed rounds support pull/antientropy only")
     n_pad = pad_to_mesh(n, mesh, axis_name)
     nl = n_pad // mesh.shape[axis_name]
-    drop_prob = 0.0 if fault is None else fault.drop_prob
     from gossip_tpu.ops import nemesis as NE
     ch = NE.get(fault)
 
@@ -60,18 +59,10 @@ def make_sharded_packed_round(
         gids = shard * nl + jnp.arange(nl, dtype=jnp.int32)
         # the round's fault masks decide, like the drop coins, which
         # pulls happen: they ride the sample scope.  Liveness in-trace
-        # (replicated compute, no O(N) inline constant)
+        # on this shard's rows only (no O(N) inline constant)
         with jax.named_scope(SAMPLE):
-            if ch is not None:
-                # schedule operands from the table tail (ops/nemesis doc)
-                base_pad = _pad_rows(
-                    NE.base_alive_or_ones(fault, n, origin), n_pad, False)
-                alive_l = NE.alive_rows(sched, base_pad, round_)[gids]
-                dp = NE.drop_at(sched, round_)
-                cut = NE.cut_at(sched, round_)
-            else:
-                alive_l = sharded_alive(fault, n, n_pad, origin)[gids]
-                dp, cut = drop_prob, None
+            alive_l, dp, cut = shard_liveness(fault, sched, n, n_pad,
+                                              origin, round_, axis_name)
         lost = jnp.float32(0.0)
         with jax.named_scope(EXCHANGE):
             visible = jnp.where(alive_l[:, None], packed_l, jnp.uint32(0))

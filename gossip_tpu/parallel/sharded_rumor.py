@@ -31,7 +31,7 @@ from gossip_tpu.models.state import bind_tables
 from gossip_tpu.ops.propagate import push_counts
 from gossip_tpu.ops.sampling import apply_drop, sample_peers
 from gossip_tpu.parallel.sharded import (_pad_rows, pad_to_mesh,
-                                         sharded_alive)
+                                         shard_liveness, sharded_alive)
 from gossip_tpu.topology.generators import Topology
 
 
@@ -47,7 +47,6 @@ def make_sharded_rumor_round(proto: ProtocolConfig, topo: Topology,
     n, k = topo.n, proto.fanout
     kk = proto.rumor_k
     feedback = proto.rumor_variant == "feedback"
-    drop_prob = 0.0 if fault is None else fault.drop_prob
     n_pad = pad_to_mesh(n, mesh, axis_name)
     nl = n_pad // mesh.shape[axis_name]
     from gossip_tpu.ops import nemesis as NE
@@ -63,16 +62,8 @@ def make_sharded_rumor_round(proto: ProtocolConfig, topo: Topology,
         shard = jax.lax.axis_index(axis_name)
         gids = shard * nl + jnp.arange(nl, dtype=jnp.int32)
         rkey = jax.random.fold_in(base_key, round_)
-        if ch is not None:
-            # schedule operands from the table tail (ops/nemesis doc)
-            base_pad = _pad_rows(
-                NE.base_alive_or_ones(fault, n, origin), n_pad, False)
-            alive_l = NE.alive_rows(sched, base_pad, round_)[gids]
-            dp = NE.drop_at(sched, round_)
-            cut = NE.cut_at(sched, round_)
-        else:
-            alive_l = sharded_alive(fault, n, n_pad, origin)[gids]
-            dp, cut = drop_prob, None
+        alive_l, dp, cut = shard_liveness(fault, sched, n, n_pad, origin,
+                                          round_, axis_name)
         lost = jnp.float32(0.0)
         nbrs_l, deg_l = table if have_table else (None, None)
 

@@ -62,8 +62,8 @@ from gossip_tpu import config as C
 from gossip_tpu.config import FaultConfig, ProtocolConfig, RunConfig
 from gossip_tpu.models.state import SimState
 from gossip_tpu.ops.bitpack import coverage_packed, n_words, pack, unpack
-from gossip_tpu.parallel.sharded import (_pad_rows, pad_to_mesh,
-                                         sharded_alive)
+from gossip_tpu.parallel.sharded import (_pad_rows, local_rows,
+                                         pad_to_mesh, sharded_alive)
 
 # RNG tags (disjoint from models/si.py's 1..5)
 SPARSE_PERM_TAG = 101
@@ -220,12 +220,14 @@ def make_sparse_pull_round(
         _, sched = NE.split_tables(ch, sched_tail)
         shard = jax.lax.axis_index(axis_name)
         rkey = jax.random.fold_in(base_key, round_)
-        row_gids = shard * nl + jnp.arange(nl, dtype=jnp.int32)
         if ch is not None:
             # churn path: the alive operand stays the STATIC mask; the
             # schedule OPERANDS' down-window subtracts per round
-            alive_l = alive_l & ~((sched.die[row_gids] <= round_)
-                                  & (round_ < sched.rec[row_gids]))
+            alive_l = NE.alive_rows(
+                NE.Schedule(local_rows(sched.die, axis_name, nl),
+                            local_rows(sched.rec, axis_name, nl),
+                            sched.cut_tbl, sched.drop_tbl),
+                alive_l, round_)
             dp = NE.drop_at(sched, round_)
             cut = NE.cut_at(sched, round_)
         else:
@@ -621,8 +623,8 @@ def make_sparse_topo_pull_round(
     def local_round(seen_l, round_, base_key, msgs, ovf, nbrs_l, deg_l):
         shard = jax.lax.axis_index(axis_name)
         rkey = jax.random.fold_in(base_key, round_)
-        row_gids = shard * nl + jnp.arange(nl, dtype=jnp.int32)
-        alive_l = sharded_alive(fault, n, n_pad, origin)[row_gids]
+        alive_l = local_rows(sharded_alive(fault, n, n_pad, origin),
+                             axis_name, nl)
         visible = jnp.where(alive_l[:, None], seen_l, jnp.uint32(0))
 
         def exchange(_):

@@ -27,7 +27,7 @@ from gossip_tpu.models import swim as SW
 from gossip_tpu.models.state import bind_tables
 from gossip_tpu.models.swim import DEAD_WIRE, SwimState, base_alive
 from gossip_tpu.ops.sampling import sample_peers
-from gossip_tpu.parallel.sharded import _pad_rows, pad_to_mesh
+from gossip_tpu.parallel.sharded import _pad_rows, local_rows, pad_to_mesh
 from gossip_tpu.topology.generators import Topology
 
 
@@ -91,7 +91,7 @@ def make_sharded_swim_round(
                                         & (round_ < sched.rec))
             if ramped:
                 dp = NE.drop_at(sched, round_)
-        alive_l = alive_full[gids]
+        alive_l = local_rows(alive_full, axis_name, nl)
         subj_gids = SW.subject_window(round_, s_count, n, rotate,
                                       epoch_rounds)
         subj_alive = alive_full[subj_gids]
@@ -140,7 +140,7 @@ def make_sharded_swim_round(
         contrib = SW.disseminate_max(targets, wire1, n_pad, proto.swim_diss,
                                      max_rounds)
         recv_full = jax.lax.pmax(contrib, axis_name)
-        recv_l = jax.lax.dynamic_slice_in_dim(recv_full, shard * nl, nl, 0)
+        recv_l = local_rows(recv_full, axis_name, nl)
         wire2 = jnp.maximum(wire1, recv_l)
 
         # 4: refutation (only rows whose gid is an alive subject) ----------

@@ -843,7 +843,7 @@ def _cached_pod_sweep_scan(n: int, n_pad: int, nl: int, k_max: int,
     comes from the ``local_nbrs``/``local_deg`` arguments)."""
     from jax.sharding import PartitionSpec as P
 
-    from gossip_tpu.parallel.sharded import sharded_alive
+    from gossip_tpu.parallel.sharded import local_rows, sharded_alive
     if have_table:
         topo_ph = Topology(nbrs=jnp.zeros((0, 0), jnp.int32),
                            deg=jnp.zeros((0,), jnp.int32), n=n,
@@ -864,15 +864,16 @@ def _cached_pod_sweep_scan(n: int, n_pad: int, nl: int, k_max: int,
         # upstream (check_supported events=False), so this key carries
         # no schedule content — the staticcheck content-in-memo-key
         # naming contract (gossip_tpu/analysis/recompile.py)
-        alive_l = sharded_alive(fault_static, n, n_pad, origin)[gids]
+        alive_l = local_rows(sharded_alive(fault_static, n, n_pad, origin),
+                             node_axis, nl)
         rkey = jax.random.fold_in(base_key, round_)
         visible = seen_l & alive_l[:, None]
 
         def count_reduce(counts):
             # psum + own slice rather than psum_scatter: this runs under
             # vmap over the local configs
-            full = jax.lax.psum(counts, node_axis)
-            return jax.lax.dynamic_slice_in_dim(full, shard * nl, nl, 0)
+            return local_rows(jax.lax.psum(counts, node_axis), node_axis,
+                              nl)
 
         delta, msgs_round = _sweep_round_delta(
             rkey, round_, gids, visible, alive_l, topo_ph, k_max,
