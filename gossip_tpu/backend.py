@@ -848,84 +848,46 @@ def _run_jax_with_topo(proto: ProtocolConfig, tc: TopologyConfig,
                            **_timing_meta(timing, wall)})
 
 
-def run_log_workload(proto: ProtocolConfig, tc: TopologyConfig,
-                     run: RunConfig, log_cfg: LogConfig,
-                     fault: Optional[FaultConfig] = None,
-                     want_curve: bool = False) -> RunReport:
-    """The replicated-log workload behind the ``Run`` RPC's ``log``
-    field (models/log.py drivers; single-process single-device — the
-    node mesh shards via the library API, the Ensemble RPC rule).
-    ``coverage`` reports the final log_conv; meta carries the
-    acked-appends truth summary."""
-    from gossip_tpu.models.log import (check_log_mode,
-                                       simulate_curve_log,
-                                       simulate_until_log)
-    from gossip_tpu.topology import generators as G
-    check_log_mode(proto)
-    if run.engine not in ("auto", "xla"):
-        raise ValueError(f"engine={run.engine!r} cannot run the log "
-                         "workload (XLA pull kernels only)")
-    topo = G.build(tc)
-    t0 = time.perf_counter()
-    if want_curve:
-        conv, msgs, _, truth = simulate_curve_log(log_cfg, proto, topo,
-                                                  run, fault)
-        hit = [i for i, c in enumerate(conv)
-               if c >= run.target_coverage]
-        rounds = (hit[0] + 1) if hit else -1
-        lc, msgs_f = float(conv[-1]), float(msgs[-1])
-        curve = [float(c) for c in conv]
-    else:
-        rounds, lc, msgs_f, _, truth = simulate_until_log(
-            log_cfg, proto, topo, run, fault)
-        curve = None
-    wall = time.perf_counter() - t0
-    return RunReport(
-        backend="jax-tpu", mode="log", n=tc.n, rounds=rounds,
-        coverage=lc, msgs=msgs_f, wall_s=round(wall, 4), curve=curve,
-        meta={"clock": "rounds", "devices": 1,
-              "msgs_counts": "transmissions", "engine": "log-xla",
-              "workload": "log", "truth": truth})
-
-
-def run_txn_workload(proto: ProtocolConfig, tc: TopologyConfig,
-                     run: RunConfig, txn_cfg: TxnConfig,
-                     fault: Optional[FaultConfig] = None,
-                     want_curve: bool = False) -> RunReport:
-    """The LWW-register transaction workload behind the ``Run`` RPC's
-    ``txn`` field (models/register.py drivers; single-process
+def run_payload_workload(proto: ProtocolConfig, tc: TopologyConfig,
+                         run: RunConfig, cfg,
+                         fault: Optional[FaultConfig] = None,
+                         want_curve: bool = False) -> RunReport:
+    """The replicated-payload workload behind the ``Run`` RPC's ``log``
+    and ``txn`` fields (models/payload drivers; single-process
     single-device — the node mesh shards via the library API, the
-    Ensemble RPC rule).  ``coverage`` reports the final txn_conv; meta
-    carries the acked-writes LWW truth summary."""
-    from gossip_tpu.models.register import (check_txn_mode,
-                                            simulate_curve_txn,
-                                            simulate_until_txn)
+    Ensemble RPC rule).  ``coverage`` reports the final convergence of
+    the config's payload row; meta carries its truth summary."""
+    from gossip_tpu.models.payload import (row_for,
+                                           simulate_curve_payload,
+                                           simulate_until_payload)
     from gossip_tpu.topology import generators as G
-    check_txn_mode(proto)
+    row = row_for(cfg)
+    row.check_mode(proto)
     if run.engine not in ("auto", "xla"):
-        raise ValueError(f"engine={run.engine!r} cannot run the txn "
-                         "workload (XLA pull kernels only)")
+        raise ValueError(f"engine={run.engine!r} cannot run the "
+                         f"{row.label} workload (XLA pull kernels only)")
     topo = G.build(tc)
     t0 = time.perf_counter()
     if want_curve:
-        conv, msgs, _, truth = simulate_curve_txn(txn_cfg, proto, topo,
-                                                  run, fault)
+        conv, msgs, _, truth = simulate_curve_payload(cfg, proto, topo,
+                                                      run, fault)
         hit = [i for i, c in enumerate(conv)
                if c >= run.target_coverage]
         rounds = (hit[0] + 1) if hit else -1
-        tcv, msgs_f = float(conv[-1]), float(msgs[-1])
+        cv, msgs_f = float(conv[-1]), float(msgs[-1])
         curve = [float(c) for c in conv]
     else:
-        rounds, tcv, msgs_f, _, truth = simulate_until_txn(
-            txn_cfg, proto, topo, run, fault)
+        rounds, cv, msgs_f, _, truth = simulate_until_payload(
+            cfg, proto, topo, run, fault)
         curve = None
     wall = time.perf_counter() - t0
     return RunReport(
-        backend="jax-tpu", mode="txn", n=tc.n, rounds=rounds,
-        coverage=tcv, msgs=msgs_f, wall_s=round(wall, 4), curve=curve,
+        backend="jax-tpu", mode=row.label, n=tc.n, rounds=rounds,
+        coverage=cv, msgs=msgs_f, wall_s=round(wall, 4), curve=curve,
         meta={"clock": "rounds", "devices": 1,
-              "msgs_counts": "transmissions", "engine": "txn-xla",
-              "workload": "txn", "truth": truth})
+              "msgs_counts": "transmissions",
+              "engine": f"{row.label}-xla", "workload": row.label,
+              "truth": truth})
 
 
 def run_simulation(backend: str, proto: ProtocolConfig, tc: TopologyConfig,
@@ -938,28 +900,20 @@ def run_simulation(backend: str, proto: ProtocolConfig, tc: TopologyConfig,
     if log_cfg is not None and txn_cfg is not None:
         raise ValueError("a request carries at most one payload "
                          "workload; pick 'log' or 'txn'")
-    if txn_cfg is not None:
+    cfg = txn_cfg if txn_cfg is not None else log_cfg
+    if cfg is not None:
+        from gossip_tpu.models.payload import row_for
+        label = row_for(cfg).label
         if backend != "jax-tpu":
-            raise ValueError("the txn workload needs the jax-tpu "
+            raise ValueError(f"the {label} workload needs the jax-tpu "
                              "backend")
         if mesh_cfg is not None:
-            raise ValueError("the txn workload over RPC is "
+            raise ValueError(f"the {label} workload over RPC is "
                              "single-process single-device; shard the "
                              "node mesh via the library API "
-                             "(parallel/sharded_register)")
-        return run_txn_workload(proto, tc, run, txn_cfg, fault,
-                                want_curve)
-    if log_cfg is not None:
-        if backend != "jax-tpu":
-            raise ValueError("the log workload needs the jax-tpu "
-                             "backend")
-        if mesh_cfg is not None:
-            raise ValueError("the log workload over RPC is "
-                             "single-process single-device; shard the "
-                             "node mesh via the library API "
-                             "(parallel/sharded_log)")
-        return run_log_workload(proto, tc, run, log_cfg, fault,
-                                want_curve)
+                             "(parallel/sharded_payload)")
+        return run_payload_workload(proto, tc, run, cfg, fault,
+                                    want_curve)
     if backend == "go-native" and run.engine not in ("auto", "native"):
         raise ValueError(f"engine={run.engine!r} is a jax-tpu kernel "
                          "selection; go-native takes 'auto' (C++ core "

@@ -1089,22 +1089,23 @@ def _parse_crdt_injections(a):
                           for s in (a.set_remove or ())))
 
 
-def cmd_crdt(a) -> int:
-    """CRDT gossip run: a commutative-merge payload (Gossip Glomers
-    counter/set workloads) on the pull exchange fabric, value
-    convergence judged integer-exact against the ground-truth merge on
-    the eventual-alive set (docs/WORKLOADS.md)."""
-    from gossip_tpu.config import CrdtConfig
+def _run_payload(a, cfg, head: dict, tail: dict,
+                 truth_key: str = "truth") -> int:
+    """The one body of the replicated-payload commands (crdt, log,
+    txn): the config's payload row on one device or the ``--devices``
+    node mesh, until-target or ``--curve``.  ``head``/``tail`` are the
+    command's own report keys, around the shared ones."""
+    from gossip_tpu.models.payload import row_for
     from gossip_tpu.topology import generators as G
-    cfg = CrdtConfig(kind=a.type, elements=a.elements,
-                     **_parse_crdt_injections(a))
+    row = row_for(cfg)
     proto = ProtocolConfig(mode="pull", fanout=a.fanout)
     tc = TopologyConfig(family=a.family, n=a.n, k=a.k, p=a.p,
                         seed=a.seed)
     run = RunConfig(target_coverage=a.target, max_rounds=a.max_rounds,
                     seed=a.seed, origin=a.origin)
     churn = _parse_churn(a)
-    byz = _parse_byz(a)
+    byz = _parse_byz(a) if row.byz else None
+    defend = bool(a.defend) if row.byz else False
     fault = None
     if (a.drop > 0 or a.death > 0 or churn is not None
             or byz is not None):
@@ -1116,44 +1117,37 @@ def cmd_crdt(a) -> int:
     t0 = _time.perf_counter()
     if a.devices > 1:
         from gossip_tpu.parallel.sharded import make_mesh
-        from gossip_tpu.parallel.sharded_crdt import (
-            simulate_curve_crdt_sharded, simulate_until_crdt_sharded)
-        mesh = make_mesh(a.devices)
-        if want_curve:
-            conv, msgs, final, truth = simulate_curve_crdt_sharded(
-                cfg, proto, topo, run, mesh, fault, defend=a.defend)
-        else:
-            rounds, vc, msgs_f, final, truth = (
-                simulate_until_crdt_sharded(cfg, proto, topo, run,
-                                            mesh, fault,
-                                            defend=a.defend))
-        engine = "crdt-sharded"
+        from gossip_tpu.parallel import sharded_payload as SP
+        sim = (SP.simulate_curve_payload_sharded if want_curve
+               else SP.simulate_until_payload_sharded)
+        res = sim(cfg, proto, topo, run, make_mesh(a.devices), fault,
+                  defend=defend)
+        engine = f"{row.label}-sharded"
     else:
-        from gossip_tpu.models.crdt import (simulate_curve_crdt,
-                                            simulate_until_crdt)
-        if want_curve:
-            conv, msgs, final, truth = simulate_curve_crdt(
-                cfg, proto, topo, run, fault, defend=a.defend)
-        else:
-            rounds, vc, msgs_f, final, truth = simulate_until_crdt(
-                cfg, proto, topo, run, fault, defend=a.defend)
-        engine = "crdt-xla"
+        from gossip_tpu.models import payload as PL
+        sim = (PL.simulate_curve_payload if want_curve
+               else PL.simulate_until_payload)
+        res = sim(cfg, proto, topo, run, fault, defend=defend)
+        engine = f"{row.label}-xla"
     wall = _time.perf_counter() - t0
     if want_curve:
+        conv, msgs, _, truth = res
         hit = [i for i, c in enumerate(conv) if c >= a.target]
         rounds = (hit[0] + 1) if hit else -1
         vc, msgs_f = float(conv[-1]), float(msgs[-1])
-    out = {"backend": "jax-tpu", "mode": "crdt", "type": a.type,
-           "n": a.n, "rounds": rounds, "value_conv": vc,
-           "converged": vc >= a.target, "truth_value": truth,
+    else:
+        rounds, vc, msgs_f, _, truth = res
+    out = {"backend": "jax-tpu", "mode": row.label, **head,
+           "rounds": rounds, row.column: vc,
+           "converged": vc >= a.target, truth_key: truth,
            "msgs": msgs_f, "wall_s": round(wall, 4),
-           "devices": a.devices, "engine": engine,
+           "devices": a.devices, "engine": engine, **tail,
            "compile_cache": _cache_stamp(a)}
     if churn is not None:
         out["fault_program"] = True
     if byz is not None:
         out["byz_program"] = True
-        out["defended"] = bool(a.defend)
+        out["defended"] = defend
     if a.save_curve:
         from gossip_tpu.utils.metrics import dump_curve_jsonl
         dump_curve_jsonl(a.save_curve, [float(c) for c in conv],
@@ -1162,6 +1156,18 @@ def cmd_crdt(a) -> int:
         out["curve"] = [float(c) for c in conv]
     print(json.dumps(out))
     return 0
+
+
+def cmd_crdt(a) -> int:
+    """CRDT gossip run: a commutative-merge payload (Gossip Glomers
+    counter/set workloads) on the pull exchange fabric, value
+    convergence judged integer-exact against the ground-truth merge on
+    the eventual-alive set (docs/WORKLOADS.md)."""
+    from gossip_tpu.config import CrdtConfig
+    cfg = CrdtConfig(kind=a.type, elements=a.elements,
+                     **_parse_crdt_injections(a))
+    return _run_payload(a, cfg, {"type": a.type, "n": a.n}, {},
+                        truth_key="truth_value")
 
 
 def _parse_log_injections(a):
@@ -1186,67 +1192,10 @@ def cmd_log(a) -> int:
     against the acked-appends ground truth on the eventual-alive set
     (docs/WORKLOADS.md "Replicated logs")."""
     from gossip_tpu.config import LogConfig
-    from gossip_tpu.topology import generators as G
     cfg = LogConfig(keys=a.keys, capacity=a.capacity,
                     **_parse_log_injections(a))
-    proto = ProtocolConfig(mode="pull", fanout=a.fanout)
-    tc = TopologyConfig(family=a.family, n=a.n, k=a.k, p=a.p,
-                        seed=a.seed)
-    run = RunConfig(target_coverage=a.target, max_rounds=a.max_rounds,
-                    seed=a.seed, origin=a.origin)
-    churn = _parse_churn(a)
-    fault = None
-    if a.drop > 0 or a.death > 0 or churn is not None:
-        fault = FaultConfig(node_death_rate=a.death, drop_prob=a.drop,
-                            seed=a.seed, churn=churn)
-    topo = G.build(tc)
-    want_curve = a.curve or bool(a.save_curve)
-    import time as _time
-    t0 = _time.perf_counter()
-    if a.devices > 1:
-        from gossip_tpu.parallel.sharded import make_mesh
-        from gossip_tpu.parallel.sharded_log import (
-            simulate_curve_log_sharded, simulate_until_log_sharded)
-        mesh = make_mesh(a.devices)
-        if want_curve:
-            conv, msgs, final, truth = simulate_curve_log_sharded(
-                cfg, proto, topo, run, mesh, fault)
-        else:
-            rounds, lc, msgs_f, final, truth = (
-                simulate_until_log_sharded(cfg, proto, topo, run,
-                                           mesh, fault))
-        engine = "log-sharded"
-    else:
-        from gossip_tpu.models.log import (simulate_curve_log,
-                                           simulate_until_log)
-        if want_curve:
-            conv, msgs, final, truth = simulate_curve_log(
-                cfg, proto, topo, run, fault)
-        else:
-            rounds, lc, msgs_f, final, truth = simulate_until_log(
-                cfg, proto, topo, run, fault)
-        engine = "log-xla"
-    wall = _time.perf_counter() - t0
-    if want_curve:
-        hit = [i for i, c in enumerate(conv) if c >= a.target]
-        rounds = (hit[0] + 1) if hit else -1
-        lc, msgs_f = float(conv[-1]), float(msgs[-1])
-    out = {"backend": "jax-tpu", "mode": "log", "n": a.n,
-           "keys": a.keys, "capacity": a.capacity, "rounds": rounds,
-           "log_conv": lc, "converged": lc >= a.target,
-           "truth": truth, "msgs": msgs_f, "wall_s": round(wall, 4),
-           "devices": a.devices, "engine": engine,
-           "compile_cache": _cache_stamp(a)}
-    if churn is not None:
-        out["fault_program"] = True
-    if a.save_curve:
-        from gossip_tpu.utils.metrics import dump_curve_jsonl
-        dump_curve_jsonl(a.save_curve, [float(c) for c in conv],
-                         meta=dict(out))
-    if a.curve:
-        out["curve"] = [float(c) for c in conv]
-    print(json.dumps(out))
-    return 0
+    return _run_payload(a, cfg, {"n": a.n, "keys": a.keys,
+                                 "capacity": a.capacity}, {})
 
 
 def _parse_txn_writes(a):
@@ -1269,75 +1218,12 @@ def cmd_txn(a) -> int:
     integer-exact against the acked-writes LWW ground truth on the
     eventual-alive set (docs/WORKLOADS.md "Transactions")."""
     from gossip_tpu.config import TxnConfig
-    from gossip_tpu.topology import generators as G
     cfg = TxnConfig(keys=a.keys, txns=a.txns, zipf_alpha=a.zipf_alpha,
                     hot_key=a.hot_key, load=a.load,
                     spread_rounds=a.spread, **_parse_txn_writes(a))
-    proto = ProtocolConfig(mode="pull", fanout=a.fanout)
-    tc = TopologyConfig(family=a.family, n=a.n, k=a.k, p=a.p,
-                        seed=a.seed)
-    run = RunConfig(target_coverage=a.target, max_rounds=a.max_rounds,
-                    seed=a.seed, origin=a.origin)
-    churn = _parse_churn(a)
-    byz = _parse_byz(a)
-    fault = None
-    if (a.drop > 0 or a.death > 0 or churn is not None
-            or byz is not None):
-        fault = FaultConfig(node_death_rate=a.death, drop_prob=a.drop,
-                            seed=a.seed, churn=churn, byz=byz)
-    topo = G.build(tc)
-    want_curve = a.curve or bool(a.save_curve)
-    import time as _time
-    t0 = _time.perf_counter()
-    if a.devices > 1:
-        from gossip_tpu.parallel.sharded import make_mesh
-        from gossip_tpu.parallel.sharded_register import (
-            simulate_curve_txn_sharded, simulate_until_txn_sharded)
-        mesh = make_mesh(a.devices)
-        if want_curve:
-            conv, msgs, final, truth = simulate_curve_txn_sharded(
-                cfg, proto, topo, run, mesh, fault, defend=a.defend)
-        else:
-            rounds, tcv, msgs_f, final, truth = (
-                simulate_until_txn_sharded(cfg, proto, topo, run,
-                                           mesh, fault,
-                                           defend=a.defend))
-        engine = "txn-sharded"
-    else:
-        from gossip_tpu.models.register import (simulate_curve_txn,
-                                                simulate_until_txn)
-        if want_curve:
-            conv, msgs, final, truth = simulate_curve_txn(
-                cfg, proto, topo, run, fault, defend=a.defend)
-        else:
-            rounds, tcv, msgs_f, final, truth = simulate_until_txn(
-                cfg, proto, topo, run, fault, defend=a.defend)
-        engine = "txn-xla"
-    wall = _time.perf_counter() - t0
-    if want_curve:
-        hit = [i for i, c in enumerate(conv) if c >= a.target]
-        rounds = (hit[0] + 1) if hit else -1
-        tcv, msgs_f = float(conv[-1]), float(msgs[-1])
-    out = {"backend": "jax-tpu", "mode": "txn", "n": a.n,
-           "keys": a.keys, "rounds": rounds, "txn_conv": tcv,
-           "converged": tcv >= a.target, "truth": truth,
-           "msgs": msgs_f, "wall_s": round(wall, 4),
-           "devices": a.devices, "engine": engine,
-           "zipf_alpha": a.zipf_alpha, "hot_key": a.hot_key,
-           "load": a.load, "compile_cache": _cache_stamp(a)}
-    if churn is not None:
-        out["fault_program"] = True
-    if byz is not None:
-        out["byz_program"] = True
-        out["defended"] = bool(a.defend)
-    if a.save_curve:
-        from gossip_tpu.utils.metrics import dump_curve_jsonl
-        dump_curve_jsonl(a.save_curve, [float(c) for c in conv],
-                         meta=dict(out))
-    if a.curve:
-        out["curve"] = [float(c) for c in conv]
-    print(json.dumps(out))
-    return 0
+    return _run_payload(a, cfg, {"n": a.n, "keys": a.keys},
+                        {"zipf_alpha": a.zipf_alpha,
+                         "hot_key": a.hot_key, "load": a.load})
 
 
 def cmd_serve(a) -> int:

@@ -409,7 +409,8 @@ CRDT_SET_KINDS = (GSET, ORSET)
 
 @dataclasses.dataclass(frozen=True)
 class CrdtConfig:
-    """A commutative-merge payload workload (ops/crdt.py, models/crdt.py).
+    """A commutative-merge payload workload (ops/crdt.py,
+    models/payload.py).
 
     The injections are a *program over rounds*, exactly like the nemesis
     schedule: counter ``adds`` are ``(node, round, amount)`` triples
@@ -540,7 +541,7 @@ class CrdtConfig:
 @dataclasses.dataclass(frozen=True)
 class LogConfig:
     """A replicated kafka-style log workload (ops/logs.py,
-    models/log.py) — the last Gossip Glomers sibling of the
+    models/payload.py) — the last Gossip Glomers sibling of the
     reference's broadcast: ordered per-key offset payloads with
     committed offsets, gossiped as fixed-capacity ring buffers whose
     merge is elementwise max over owner-indexed slot planes.
@@ -667,7 +668,7 @@ TXN_LOADS = ("uniform", "diurnal")
 @dataclasses.dataclass(frozen=True)
 class TxnConfig:
     """A totally-available transaction workload over last-writer-wins
-    registers (ops/registers.py, models/register.py) — the Maelstrom
+    registers (ops/registers.py, models/payload.py) — the Maelstrom
     ``txn-rw-register`` shape batched: K per-key LWW registers gossiped
     on the pull fabric, each a ``(value, timestamp)`` pair whose
     timestamp is the lexicographic ``(round, owner)`` key packed into

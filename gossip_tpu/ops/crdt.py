@@ -62,9 +62,10 @@ import jax
 import jax.numpy as jnp
 
 from gossip_tpu.config import (CRDT_COUNTER_KINDS, CRDT_SET_KINDS,
-                               GCOUNTER, GSET, ORSET, PNCOUNTER, VCLOCK,
-                               CrdtConfig, FaultConfig)
+                               GCOUNTER, GSET, ORSET, PNCOUNTER, PULL,
+                               VCLOCK, CrdtConfig, FaultConfig)
 from gossip_tpu.ops.bitpack import n_words, pack
+from gossip_tpu.ops.payload import PayloadRow
 
 # How many trailing step arguments an injection program occupies on a
 # factory's ``tables`` tuple (inject_args / split_inject): counters
@@ -623,3 +624,123 @@ def payload_count(cfg: CrdtConfig, rows: jax.Array,
         return jnp.sum(pc, dtype=jnp.float32)
     return jnp.sum(jnp.where(alive[:, None], rows, 0),
                    dtype=jnp.float32)
+
+
+# -- the exchange drivers' refusals and the payload row -----------------
+
+def check_crdt_mode(proto) -> None:
+    """Pull only: state-based CRDT dissemination IS the pull/digest
+    exchange (each round a node fetches k peers' full states and joins
+    them — Shapiro et al. §3.2), and the push half would need a
+    scatter-max/scatter-OR collective XLA does not have — exactly the
+    reason models/si_packed.py rejects push modes.  One loud reason,
+    shared by every driver and the CLI."""
+    if proto.mode != PULL:
+        raise ValueError(
+            "CRDT rounds run the pull exchange only (state-based merge "
+            f"IS the digest pull; got mode {proto.mode!r} — the push "
+            "half would need a scatter-max/scatter-OR collective XLA "
+            "does not have, the models/si_packed.py precedent)")
+
+
+def check_injections_reachable(cfg: CrdtConfig, run) -> None:
+    """Every scripted injection must fire inside the run: an add at a
+    round >= max_rounds would be counted by ground_truth (the owner IS
+    alive there) but never applied by the loop, so the run could never
+    converge — reported as a quiet converged:false instead of the loud
+    error the no-silent-failure policy demands."""
+    last = cfg.horizon() - 1
+    if last >= run.max_rounds:
+        raise ValueError(
+            f"injection at round {last} can never fire: the run stops "
+            f"after max_rounds={run.max_rounds} rounds, so ground "
+            "truth would be unreachable by construction — raise "
+            "--max-rounds past the last scripted round")
+
+
+def check_exchange(cfg: CrdtConfig, sharded: bool) -> None:
+    """Vector clocks tick locally and have no exchange driver."""
+    if cfg.kind != VCLOCK:
+        return
+    if sharded:
+        raise ValueError("vclock has no exchange driver (ops/crdt merge "
+                         "kernel + tick only)")
+    raise ValueError("vclock has no exchange driver (merge kernel "
+                     "+ tick only — ops/crdt); run gcounter/"
+                     "pncounter/gset/orset")
+
+
+def check_byz_defendable(cfg, fault, fanout: int, defend: bool) -> None:
+    """The defend/byz coupling, one loud reason per arm (shared by both
+    payload frames and every payload that takes liar programs):
+    ``defend=True`` without a liar program is rejected (the defended
+    admission CHANGES the exchange — owner-direct propagation — so a
+    defended no-liar run is not the control arm of anything), and a
+    defended packed-set run needs ``fanout >= quorum`` (a round that
+    samples fewer partners than the echo threshold could never admit a
+    broadcast bit by quorum)."""
+    from gossip_tpu.ops import nemesis as NE
+    bz = NE.get_byz(fault)
+    if defend and bz is None:
+        raise ValueError(
+            "defend=True without a byzantine program: the defended "
+            "admission changes the exchange (owner-direct "
+            "propagation), so there is nothing it would be defending "
+            "against — script liars with --byz, or drop --defend")
+    if (defend and bz is not None and cfg is not None
+            and getattr(cfg, "kind", None) in CRDT_SET_KINDS
+            and fanout < fault.byz.quorum):
+        raise ValueError(
+            f"defended packed-set exchange with fanout={fanout} < "
+            f"quorum={fault.byz.quorum}: a bit echoed by fewer "
+            "partners than are even sampled per round can never meet "
+            "the quorum — raise --fanout or lower ByzConfig.quorum")
+
+
+def truth_scalar(cfg: CrdtConfig, truth, n: int):
+    """The human-readable ground truth: counter value (int) or member
+    count (int) — integer-exact, for reports and the CLI."""
+    import numpy as np
+    truth = np.asarray(truth)
+    if cfg.kind in CRDT_COUNTER_KINDS:
+        if cfg.kind == PNCOUNTER:
+            return int(truth[:n].sum() - truth[n:].sum())
+        return int(truth.sum())
+    w = truth.shape[0] // 2
+    members = truth[:w] & ~truth[w:]
+    return int(sum(bin(int(x)).count("1") for x in members))
+
+
+def _inject_own(cfg, val, rows):
+    """Own columns add (increments accumulate), set planes OR."""
+    return val + rows if cfg.kind in CRDT_COUNTER_KINDS else val | rows
+
+
+def _pull_merge_byz(cfg, rows_all, partners, n, byz, round_, gids,
+                    origin, fault, defend):
+    return pull_merge_crdt_byz(
+        cfg, rows_all, partners, n, byz=byz, round_=round_, gids=gids,
+        n=n, origin=origin, alive_fn=alive_at_fn(fault, n, origin),
+        defend=defend)
+
+
+ROW = PayloadRow(
+    label="crdt", column="value_conv", engine="crdt-pull",
+    check_mode=check_crdt_mode,
+    check_reachable=check_injections_reachable,
+    check_cfg=check_exchange,
+    width=state_width, dtype=state_dtype,
+    inject_args=inject_args, split_inject=split_inject,
+    inject_rows=lambda cfg, inj, gids, r, n, origin, fault: inject_rows(
+        cfg, inj, gids, r, n, origin, alive_at_fn(fault, n, origin),
+        eventual_alive_crdt(fault, n, origin)),
+    apply_inject=_inject_own,
+    merge=lambda cfg, a, b: merge(cfg.kind, a, b),
+    pull_merge=lambda cfg, rows, partners, n: pull_merge_crdt(
+        cfg.kind, rows, partners, n),
+    ground_truth=ground_truth, payload_count=payload_count,
+    truth_summary=truth_scalar,
+    pull_merge_byz=_pull_merge_byz,
+    honest_mask=lambda cfg, inj, fault, n, origin, honest: (
+        honest_component_mask(cfg, n, origin, honest)),
+    byz_conv_frac=byz_conv_frac)

@@ -64,7 +64,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from gossip_tpu.config import LogConfig
+from gossip_tpu.config import PULL, LogConfig
 # ONE definition each for the padding bucket, the no-injection round
 # sentinel, and the shared liveness predicates (ops/crdt): the log and
 # CRDT injection lowerings must agree on all of them by construction.
@@ -72,11 +72,13 @@ from gossip_tpu.ops.crdt import (NO_ROUND, _applied_mask, _pad_pow2,
                                  alive_at_fn, converged_count,
                                  eventual_alive_crdt, merge_max,
                                  value_conv_frac)
+from gossip_tpu.ops.payload import PayloadRow
 
-__all__ = ["N_INJECT_OPERANDS", "alive_at_fn", "converged_count",
-           "eventual_alive_crdt", "ground_truth", "inject_args",
-           "inject_rows", "log_commits", "log_len", "log_sends",
-           "merge_max", "payload_count", "pull_merge_log",
+__all__ = ["N_INJECT_OPERANDS", "ROW", "alive_at_fn",
+           "check_injections_reachable", "check_log_mode",
+           "converged_count", "eventual_alive_crdt", "ground_truth",
+           "inject_args", "inject_rows", "log_commits", "log_len",
+           "log_sends", "merge_max", "payload_count", "pull_merge_log",
            "send_offsets", "split_inject", "state_width",
            "truth_summary", "value_conv_frac"]
 
@@ -282,3 +284,50 @@ def truth_summary(cfg: LogConfig, truth) -> dict:
     committed = [int(c) for c in truth[cfg.keys * cfg.capacity:]]
     return {"lens": lens, "committed": committed,
             "total_entries": int(sum(lens))}
+
+
+# -- the exchange drivers' refusals and the payload row -----------------
+
+def check_log_mode(proto) -> None:
+    """Pull only: state-based dissemination IS the pull/digest
+    exchange, and the push half would need a scatter-max collective
+    XLA does not have (the ops/crdt precedent).  One loud reason,
+    shared by every driver and the CLI."""
+    if proto.mode != PULL:
+        raise ValueError(
+            "replicated-log rounds run the pull exchange only "
+            "(state-based merge IS the digest pull; got mode "
+            f"{proto.mode!r} — the push half would need a scatter-max "
+            "collective XLA does not have, the models/crdt precedent)")
+
+
+def check_injections_reachable(cfg: LogConfig, run) -> None:
+    """Every scripted send/commit must fire inside the run (the
+    ops/crdt rule: an unreachable injection makes ground truth
+    unreachable by construction — a loud error, never a quiet
+    converged:false)."""
+    last = cfg.horizon() - 1
+    if last >= run.max_rounds:
+        raise ValueError(
+            f"log send/commit at round {last} can never fire: the run "
+            f"stops after max_rounds={run.max_rounds} rounds, so "
+            "ground truth would be unreachable by construction — "
+            "raise --max-rounds past the last scripted round")
+
+
+# Writes are max-merges: an entry slot is set once by its owner,
+# commits only ever raise.
+ROW = PayloadRow(
+    label="log", column="log_conv", engine="log-pull",
+    check_mode=check_log_mode,
+    check_reachable=check_injections_reachable,
+    width=lambda cfg, n: state_width(cfg),
+    dtype=lambda cfg: jnp.int32,
+    inject_args=inject_args, split_inject=split_inject,
+    inject_rows=inject_rows,
+    apply_inject=lambda cfg, val, rows: merge_max(val, rows),
+    merge=lambda cfg, a, b: merge_max(a, b),
+    pull_merge=lambda cfg, rows, partners, n: pull_merge_log(
+        rows, partners, n),
+    ground_truth=ground_truth, payload_count=payload_count,
+    truth_summary=lambda cfg, truth, n: truth_summary(cfg, truth))

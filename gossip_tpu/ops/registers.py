@@ -65,7 +65,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from gossip_tpu.config import TxnConfig
+from gossip_tpu.config import PULL, TxnConfig
 # ONE definition each for the padding bucket, the no-injection round
 # sentinel, and the shared liveness predicates (ops/crdt): the txn,
 # log, and CRDT injection lowerings must agree on all of them by
@@ -73,9 +73,11 @@ from gossip_tpu.config import TxnConfig
 from gossip_tpu.ops.crdt import (NO_ROUND, _applied_mask, _pad_pow2,
                                  alive_at_fn, converged_count,
                                  eventual_alive_crdt, value_conv_frac)
+from gossip_tpu.ops.payload import PayloadRow
 
-__all__ = ["N_INJECT_OPERANDS", "alive_at_fn", "byz_conv_frac",
-           "byz_converged_count", "converged_count",
+__all__ = ["N_INJECT_OPERANDS", "ROW", "alive_at_fn", "byz_conv_frac",
+           "byz_converged_count", "check_txn_mode",
+           "check_writes_reachable", "converged_count",
            "eventual_alive_crdt", "ground_truth", "honest_key_mask",
            "inject_args", "inject_rows", "merge_lww", "pack_ts",
            "payload_count", "pull_merge_reg", "pull_merge_reg_byz",
@@ -483,3 +485,60 @@ def truth_summary(cfg: TxnConfig, truth, n: int) -> dict:
     return {"values": [int(v) for v in vals],
             "ts_round": rounds, "ts_owner": owners,
             "written_keys": int((ts > 0).sum())}
+
+
+# -- the exchange drivers' refusals and the payload row -----------------
+
+def check_txn_mode(proto) -> None:
+    """Pull only: state-based dissemination IS the pull/digest
+    exchange, and the push half would need a scatter-argmax collective
+    XLA does not have (the ops/crdt and ops/logs precedent).  One loud
+    reason, shared by every driver and the CLI."""
+    if proto.mode != PULL:
+        raise ValueError(
+            "LWW-register rounds run the pull exchange only "
+            "(state-based merge IS the digest pull; got mode "
+            f"{proto.mode!r} — the push half would need a "
+            "scatter-argmax collective XLA does not have, the "
+            "models/crdt and models/log precedent)")
+
+
+def check_writes_reachable(cfg: TxnConfig, run) -> None:
+    """Every scripted write must fire inside the run (the ops/crdt
+    rule: an unreachable write makes ground truth unreachable by
+    construction — a loud error, never a quiet converged:false)."""
+    last = cfg.horizon() - 1
+    if last >= run.max_rounds:
+        raise ValueError(
+            f"txn write at round {last} can never fire: the run "
+            f"stops after max_rounds={run.max_rounds} rounds, so "
+            "ground truth would be unreachable by construction — "
+            "raise --max-rounds past the last scripted round")
+
+
+def _pull_merge_byz(cfg, rows_all, partners, n, byz, round_, gids,
+                    origin, fault, defend):
+    return pull_merge_reg_byz(
+        rows_all, partners, n, byz=byz, round_=round_, gids=gids, n=n,
+        alive_fn=alive_at_fn(fault, n, origin), defend=defend)
+
+
+# The injection merges via the SAME LWW join as the exchange — an own
+# write always wins locally (its timestamp exceeds anything merged in
+# earlier rounds) and same-round peers resolve by owner order.
+ROW = PayloadRow(
+    label="txn", column="txn_conv", engine="txn-pull",
+    check_mode=check_txn_mode,
+    check_reachable=check_writes_reachable,
+    width=lambda cfg, n: state_width(cfg),
+    dtype=lambda cfg: jnp.int32,
+    inject_args=inject_args, split_inject=split_inject,
+    inject_rows=inject_rows,
+    apply_inject=lambda cfg, val, rows: merge_lww(val, rows),
+    merge=lambda cfg, a, b: merge_lww(a, b),
+    pull_merge=lambda cfg, rows, partners, n: pull_merge_reg(
+        rows, partners, n),
+    ground_truth=ground_truth, payload_count=payload_count,
+    truth_summary=truth_summary,
+    pull_merge_byz=_pull_merge_byz, honest_mask=honest_key_mask,
+    byz_conv_frac=byz_conv_frac)

@@ -299,7 +299,7 @@ class CounterServer:
     """The Gossip Glomers grow-only / PN counter workload node: the
     SAME epidemic machinery as :class:`BroadcastServer` with a
     commutative-merge payload instead of the dedup set (the batched
-    twin is gossip_tpu/models/crdt.py; docs/WORKLOADS.md).
+    twin is gossip_tpu/models/payload.py; docs/WORKLOADS.md).
 
     State is the classic per-node counter shards — ``pos``/``neg`` maps
     ``node_id -> contribution`` where only the owner ever raises its
@@ -420,7 +420,7 @@ class CounterServer:
 class KafkaServer:
     """The Gossip Glomers replicated-log ("kafka") workload node: the
     last challenge-family sibling (the batched twin is
-    gossip_tpu/models/log.py; docs/WORKLOADS.md "Replicated logs").
+    gossip_tpu/models/payload.py; docs/WORKLOADS.md "Replicated logs").
 
     Per-key logs with a single OFFSET-ASSIGNER: key ``k`` is owned by
     a deterministic node (``crc32(key) % n`` over the init-ordered
@@ -628,7 +628,7 @@ class TxnServer:
     """The Maelstrom ``txn-rw-register`` workload node: totally-
     available multi-key read/write transactions over gossip-replicated
     last-writer-wins registers (the batched twin is
-    gossip_tpu/models/register.py; docs/WORKLOADS.md "Transactions").
+    gossip_tpu/models/payload.py; docs/WORKLOADS.md "Transactions").
 
     State: ``store[key] = (value, ts)`` with ``ts = (counter,
     node_index)`` — a Lamport pair, totally ordered, merged per key by

@@ -67,16 +67,16 @@ def test_defended_exact_where_undefended_control_diverges():
     UNDEFENDED control arm — the same executable shape, defenses
     off — provably diverges.  A defense whose absence changes nothing
     defends nothing."""
-    from gossip_tpu.models.crdt import simulate_curve_crdt
+    from gossip_tpu.models.payload import simulate_curve_payload
     from gossip_tpu.ops import crdt as CR
     from gossip_tpu.ops import nemesis as NE
     topo = G.complete(_N)
     cfg = CrdtConfig(kind="gcounter")
-    _, _, fin_u, _ = simulate_curve_crdt(cfg, _BPROTO, topo, _BRUN,
-                                         _BFAULT, defend=False)
-    conv_d, _, fin_d, _ = simulate_curve_crdt(cfg, _BPROTO, topo,
-                                              _BRUN, _BFAULT,
-                                              defend=True)
+    _, _, fin_u, _ = simulate_curve_payload(cfg, _BPROTO, topo, _BRUN,
+                                            _BFAULT, defend=False)
+    conv_d, _, fin_d, _ = simulate_curve_payload(cfg, _BPROTO, topo,
+                                                 _BRUN, _BFAULT,
+                                                 defend=True)
     inj = CR.inject_args(cfg, _N)
     truth = CR.ground_truth(cfg, inj, _BFAULT, _N, 0)
     honest = NE.honest_mask(_BFAULT, _N)
@@ -105,8 +105,8 @@ def test_byz_programs_compile_once_and_salted_reentry_is_free(
     argument tail), so after the first call every salted re-entry
     compiles NOTHING."""
     import jax
-    from gossip_tpu.parallel.sharded_crdt import (
-        init_sharded_crdt_state, make_sharded_crdt_round)
+    from gossip_tpu.parallel.sharded_payload import (
+        init_sharded_payload_state, make_sharded_payload_round)
     topo = G.complete(32)
     cfg = CrdtConfig(kind="gcounter")
     run = RunConfig(seed=0, max_rounds=8, target_coverage=1.0)
@@ -115,11 +115,11 @@ def test_byz_programs_compile_once_and_salted_reentry_is_free(
                        churn=ChurnConfig(events=((3, 2, 5),)),
                        byz=ByzConfig(liars=((3, 1, "inflate", 5),),
                                      quorum=2))
-    step, tables = make_sharded_crdt_round(cfg, _BPROTO, topo, mesh,
+    step, tables = make_sharded_payload_round(cfg, _BPROTO, topo, mesh,
                                            base, 0, tabled=True,
                                            defend=True)
     step = jax.jit(step)
-    state = init_sharded_crdt_state(run, cfg, topo, mesh)
+    state = init_sharded_payload_state(run, cfg, topo, mesh)
     with assert_compiles(4, at_most=True):  # first call + auxiliaries
         jax.block_until_ready(step(state, *tables))
     salts = [
@@ -134,7 +134,7 @@ def test_byz_programs_compile_once_and_salted_reentry_is_free(
             salted = FaultConfig(drop_prob=0.05, seed=2,
                                  churn=ChurnConfig(events=((3, 2, 5),)),
                                  byz=bz)
-            _, tk = make_sharded_crdt_round(cfg, _BPROTO, topo, mesh,
+            _, tk = make_sharded_payload_round(cfg, _BPROTO, topo, mesh,
                                             salted, 0, tabled=True,
                                             defend=True)
             jax.block_until_ready(step(state, *tk))
@@ -148,7 +148,7 @@ def test_inactive_liar_table_leaves_trajectory_bitwise_unchanged():
     EMPTY liar table, or one whose liars only start past the horizon,
     reproduces the no-byz trajectory BITWISE (curve, final state,
     message count) — on both arms of the defend gate's control side."""
-    from gossip_tpu.models.crdt import simulate_curve_crdt
+    from gossip_tpu.models.payload import simulate_curve_payload
     topo = G.complete(_N)
     cfg = CrdtConfig(kind="gcounter")
     run = RunConfig(seed=3, max_rounds=16, target_coverage=1.0)
@@ -160,10 +160,11 @@ def test_inactive_liar_table_leaves_trajectory_bitwise_unchanged():
                           byz=ByzConfig(liars=((3, 900, "inflate", 5),
                                                (7, 900, "corrupt", 1)),
                                         quorum=2))
-    c0, _, f0, t0 = simulate_curve_crdt(cfg, _BPROTO, topo, run, plain)
+    c0, _, f0, t0 = simulate_curve_payload(cfg, _BPROTO, topo, run,
+                                           plain)
     for fault in (empty, dormant):
-        c1, _, f1, t1 = simulate_curve_crdt(cfg, _BPROTO, topo, run,
-                                            fault)
+        c1, _, f1, t1 = simulate_curve_payload(cfg, _BPROTO, topo, run,
+                                               fault)
         assert (np.asarray(c0) == np.asarray(c1)).all()
         assert (np.asarray(f0.val) == np.asarray(f1.val)).all()
         assert float(f0.msgs) == float(f1.msgs)
@@ -178,14 +179,14 @@ def test_engines_without_liar_transforms_reject_byz_loudly():
     reject ``fault.byz`` loudly (the no-silent-substitution policy),
     even when the program carries no churn schedule at all."""
     from gossip_tpu.config import LogConfig
-    from gossip_tpu.models.log import simulate_curve_log
+    from gossip_tpu.models.payload import simulate_curve_payload
     from gossip_tpu.ops import nemesis as NE
     byz_only = FaultConfig(byz=ByzConfig(liars=_LIARS, quorum=2))
     with pytest.raises(ValueError, match="byz"):
         NE.check_supported(byz_only, engine="swim-probe")
     with pytest.raises(ValueError, match="byz"):
-        simulate_curve_log(LogConfig(), _BPROTO, G.complete(_N), _BRUN,
-                           byz_only)
+        simulate_curve_payload(LogConfig(), _BPROTO, G.complete(_N),
+                               _BRUN, byz_only)
 
 
 # -- committed artifact + provenance gate ------------------------------

@@ -1,11 +1,12 @@
-"""CRDT gossip subsystem (ops/crdt, models/crdt, parallel/sharded_crdt):
-algebraic merge pins (commutativity / associativity / idempotence,
-BITWISE), injection lowering + acked-adds ground truth, the
-partition-heal value-convergence acceptance, 1-vs-4-device bitwise
-parity under full fault programs, the value_conv round-metrics column,
-CLI + Maelstrom counter-workload surfaces, the committed artifact
-verdict pin, and the no-CRDT regression guard (existing fabric
-trajectories bitwise unchanged)."""
+"""CRDT gossip subsystem (ops/crdt; its driver contract — the
+partition-heal acceptance, mesh parity, until target, refusals, the
+value_conv round-metrics column, CLI — is the crdt row of
+tests/test_payload.py): algebraic merge pins (commutativity /
+associativity / idempotence, BITWISE), injection lowering + acked-adds
+ground truth, heal under the full fault program for every kind,
+Maelstrom counter-workload surfaces, the committed artifact verdict
+pin, and the no-CRDT regression guard (existing fabric trajectories
+bitwise unchanged)."""
 
 import json
 import os
@@ -193,69 +194,16 @@ def test_set_injection_owner_rotation_and_tombstones():
 
 # -- partition-heal value convergence (the acceptance gate) ------------
 
-_HEAL_N = 64
-_HEAL_END = 8    # long enough for each side to saturate its own split
-
-
-def _heal_bound(fanout):
-    # ~2 epidemic legs + slack after the window closes (the
-    # docs/ROBUSTNESS.md bound the broadcast heal tests use)
-    import math
-    leg = math.ceil(math.log(_HEAL_N) / math.log(1 + fanout))
-    return _HEAL_END + 2 * leg + 4
-
-
-def test_partition_heal_value_convergence_stall_and_exact_heal():
-    """While the window is open, value convergence provably stalls at
-    the partition value split — each side's merged value is exactly its
-    OWN side's truth sum, nobody holds the global truth — and after
-    heal every node reaches the exact integer ground truth within the
-    documented bound."""
-    from gossip_tpu.models.crdt import simulate_curve_crdt
-    from gossip_tpu.ops import crdt as CR
-    cut = 48
-    cfg = CrdtConfig(kind="gcounter")
-    fault = FaultConfig(seed=0, churn=ChurnConfig(
-        partitions=((0, _HEAL_END, cut),)))
-    run = RunConfig(seed=0, max_rounds=24, target_coverage=1.0)
-    topo = G.complete(_HEAL_N)
-    conv, msgs, final, truth_val = simulate_curve_crdt(
-        cfg, _PROTO, topo, run, fault)
-    # stalled: nobody converges to the GLOBAL truth while the cut is
-    # open (both sides hold strictly partial sums)
-    assert all(c == 0.0 for c in conv[:_HEAL_END]), list(conv)
-    # ... and the stall sits exactly at the partition value SPLIT: by
-    # round _HEAL_END every node holds its own side's full sum — run
-    # the open-window prefix and check the integer split
-    prefix = RunConfig(seed=0, max_rounds=_HEAL_END - 1,
-                       target_coverage=1.0)
-    _, _, mid, _ = simulate_curve_crdt(cfg, _PROTO, topo, prefix, fault)
-    truth = np.asarray(CR.ground_truth(
-        cfg, CR.inject_args(cfg, _HEAL_N), fault, _HEAL_N, 0))
-    lo_sum, hi_sum = int(truth[:cut].sum()), int(truth[cut:].sum())
-    vals = np.asarray(mid.val).sum(axis=1)
-    assert vals.max() <= lo_sum + hi_sum
-    assert (vals[:cut] <= lo_sum).all() and (vals[cut:] <= hi_sum).all()
-    assert vals[:cut].max() == lo_sum       # near side saturated its split
-    # healed: EXACT ground truth everywhere within the bound
-    hit = np.nonzero(np.asarray(conv) >= 1.0)[0]
-    assert len(hit), f"never healed: {list(conv)}"
-    assert int(hit[0]) + 1 <= _heal_bound(_PROTO.fanout), list(conv)
-    assert (np.asarray(final.val)
-            == truth[None, :]).all()        # integer-exact, every node
-    assert truth_val == lo_sum + hi_sum
-
-
 def test_heal_under_full_fault_program_pncounter():
     """The PN-counter reaches exact ground truth on the eventual-alive
     set under the full mixed fault program (event + permanent crash +
     window + ramp) — the integer-exact eventual-consistency invariant.
-    (In-gate this covers the one kind the parity tests below do not
-    already drive to 1.0 under _CFAULT; the all-kinds sweep runs in
-    the slow tier — tier-1 wall budget.)"""
-    from gossip_tpu.models.crdt import simulate_curve_crdt
+    (In-gate this covers the one kind the parity tests in
+    tests/test_payload.py do not already drive to 1.0 under _CFAULT;
+    the all-kinds sweep runs in the slow tier — tier-1 wall budget.)"""
+    from gossip_tpu.models.payload import simulate_curve_payload
     run = RunConfig(seed=0, max_rounds=24, target_coverage=1.0)
-    conv, _, final, _ = simulate_curve_crdt(
+    conv, _, final, _ = simulate_curve_payload(
         CrdtConfig(kind="pncounter"), _PROTO, G.complete(32), run,
         _CFAULT)
     assert conv[-1] == 1.0, list(conv)
@@ -263,7 +211,7 @@ def test_heal_under_full_fault_program_pncounter():
 
 @pytest.mark.slow
 def test_heal_under_full_fault_program_all_kinds():
-    from gossip_tpu.models.crdt import simulate_curve_crdt
+    from gossip_tpu.models.payload import simulate_curve_payload
     run = RunConfig(seed=0, max_rounds=24, target_coverage=1.0)
     topo = G.complete(32)
     for cfg in (CrdtConfig(kind="gcounter"),
@@ -271,197 +219,9 @@ def test_heal_under_full_fault_program_all_kinds():
                 CrdtConfig(kind="orset", elements=40,
                            set_removes=((5, 3),)),
                 CrdtConfig(kind="gset", elements=40)):
-        conv, _, final, _ = simulate_curve_crdt(cfg, _PROTO, topo, run,
-                                                _CFAULT)
+        conv, _, final, _ = simulate_curve_payload(cfg, _PROTO, topo,
+                                                   run, _CFAULT)
         assert conv[-1] == 1.0, (cfg.kind, list(conv))
-
-
-# -- mesh parity: dense + packed sharded fabric, schedules as operands -
-
-def _mesh(k=4):
-    from gossip_tpu.parallel.sharded import make_mesh
-    return make_mesh(k)
-
-
-def test_crdt_mesh_parity_bitwise_gcounter():
-    """1-device vs 4-device trajectories BITWISE identical under the
-    full fault program — the counter payload on the dense sharded
-    exchange (int32 shard rows over all_gather)."""
-    from gossip_tpu.models.crdt import simulate_curve_crdt
-    from gossip_tpu.parallel.sharded_crdt import (
-        simulate_curve_crdt_sharded)
-    run = RunConfig(seed=0, max_rounds=16, target_coverage=1.0)
-    topo = G.complete(32)
-    cfg = CrdtConfig(kind="gcounter")
-    c1, m1, f1, t1 = simulate_curve_crdt(cfg, _PROTO, topo, run, _CFAULT)
-    c4, m4, f4, t4 = simulate_curve_crdt_sharded(cfg, _PROTO, topo, run,
-                                                 _mesh(), _CFAULT)
-    assert (np.asarray(c1) == np.asarray(c4)).all()
-    assert (np.asarray(f1.val) == np.asarray(f4.val)[:32]).all()
-    assert float(f1.msgs) == float(f4.msgs)
-    assert t1 == t4
-    assert c4[-1] == 1.0
-
-
-def test_crdt_mesh_parity_bitwise_orset_packed():
-    """The packed-plane set payload (uint32 words, 32 elements per
-    lane — the ops/bitpack layout) on the sharded exchange: bitwise
-    1-vs-4-device parity under the full fault program."""
-    from gossip_tpu.models.crdt import simulate_curve_crdt
-    from gossip_tpu.parallel.sharded_crdt import (
-        simulate_curve_crdt_sharded)
-    run = RunConfig(seed=0, max_rounds=16, target_coverage=1.0)
-    topo = G.complete(32)
-    cfg = CrdtConfig(kind="orset", elements=48,
-                     set_removes=((5, 3), (11, 8)))
-    c1, m1, f1, t1 = simulate_curve_crdt(cfg, _PROTO, topo, run, _CFAULT)
-    c4, m4, f4, t4 = simulate_curve_crdt_sharded(cfg, _PROTO, topo, run,
-                                                 _mesh(), _CFAULT)
-    assert (np.asarray(c1) == np.asarray(c4)).all()
-    assert (np.asarray(f1.val) == np.asarray(f4.val)[:32]).all()
-    assert t1 == t4
-    assert c4[-1] == 1.0
-
-
-@pytest.mark.slow
-def test_crdt_mesh_parity_bitwise_pncounter():
-    from gossip_tpu.models.crdt import simulate_curve_crdt
-    from gossip_tpu.parallel.sharded_crdt import (
-        simulate_curve_crdt_sharded)
-    run = RunConfig(seed=0, max_rounds=16, target_coverage=1.0)
-    topo = G.complete(32)
-    cfg = CrdtConfig(kind="pncounter")
-    c1, _, f1, t1 = simulate_curve_crdt(cfg, _PROTO, topo, run, _CFAULT)
-    c4, _, f4, t4 = simulate_curve_crdt_sharded(cfg, _PROTO, topo, run,
-                                                _mesh(), _CFAULT)
-    assert (np.asarray(c1) == np.asarray(c4)).all()
-    assert (np.asarray(f1.val) == np.asarray(f4.val)[:32]).all()
-    assert t1 == t4
-
-
-# ~5 s (flight data, the log-PR rebalance): the integer-target until
-# cond stays pinned in-gate by the replicated-log twin
-# (tests/test_logs.py::test_until_driver_integer_target — the same
-# converged-count compare on the sibling payload) and the CLI crdt
-# run (no --curve) smokes the single-device until driver; this
-# CRDT-side single-vs-sharded depth runs under -m slow
-@pytest.mark.slow
-def test_until_driver_integer_target():
-    """The while_loop driver's cond is an exact integer converged-count
-    compare; single and sharded agree on rounds and the final value."""
-    from gossip_tpu.models.crdt import simulate_until_crdt
-    from gossip_tpu.parallel.sharded_crdt import (
-        simulate_until_crdt_sharded)
-    run = RunConfig(seed=0, max_rounds=24, target_coverage=1.0)
-    topo = G.complete(32)
-    cfg = CrdtConfig(kind="gcounter")
-    r1, c1, m1, f1, t1 = simulate_until_crdt(cfg, _PROTO, topo, run,
-                                             _CFAULT)
-    r4, c4, m4, f4, t4 = simulate_until_crdt_sharded(
-        cfg, _PROTO, topo, run, _mesh(), _CFAULT)
-    assert (r1, c1, t1) == (r4, c4, t4)
-    assert c1 == 1.0 and r1 < 24
-
-
-def test_crdt_rejections_are_loud():
-    from gossip_tpu.models.crdt import (make_crdt_round,
-                                        simulate_until_crdt)
-    with pytest.raises(ValueError, match="pull exchange only"):
-        make_crdt_round(CrdtConfig(kind="gcounter"),
-                        ProtocolConfig(mode=C.PUSH), G.complete(8))
-    with pytest.raises(ValueError, match="no exchange driver"):
-        make_crdt_round(CrdtConfig(kind="vclock"),
-                        ProtocolConfig(mode=C.PULL), G.complete(8))
-    # an injection the loop can never fire makes ground truth
-    # unreachable by construction — drivers reject it loudly instead
-    # of quietly reporting converged:false
-    with pytest.raises(ValueError, match="can never fire"):
-        simulate_until_crdt(
-            CrdtConfig(kind="gcounter", adds=((0, 100, 5),)), _PROTO,
-            G.complete(8), RunConfig(seed=0, max_rounds=8))
-
-
-# -- the value_conv round-metrics column -------------------------------
-
-# ~6 s (flight data, the log-PR rebalance): the payload-column
-# recorder mechanism (RM.init flag -> record -> emit, zero-impact
-# bitwise) is pinned in-gate by the log twin
-# (tests/test_logs.py::test_log_conv_round_metrics_emitted_and_
-# bitwise_free — the same recorder shape on the sibling column), and
-# the value_conv column itself stays asserted in-gate on the
-# committed record (test_committed_crdt_artifact_verdict); this live
-# CRDT emission depth runs under -m slow
-@pytest.mark.slow
-def test_value_conv_round_metrics_emitted_and_bitwise_free(tmp_path):
-    """With an active run ledger the sharded CRDT drivers flush a
-    round_metrics stack carrying the value_conv column (+ the nemesis
-    columns under churn); recording must not move the trajectory
-    bitwise (the ops/round_metrics zero-impact contract)."""
-    from gossip_tpu.parallel.sharded_crdt import (
-        simulate_curve_crdt_sharded)
-    from gossip_tpu.utils import telemetry
-    run = RunConfig(seed=0, max_rounds=12, target_coverage=1.0)
-    topo = G.complete(32)
-    cfg = CrdtConfig(kind="gcounter")
-    # metrics-off reference
-    c0, _, f0, _ = simulate_curve_crdt_sharded(cfg, _PROTO, topo, run,
-                                               _mesh(), _CFAULT)
-    path = str(tmp_path / "crdt_metrics.jsonl")
-    led = telemetry.Ledger(path)
-    prev = telemetry.activate(led)
-    try:
-        c1, _, f1, _ = simulate_curve_crdt_sharded(
-            cfg, _PROTO, topo, run, _mesh(), _CFAULT)
-    finally:
-        telemetry.activate(prev)
-        led.close()
-    assert (np.asarray(c0) == np.asarray(c1)).all()
-    assert (np.asarray(f0.val) == np.asarray(f1.val)).all()
-    evs = telemetry.load_ledger(path)
-    rms = [e for e in evs if e.get("ev") == "round_metrics"]
-    assert rms
-    e = rms[-1]
-    assert e["driver"] == "simulate_curve_crdt_sharded"
-    assert len(e["value_conv"]) == e["rounds"] == 12
-    assert e["totals"]["value_conv_final"] == pytest.approx(
-        float(c1[-1]), abs=1e-4)
-    # nemesis columns ride the same stack under the fault program
-    assert e["totals"]["dropped"] > 0
-    assert any(p > 0 for p in e["cut_pairs"])
-
-
-# -- CLI ---------------------------------------------------------------
-
-def test_cli_crdt_run_and_error_paths(capsys, monkeypatch):
-    from gossip_tpu import cli
-
-    # in-process cli.main: --no-compile-cache writes
-    # GOSSIP_COMPILE_CACHE="" into THIS process's env — monkeypatch
-    # re-pins the session cache dir for the tests that follow
-    monkeypatch.setenv("GOSSIP_COMPILE_CACHE",
-                       os.environ.get("GOSSIP_COMPILE_CACHE", ""))
-    rc = cli.main(["crdt", "--type", "gcounter", "--n", "32",
-                   "--max-rounds", "24", "--partition", "0:4:16",
-                   "--churn-event", "3:2:5", "--drop-ramp",
-                   "1:3:0.0:0.2", "--no-compile-cache"])
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rc == 0
-    assert out["mode"] == "crdt" and out["type"] == "gcounter"
-    assert out["converged"] is True and out["value_conv"] == 1.0
-    assert out["truth_value"] > 0 and out["fault_program"] is True
-    # scripted adds + curve
-    rc = cli.main(["crdt", "--type", "pncounter", "--n", "16",
-                   "--add", "0:0:9", "--add", "1:1:-4", "--curve",
-                   "--max-rounds", "12", "--no-compile-cache"])
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rc == 0
-    assert out["truth_value"] == 5
-    assert out["curve"][-1] == 1.0
-    # validation surfaces as a clean CLI error, never a traceback
-    rc = cli.main(["crdt", "--type", "gcounter", "--add", "0:0:-1",
-                   "--no-compile-cache"])
-    assert rc == 2
-    assert "positive" in capsys.readouterr().err
 
 
 # -- Maelstrom counter workload (the Gossip Glomers invariant) ---------

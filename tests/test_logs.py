@@ -1,10 +1,10 @@
-"""Replicated-log subsystem (ops/logs, models/log, parallel/
-sharded_log): config validation, offset-assignment + acked-appends
-ground truth, the partition-stall/exact-heal acceptance, 1-vs-4-device
-bitwise parity under the full mixed fault program, the log_conv
-round-metrics column, CLI + RPC fall-through + Maelstrom kafka
-workload surfaces, the committed artifact verdict pin, and the
-``*kafka*``/``*replog*`` provenance rule."""
+"""Replicated-log subsystem (ops/logs; its driver contract — heal,
+mesh parity, until target, refusals, the log_conv round-metrics
+column, CLI — is the log row of tests/test_payload.py): config
+validation, offset-assignment + acked-appends ground truth, RPC
+fall-through + Maelstrom kafka workload surfaces, the committed
+artifact verdict pin, and the ``*kafka*``/``*replog*`` provenance
+rule."""
 
 import json
 import os
@@ -18,14 +18,6 @@ from gossip_tpu.config import (ChurnConfig, FaultConfig, LogConfig,
 from gossip_tpu.topology import generators as G
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-_PROTO = ProtocolConfig(mode=C.PULL, fanout=2)
-# the full mixed fault program every parity/heal surface runs:
-# crash/recover, permanent crash, open partition window, drop ramp
-_CFAULT = FaultConfig(drop_prob=0.05, seed=1, churn=ChurnConfig(
-    events=((3, 2, 5), (7, 1, -1)), partitions=((0, 6, 16),),
-    ramp=(1, 4, 0.0, 0.3)))
-
 
 # -- config validation -------------------------------------------------
 
@@ -94,168 +86,6 @@ def test_ground_truth_acked_append_semantics():
     # the derived append cursor reads the contiguous prefix
     lens = np.asarray(LG.log_len(cfg, truth[None, :]))[0]
     assert lens.tolist() == [2, 0]
-
-
-# -- partition-heal convergence (the acceptance gate) ------------------
-
-def test_partition_stall_and_exact_heal():
-    """While the window is open, log convergence provably stalls (no
-    node holds the global acked log + committed offsets) and after
-    heal every eventual-alive node reaches the exact integer ground
-    truth — the ordered eventual-consistency invariant under the full
-    mixed fault program."""
-    from gossip_tpu.models.log import simulate_curve_log
-    from gossip_tpu.ops import logs as LG
-    cfg = LogConfig(keys=4, capacity=8)
-    run = RunConfig(seed=0, max_rounds=24, target_coverage=1.0)
-    n = 32
-    conv, _, final, truth = simulate_curve_log(cfg, _PROTO,
-                                               G.complete(n), run,
-                                               _CFAULT)
-    # stalled while the committed window [0, 6) is open
-    assert all(c < 1.0 for c in conv[:6]), list(conv)
-    assert conv[-1] == 1.0, list(conv)
-    # integer-exact: every eventual-alive node holds the truth row
-    inj = LG.inject_args(cfg, n)
-    truth_row = np.asarray(LG.ground_truth(cfg, inj, _CFAULT, n, 0))
-    eventual = np.asarray(LG.eventual_alive_crdt(_CFAULT, n, 0))
-    vals = np.asarray(final.val)
-    assert (vals[eventual] == truth_row[None, :]).all()
-    # the permanently-dead appender's sends are compacted out of truth
-    assert truth["total_entries"] < 16
-
-
-# -- mesh parity: schedules + injections as operands -------------------
-
-def _mesh(k=4):
-    from gossip_tpu.parallel.sharded import make_mesh
-    return make_mesh(k)
-
-
-def test_log_mesh_parity_bitwise_full_fault_program():
-    """1-device vs 4-device log trajectories BITWISE identical under
-    the full mixed fault program (event + permanent crash + open
-    partition window + ramp) — the acceptance criterion, plus exact
-    convergence on the eventual-alive set."""
-    from gossip_tpu.models.log import simulate_curve_log
-    from gossip_tpu.parallel.sharded_log import (
-        simulate_curve_log_sharded)
-    run = RunConfig(seed=0, max_rounds=16, target_coverage=1.0)
-    topo = G.complete(32)
-    cfg = LogConfig(keys=4, capacity=8)
-    c1, m1, f1, t1 = simulate_curve_log(cfg, _PROTO, topo, run, _CFAULT)
-    c4, m4, f4, t4 = simulate_curve_log_sharded(cfg, _PROTO, topo, run,
-                                                _mesh(), _CFAULT)
-    assert (np.asarray(c1) == np.asarray(c4)).all()
-    assert (np.asarray(f1.val) == np.asarray(f4.val)[:32]).all()
-    assert float(f1.msgs) == float(f4.msgs)
-    assert t1 == t4
-    assert c4[-1] == 1.0
-
-
-def test_until_driver_integer_target():
-    """The while_loop driver's cond is an exact integer converged-count
-    compare; single and sharded agree on rounds and the final value."""
-    from gossip_tpu.models.log import simulate_until_log
-    from gossip_tpu.parallel.sharded_log import (
-        simulate_until_log_sharded)
-    run = RunConfig(seed=0, max_rounds=24, target_coverage=1.0)
-    topo = G.complete(32)
-    cfg = LogConfig(keys=4, capacity=8)
-    r1, c1, m1, f1, t1 = simulate_until_log(cfg, _PROTO, topo, run,
-                                            _CFAULT)
-    r4, c4, m4, f4, t4 = simulate_until_log_sharded(
-        cfg, _PROTO, topo, run, _mesh(), _CFAULT)
-    assert (r1, c1, t1) == (r4, c4, t4)
-    assert c1 == 1.0 and r1 < 24
-
-
-def test_log_rejections_are_loud():
-    from gossip_tpu.models.log import make_log_round, simulate_until_log
-    with pytest.raises(ValueError, match="pull exchange only"):
-        make_log_round(LogConfig(), ProtocolConfig(mode=C.PUSH),
-                       G.complete(8))
-    # an injection the loop can never fire makes ground truth
-    # unreachable by construction — a loud error (models/crdt rule)
-    with pytest.raises(ValueError, match="can never fire"):
-        simulate_until_log(
-            LogConfig(sends=((0, 0, 100, 1),)), _PROTO, G.complete(8),
-            RunConfig(seed=0, max_rounds=8))
-
-
-# -- the log_conv round-metrics column ---------------------------------
-
-def test_log_conv_round_metrics_emitted_and_bitwise_free(tmp_path):
-    """With an active run ledger the sharded log drivers flush a
-    round_metrics stack carrying the log_conv column (+ the nemesis
-    columns under churn); recording must not move the trajectory
-    bitwise (the ops/round_metrics zero-impact contract)."""
-    from gossip_tpu.parallel.sharded_log import (
-        simulate_curve_log_sharded)
-    from gossip_tpu.utils import telemetry
-    run = RunConfig(seed=0, max_rounds=12, target_coverage=1.0)
-    topo = G.complete(32)
-    cfg = LogConfig(keys=4, capacity=8)
-    # metrics-off reference
-    c0, _, f0, _ = simulate_curve_log_sharded(cfg, _PROTO, topo, run,
-                                              _mesh(), _CFAULT)
-    path = str(tmp_path / "log_metrics.jsonl")
-    led = telemetry.Ledger(path)
-    prev = telemetry.activate(led)
-    try:
-        c1, _, f1, _ = simulate_curve_log_sharded(
-            cfg, _PROTO, topo, run, _mesh(), _CFAULT)
-    finally:
-        telemetry.activate(prev)
-        led.close()
-    assert (np.asarray(c0) == np.asarray(c1)).all()
-    assert (np.asarray(f0.val) == np.asarray(f1.val)).all()
-    evs = telemetry.load_ledger(path)
-    rms = [e for e in evs if e.get("ev") == "round_metrics"]
-    assert rms
-    e = rms[-1]
-    assert e["driver"] == "simulate_curve_log_sharded"
-    assert len(e["log_conv"]) == e["rounds"] == 12
-    assert e["totals"]["log_conv_final"] == pytest.approx(
-        float(c1[-1]), abs=1e-4)
-    # nemesis columns ride the same stack under the fault program
-    assert e["totals"]["dropped"] > 0
-    assert any(p > 0 for p in e["cut_pairs"])
-
-
-# -- CLI ---------------------------------------------------------------
-
-def test_cli_log_run_and_error_paths(capsys, monkeypatch):
-    from gossip_tpu import cli
-
-    # in-process cli.main: --no-compile-cache writes
-    # GOSSIP_COMPILE_CACHE="" into THIS process's env — monkeypatch
-    # re-pins the session cache dir for the tests that follow
-    monkeypatch.setenv("GOSSIP_COMPILE_CACHE",
-                       os.environ.get("GOSSIP_COMPILE_CACHE", ""))
-    rc = cli.main(["log", "--n", "32", "--max-rounds", "24",
-                   "--partition", "0:4:16", "--churn-event", "3:2:5",
-                   "--drop-ramp", "1:3:0.0:0.2", "--no-compile-cache"])
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rc == 0
-    assert out["mode"] == "log"
-    assert out["converged"] is True and out["log_conv"] == 1.0
-    assert out["truth"]["total_entries"] > 0
-    assert out["fault_program"] is True
-    # scripted sends/commits + curve
-    rc = cli.main(["log", "--n", "16", "--keys", "2",
-                   "--send", "0:0:0:9", "--send", "1:0:1:4",
-                   "--commit", "2:0:3:1", "--curve",
-                   "--max-rounds", "12", "--no-compile-cache"])
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rc == 0
-    assert out["truth"] == {"lens": [2, 0], "committed": [1, 0],
-                            "total_entries": 2}
-    assert out["curve"][-1] == 1.0
-    # validation surfaces as a clean CLI error, never a traceback
-    rc = cli.main(["log", "--send", "0:0:0:0", "--no-compile-cache"])
-    assert rc == 2
-    assert "values must be >= 1" in capsys.readouterr().err
 
 
 # -- RPC: the admission-batcher fall-through contract ------------------

@@ -1,12 +1,12 @@
-"""LWW-register transaction subsystem (ops/registers, models/register,
-parallel/sharded_register, runtime/txn_checker): config validation,
-the LWW join algebra + owner-order tie-break, acked-writes ground
-truth, the partition-stall/exact-heal acceptance, 1-vs-4-device
-bitwise parity under the full mixed fault program, the txn_conv
-round-metrics column, CLI + RPC fall-through + Maelstrom
-txn-rw-register workload surfaces, the weak-isolation checker (which
-MUST flag planted G0/G1a anomalies), the committed artifact verdict
-pin, and the ``*txn*``/``*register*`` provenance rule."""
+"""LWW-register transaction subsystem (ops/registers,
+runtime/txn_checker; the driver contract — heal, mesh parity, until
+target, refusals, the txn_conv round-metrics column, CLI — is the txn
+row of tests/test_payload.py): config validation, the LWW join algebra
++ owner-order tie-break, acked-writes ground truth, RPC fall-through +
+Maelstrom txn-rw-register workload surfaces, the weak-isolation
+checker (which MUST flag planted G0/G1a anomalies), the committed
+artifact verdict pin, and the ``*txn*``/``*register*`` provenance
+rule."""
 
 import json
 import os
@@ -22,11 +22,6 @@ from gossip_tpu.topology import generators as G
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _PROTO = ProtocolConfig(mode=C.PULL, fanout=2)
-# the full mixed fault program every parity/heal surface runs:
-# crash/recover, permanent crash, open partition window, drop ramp
-_CFAULT = FaultConfig(drop_prob=0.05, seed=1, churn=ChurnConfig(
-    events=((3, 2, 5), (7, 1, -1)), partitions=((0, 6, 16),),
-    ramp=(1, 4, 0.0, 0.3)))
 
 
 # -- config validation -------------------------------------------------
@@ -132,7 +127,7 @@ def test_tie_break_at_equal_round_is_owner_order():
     """Two writes to one key at the SAME round: the higher owner id
     wins — deterministic by the packed (round, owner) timestamp, on
     the ground truth AND on a full simulated trajectory."""
-    from gossip_tpu.models.register import simulate_curve_txn
+    from gossip_tpu.models.payload import simulate_curve_payload
     from gossip_tpu.ops import registers as RG
     n = 16
     cfg = TxnConfig(keys=2, writes=((3, 0, 1, 9), (5, 0, 1, 7),
@@ -143,8 +138,8 @@ def test_tie_break_at_equal_round_is_owner_order():
     assert RG.truth_summary(cfg, truth, n)["ts_owner"][0] == 5
     # and the trajectory converges to that winner everywhere
     run = RunConfig(seed=0, max_rounds=12, target_coverage=1.0)
-    conv, _, final, ts = simulate_curve_txn(cfg, _PROTO, G.complete(n),
-                                            run)
+    conv, _, final, ts = simulate_curve_payload(cfg, _PROTO,
+                                                G.complete(n), run)
     assert conv[-1] == 1.0
     assert ts["values"][0] == 7 and ts["ts_owner"][0] == 5
     # a LATER round beats any same-round owner: round order dominates
@@ -178,134 +173,6 @@ def test_ground_truth_acked_write_semantics():
     with pytest.raises(ValueError, match="overflows int32"):
         RG.check_ts_packable(TxnConfig(writes=((0, 0, 90_000, 1),)),
                              50_000)
-
-
-# -- partition-heal convergence (the acceptance gate) ------------------
-
-def test_partition_stall_and_exact_heal():
-    """While the window is open, txn convergence provably stalls (no
-    node holds the global LWW winners) and after heal every
-    eventual-alive node reaches the exact integer ground truth —
-    value AND timestamp planes — under the full mixed fault
-    program."""
-    from gossip_tpu.models.register import simulate_curve_txn
-    from gossip_tpu.ops import registers as RG
-    cfg = TxnConfig(keys=8, txns=24, zipf_alpha=1.2, hot_key=0.3)
-    run = RunConfig(seed=0, max_rounds=24, target_coverage=1.0)
-    n = 32
-    conv, _, final, truth = simulate_curve_txn(cfg, _PROTO,
-                                               G.complete(n), run,
-                                               _CFAULT)
-    # stalled while the committed window [0, 6) is open
-    assert all(c < 1.0 for c in conv[:6]), list(conv)
-    assert conv[-1] == 1.0, list(conv)
-    # integer-exact: every eventual-alive node holds the truth row
-    inj = RG.inject_args(cfg, n)
-    truth_row = np.asarray(RG.ground_truth(cfg, inj, _CFAULT, n, 0))
-    eventual = np.asarray(RG.eventual_alive_crdt(_CFAULT, n, 0))
-    vals = np.asarray(final.val)
-    assert (vals[eventual] == truth_row[None, :]).all()
-    # the permanently-dead writer's writes won nothing
-    assert 7 not in truth["ts_owner"]
-
-
-# -- mesh parity: schedules + write programs as operands ---------------
-
-def _mesh(k=4):
-    from gossip_tpu.parallel.sharded import make_mesh
-    return make_mesh(k)
-
-
-def test_txn_mesh_parity_bitwise_full_fault_program():
-    """1-device vs 4-device register trajectories BITWISE identical
-    under the full mixed fault program (event + permanent crash + open
-    partition window + ramp) — the acceptance criterion, plus exact
-    convergence on the eventual-alive set."""
-    from gossip_tpu.models.register import simulate_curve_txn
-    from gossip_tpu.parallel.sharded_register import (
-        simulate_curve_txn_sharded)
-    run = RunConfig(seed=0, max_rounds=16, target_coverage=1.0)
-    topo = G.complete(32)
-    cfg = TxnConfig(keys=8, txns=16, zipf_alpha=1.2, hot_key=0.3)
-    c1, m1, f1, t1 = simulate_curve_txn(cfg, _PROTO, topo, run, _CFAULT)
-    c4, m4, f4, t4 = simulate_curve_txn_sharded(cfg, _PROTO, topo, run,
-                                                _mesh(), _CFAULT)
-    assert (np.asarray(c1) == np.asarray(c4)).all()
-    assert (np.asarray(f1.val) == np.asarray(f4.val)[:32]).all()
-    assert float(f1.msgs) == float(f4.msgs)
-    assert t1 == t4
-    assert c4[-1] == 1.0
-
-
-def test_until_driver_integer_target():
-    """The while_loop driver's cond is an exact integer converged-count
-    compare; single and sharded agree on rounds and the final value."""
-    from gossip_tpu.models.register import simulate_until_txn
-    from gossip_tpu.parallel.sharded_register import (
-        simulate_until_txn_sharded)
-    run = RunConfig(seed=0, max_rounds=24, target_coverage=1.0)
-    topo = G.complete(32)
-    cfg = TxnConfig(keys=8, txns=16)
-    r1, c1, m1, f1, t1 = simulate_until_txn(cfg, _PROTO, topo, run,
-                                            _CFAULT)
-    r4, c4, m4, f4, t4 = simulate_until_txn_sharded(
-        cfg, _PROTO, topo, run, _mesh(), _CFAULT)
-    assert (r1, c1, t1) == (r4, c4, t4)
-    assert c1 == 1.0 and r1 < 24
-
-
-def test_txn_rejections_are_loud():
-    from gossip_tpu.models.register import (make_register_round,
-                                            simulate_until_txn)
-    with pytest.raises(ValueError, match="pull exchange only"):
-        make_register_round(TxnConfig(), ProtocolConfig(mode=C.PUSH),
-                            G.complete(8))
-    # a write the loop can never fire makes ground truth unreachable
-    # by construction — a loud error (models/crdt rule)
-    with pytest.raises(ValueError, match="can never fire"):
-        simulate_until_txn(
-            TxnConfig(writes=((0, 0, 100, 1),)), _PROTO, G.complete(8),
-            RunConfig(seed=0, max_rounds=8))
-
-
-# -- the txn_conv round-metrics column ---------------------------------
-
-def test_txn_conv_round_metrics_emitted_and_bitwise_free(tmp_path):
-    """With an active run ledger the sharded register drivers flush a
-    round_metrics stack carrying the txn_conv column (+ the nemesis
-    columns under churn); recording must not move the trajectory
-    bitwise (the ops/round_metrics zero-impact contract)."""
-    from gossip_tpu.parallel.sharded_register import (
-        simulate_curve_txn_sharded)
-    from gossip_tpu.utils import telemetry
-    run = RunConfig(seed=0, max_rounds=12, target_coverage=1.0)
-    topo = G.complete(32)
-    cfg = TxnConfig(keys=8, txns=16)
-    # metrics-off reference
-    c0, _, f0, _ = simulate_curve_txn_sharded(cfg, _PROTO, topo, run,
-                                              _mesh(), _CFAULT)
-    path = str(tmp_path / "txn_metrics.jsonl")
-    led = telemetry.Ledger(path)
-    prev = telemetry.activate(led)
-    try:
-        c1, _, f1, _ = simulate_curve_txn_sharded(
-            cfg, _PROTO, topo, run, _mesh(), _CFAULT)
-    finally:
-        telemetry.activate(prev)
-        led.close()
-    assert (np.asarray(c0) == np.asarray(c1)).all()
-    assert (np.asarray(f0.val) == np.asarray(f1.val)).all()
-    evs = telemetry.load_ledger(path)
-    rms = [e for e in evs if e.get("ev") == "round_metrics"]
-    assert rms
-    e = rms[-1]
-    assert e["driver"] == "simulate_curve_txn_sharded"
-    assert len(e["txn_conv"]) == e["rounds"] == 12
-    assert e["totals"]["txn_conv_final"] == pytest.approx(
-        float(c1[-1]), abs=1e-4)
-    # nemesis columns ride the same stack under the fault program
-    assert e["totals"]["dropped"] > 0
-    assert any(p > 0 for p in e["cut_pairs"])
 
 
 # -- the weak-isolation checker (it MUST flag planted anomalies) -------
@@ -514,43 +381,6 @@ def test_checker_reports_lost_update_without_failing_verdict():
     ]
     out3 = check_txn_trace(serial)
     assert not out3["lost_update"] and out3["ok"]
-
-
-# -- CLI ---------------------------------------------------------------
-
-def test_cli_txn_run_and_error_paths(capsys, monkeypatch):
-    from gossip_tpu import cli
-
-    # in-process cli.main: --no-compile-cache writes
-    # GOSSIP_COMPILE_CACHE="" into THIS process's env — monkeypatch
-    # re-pins the session cache dir for the tests that follow
-    monkeypatch.setenv("GOSSIP_COMPILE_CACHE",
-                       os.environ.get("GOSSIP_COMPILE_CACHE", ""))
-    rc = cli.main(["txn", "--n", "32", "--max-rounds", "24",
-                   "--partition", "0:4:16", "--churn-event", "3:2:5",
-                   "--drop-ramp", "1:3:0.0:0.2", "--zipf-alpha", "1.3",
-                   "--hot-key", "0.4", "--no-compile-cache"])
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rc == 0
-    assert out["mode"] == "txn"
-    assert out["converged"] is True and out["txn_conv"] == 1.0
-    assert out["truth"]["written_keys"] > 0
-    assert out["fault_program"] is True
-    assert out["zipf_alpha"] == 1.3 and out["hot_key"] == 0.4
-    # scripted writes + curve: the owner tie-break is visible in truth
-    rc = cli.main(["txn", "--n", "16", "--keys", "2",
-                   "--write", "3:0:1:9", "--write", "5:0:1:7",
-                   "--write", "1:1:0:4", "--curve",
-                   "--max-rounds", "12", "--no-compile-cache"])
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rc == 0
-    assert out["truth"]["values"] == [7, 4]
-    assert out["truth"]["ts_owner"] == [5, 1]
-    assert out["curve"][-1] == 1.0
-    # validation surfaces as a clean CLI error, never a traceback
-    rc = cli.main(["txn", "--write", "0:0:0:0", "--no-compile-cache"])
-    assert rc == 2
-    assert "values must be >= 1" in capsys.readouterr().err
 
 
 # -- RPC: the admission-batcher fall-through contract ------------------

@@ -83,15 +83,12 @@ def main(argv=None):
     from gossip_tpu.config import (ByzConfig, ChurnConfig, CrdtConfig,
                                    FaultConfig, ProtocolConfig,
                                    RunConfig, TxnConfig)
-    from gossip_tpu.models.crdt import simulate_curve_crdt
-    from gossip_tpu.models.register import simulate_curve_txn
+    from gossip_tpu.models.payload import simulate_curve_payload
     from gossip_tpu.ops import crdt as CR
     from gossip_tpu.ops import nemesis as NE
     from gossip_tpu.ops import registers as RG
-    from gossip_tpu.parallel.sharded_crdt import (
-        simulate_curve_crdt_sharded)
-    from gossip_tpu.parallel.sharded_register import (
-        simulate_curve_txn_sharded)
+    from gossip_tpu.parallel.sharded_payload import (
+        simulate_curve_payload_sharded)
     from gossip_tpu.topology.generators import complete
     from gossip_tpu.utils import telemetry
 
@@ -118,9 +115,9 @@ def main(argv=None):
         # -- gcounter leg: defended exact vs undefended divergence ----
         cfg = CrdtConfig(kind="gcounter")
         with led.span("byz:gcounter"):
-            conv_u, _, fin_u, _ = simulate_curve_crdt(
+            conv_u, _, fin_u, _ = simulate_curve_payload(
                 cfg, proto, topo, run, fault, defend=False)
-            conv_d, _, fin_d, _ = simulate_curve_crdt(
+            conv_d, _, fin_d, _ = simulate_curve_payload(
                 cfg, proto, topo, run, fault, defend=True)
         inj = CR.inject_args(cfg, N)
         truth = CR.ground_truth(cfg, inj, fault, N, 0)
@@ -137,9 +134,9 @@ def main(argv=None):
         # (the sharded runs flush round_metrics w/ byz_conv into the
         # ledger under the active telemetry)
         with led.span("byz:mesh_parity"):
-            _, _, f1, _ = simulate_curve_crdt_sharded(
+            _, _, f1, _ = simulate_curve_payload_sharded(
                 cfg, proto, topo, run, mesh1, fault, defend=True)
-            c4, _, f4, _ = simulate_curve_crdt_sharded(
+            c4, _, f4, _ = simulate_curve_payload_sharded(
                 cfg, proto, topo, run, mesh4, fault, defend=True)
         parity = bool(
             np.array_equal(np.asarray(f1.val)[:N],
@@ -167,11 +164,11 @@ def main(argv=None):
         if not smoke:
             cfgt = TxnConfig(txns=12, keys=6, spread_rounds=8)
             with led.span("byz:register"):
-                ru = simulate_curve_txn(cfgt, proto, topo, run, fault,
+                ru = simulate_curve_payload(cfgt, proto, topo, run, fault,
                                         defend=False)
-                rd = simulate_curve_txn(cfgt, proto, topo, run, fault,
+                rd = simulate_curve_payload(cfgt, proto, topo, run, fault,
                                         defend=True)
-                r4 = simulate_curve_txn_sharded(cfgt, proto, topo,
+                r4 = simulate_curve_payload_sharded(cfgt, proto, topo,
                                                 run, mesh4, fault,
                                                 defend=True)
             injt = RG.inject_args(cfgt, N)

@@ -66,16 +66,12 @@ def _engine_compiles(led, mesh, n_devices):
                                    ProtocolConfig, RunConfig, TxnConfig)
     from gossip_tpu.parallel.sharded import (init_sharded_state,
                                              make_sharded_si_round)
-    from gossip_tpu.parallel.sharded_crdt import (
-        init_sharded_crdt_state, make_sharded_crdt_round)
     from gossip_tpu.parallel.sharded_fused import (
         make_plane_mesh, simulate_until_sharded_fused)
-    from gossip_tpu.parallel.sharded_log import (
-        init_sharded_log_state, make_sharded_log_round)
     from gossip_tpu.parallel.sharded_packed import (
         init_sharded_packed_state, make_sharded_packed_round)
-    from gossip_tpu.parallel.sharded_register import (
-        init_sharded_reg_state, make_sharded_register_round)
+    from gossip_tpu.parallel.sharded_payload import (
+        init_sharded_payload_state, make_sharded_payload_round)
     from gossip_tpu.parallel.sharded_sparse import (
         init_sparse_state, make_sparse_pull_round)
     from gossip_tpu.topology import generators as G
@@ -116,22 +112,22 @@ def _engine_compiles(led, mesh, n_devices):
 
     dproto = ProtocolConfig(mode=C.PULL, fanout=2)
     dcfg = CrdtConfig(kind="gcounter")
-    cstep = jax.jit(make_sharded_crdt_round(dcfg, dproto, topo, mesh,
+    cstep = jax.jit(make_sharded_payload_round(dcfg, dproto, topo, mesh,
                                             fault, run.origin))
-    cstate = init_sharded_crdt_state(run, dcfg, topo, mesh)
+    cstate = init_sharded_payload_state(run, dcfg, topo, mesh)
     verdicts["crdt"] = case("crdt", cstep, cstate)
 
     gcfg = LogConfig(keys=4, capacity=8)
-    gstep = jax.jit(make_sharded_log_round(gcfg, dproto, topo, mesh,
+    gstep = jax.jit(make_sharded_payload_round(gcfg, dproto, topo, mesh,
                                            fault, run.origin))
-    gstate = init_sharded_log_state(run, gcfg, topo, mesh)
+    gstate = init_sharded_payload_state(run, gcfg, topo, mesh)
     verdicts["log"] = case("log", gstep, gstate)
 
     xcfg = TxnConfig(keys=8, txns=16, zipf_alpha=1.2, hot_key=0.3)
-    xstep = jax.jit(make_sharded_register_round(xcfg, dproto, topo,
+    xstep = jax.jit(make_sharded_payload_round(xcfg, dproto, topo,
                                                 mesh, fault,
                                                 run.origin))
-    xstate = init_sharded_reg_state(run, xcfg, topo, mesh)
+    xstate = init_sharded_payload_state(run, xcfg, topo, mesh)
     verdicts["txn"] = case("txn", xstep, xstate)
 
     # the fused driver compiles INSIDE simulate_until_sharded_fused —

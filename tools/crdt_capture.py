@@ -53,10 +53,10 @@ def main(argv=None):
     import numpy as np
     from gossip_tpu.config import (ChurnConfig, CrdtConfig, FaultConfig,
                                    ProtocolConfig, RunConfig)
-    from gossip_tpu.models.crdt import simulate_curve_crdt
+    from gossip_tpu.models.payload import simulate_curve_payload
     from gossip_tpu.parallel.sharded import make_mesh
-    from gossip_tpu.parallel.sharded_crdt import (
-        simulate_curve_crdt_sharded)
+    from gossip_tpu.parallel.sharded_payload import (
+        simulate_curve_payload_sharded)
     from gossip_tpu.topology import generators as G
     from gossip_tpu.utils import telemetry
 
@@ -89,9 +89,9 @@ def main(argv=None):
                   n=N, max_rounds=MAX_ROUNDS)
         for name, cfg in kinds:
             with led.span(f"crdt:{name}", kind=name):
-                conv4, msgs4, fin4, truth4 = simulate_curve_crdt_sharded(
+                conv4, msgs4, fin4, truth4 = simulate_curve_payload_sharded(
                     cfg, proto, topo, run, mesh, fault)
-                conv1, msgs1, fin1, truth1 = simulate_curve_crdt(
+                conv1, msgs1, fin1, truth1 = simulate_curve_payload(
                     cfg, proto, topo, run, fault)
             parity = bool(
                 (np.asarray(conv1) == np.asarray(conv4)).all()

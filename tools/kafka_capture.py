@@ -59,10 +59,10 @@ def main(argv=None):
     import numpy as np
     from gossip_tpu.config import (ChurnConfig, FaultConfig, LogConfig,
                                    ProtocolConfig, RunConfig)
-    from gossip_tpu.models.log import simulate_curve_log
+    from gossip_tpu.models.payload import simulate_curve_payload
     from gossip_tpu.parallel.sharded import make_mesh
-    from gossip_tpu.parallel.sharded_log import (
-        simulate_curve_log_sharded)
+    from gossip_tpu.parallel.sharded_payload import (
+        simulate_curve_payload_sharded)
     from gossip_tpu.topology import generators as G
     from gossip_tpu.utils import telemetry
 
@@ -90,9 +90,9 @@ def main(argv=None):
                   n=N, keys=cfg.keys, capacity=cfg.capacity,
                   max_rounds=MAX_ROUNDS)
         with led.span("kafka:log", keys=cfg.keys):
-            conv4, msgs4, fin4, truth4 = simulate_curve_log_sharded(
+            conv4, msgs4, fin4, truth4 = simulate_curve_payload_sharded(
                 cfg, proto, topo, run, mesh, fault)
-            conv1, msgs1, fin1, truth1 = simulate_curve_log(
+            conv1, msgs1, fin1, truth1 = simulate_curve_payload(
                 cfg, proto, topo, run, fault)
         parity = bool(
             (np.asarray(conv1) == np.asarray(conv4)).all()

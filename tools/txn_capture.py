@@ -72,10 +72,10 @@ def main(argv=None):
     from gossip_tpu.config import (ChurnConfig, FaultConfig,
                                    ProtocolConfig, RunConfig,
                                    TxnConfig)
-    from gossip_tpu.models.register import simulate_curve_txn
+    from gossip_tpu.models.payload import simulate_curve_payload
     from gossip_tpu.parallel.sharded import make_mesh
-    from gossip_tpu.parallel.sharded_register import (
-        simulate_curve_txn_sharded)
+    from gossip_tpu.parallel.sharded_payload import (
+        simulate_curve_payload_sharded)
     from gossip_tpu.runtime.maelstrom_harness import run_txn_workload
     from gossip_tpu.topology import generators as G
     from gossip_tpu.utils import telemetry
@@ -105,9 +105,9 @@ def main(argv=None):
                   zipf_alpha=cfg.zipf_alpha, hot_key=cfg.hot_key,
                   max_rounds=MAX_ROUNDS)
         with led.span("txn:register", keys=cfg.keys):
-            conv4, msgs4, fin4, truth4 = simulate_curve_txn_sharded(
+            conv4, msgs4, fin4, truth4 = simulate_curve_payload_sharded(
                 cfg, proto, topo, run, mesh, fault)
-            conv1, msgs1, fin1, truth1 = simulate_curve_txn(
+            conv1, msgs1, fin1, truth1 = simulate_curve_payload(
                 cfg, proto, topo, run, fault)
         parity = bool(
             (np.asarray(conv1) == np.asarray(conv4)).all()
