@@ -267,18 +267,19 @@ def _run_fused(proto: ProtocolConfig, tc: TopologyConfig, run: RunConfig,
             compiled_curve_fused, compiled_curve_fused_multirumor)
         if proto.rumors == 1:
             scan, init = compiled_curve_fused(
-                n, seed=run.seed, fanout=proto.fanout,
+                n, fanout=proto.fanout,
                 max_rounds=run.max_rounds, origin=run.origin,
                 interpret=False, fault=fault)
         else:
             scan, init = compiled_curve_fused_multirumor(
-                n, proto.rumors, seed=run.seed, fanout=proto.fanout,
+                n, proto.rumors, fanout=proto.fanout,
                 max_rounds=run.max_rounds, origin=run.origin,
                 interpret=False, fault=fault)
         from gossip_tpu.utils.trace import maybe_aot_timed
         timing: Dict[str, float] = {}
         t0 = time.perf_counter()
-        final, covs = maybe_aot_timed(scan, timing, init, label="solo")
+        final, covs = maybe_aot_timed(scan, timing, init,
+                                      jnp.int32(run.seed), label="solo")
         wall = time.perf_counter() - t0
         # the scanned state already accumulated the closed-form total
         rounds, cov, msgs, curve = _curve_summary(
@@ -297,7 +298,7 @@ def _run_fused(proto: ProtocolConfig, tc: TopologyConfig, run: RunConfig,
     with span(BUILD):
         if proto.rumors == 1:
             loop, init = compiled_until_fused(
-                n, seed=run.seed, fanout=proto.fanout,
+                n, fanout=proto.fanout,
                 target_coverage=run.target_coverage,
                 max_rounds=run.max_rounds, origin=run.origin, fault=fault)
             # the SAME weighting chooser the loop's cond uses — cannot drift
@@ -305,7 +306,7 @@ def _run_fused(proto: ProtocolConfig, tc: TopologyConfig, run: RunConfig,
             cov_fn = fused_cov_fn(n, fault, run.origin)
         else:
             loop, init = compiled_until_fused_multirumor(
-                n, proto.rumors, seed=run.seed, fanout=proto.fanout,
+                n, proto.rumors, fanout=proto.fanout,
                 target_coverage=run.target_coverage,
                 max_rounds=run.max_rounds, origin=run.origin, fault=fault)
             from gossip_tpu.ops.pallas_round import fused_mr_cov_fn
@@ -313,7 +314,10 @@ def _run_fused(proto: ProtocolConfig, tc: TopologyConfig, run: RunConfig,
 
     timing: Dict[str, float] = {}
     t0 = time.perf_counter()
-    final = maybe_aot_timed(loop, timing, init, label="solo")
+    # the seed is an operand of the loop, not a constant: one executable
+    # (one compile-layer key) serves every seed of a study
+    final = maybe_aot_timed(loop, timing, init, jnp.int32(run.seed),
+                            label="solo")
     wall = time.perf_counter() - t0
     with span(READBACK):
         cov = float(cov_fn(final.table))

@@ -1074,20 +1074,21 @@ def init_multirumor_state(n: int, rumors: int, origin: int = 0):
                       msgs=jnp.float32(0.0))
 
 
-def compiled_curve_fused(n: int, seed: int, fanout: int = 1,
+def compiled_curve_fused(n: int, fanout: int = 1,
                          max_rounds: int = 128, origin: int = 0,
                          interpret: bool = False, fault=None):
     """(scan, init): fixed-length ``lax.scan`` over the fused
     single-rumor kernel recording per-round coverage — the curve twin of
     :func:`compiled_until_fused` (no early exit; rounds-to-target is
     derived from the curve by the caller).  Same kernel, same fault
-    masks, same alive-weighted coverage chooser."""
+    masks, same alive-weighted coverage chooser, and the same seed
+    operand: ``scan(init, seed)``, one executable for every seed."""
     drop_threshold = drop_threshold_for(fault)
     has_alive = fault is not None and bool(fault.node_death_rate)
     cov = fused_cov_fn(n, fault, origin)
 
     @functools.partial(jax.jit, donate_argnums=0)
-    def scan(st: FusedState):
+    def scan(st: FusedState, seed: jax.Array):
         def body(s, _):
             alive_tab = (fault_masks_node_packed(fault, n, origin)[0]
                          if has_alive else None)
@@ -1103,7 +1104,7 @@ def compiled_curve_fused(n: int, seed: int, fanout: int = 1,
     return scan, init_fused_state(n, origin)
 
 
-def compiled_until_fused_multirumor(n: int, rumors: int, seed: int,
+def compiled_until_fused_multirumor(n: int, rumors: int,
                                     fanout: int = 1,
                                     target_coverage: float = 0.99,
                                     max_rounds: int = 128, origin: int = 0,
@@ -1112,13 +1113,18 @@ def compiled_until_fused_multirumor(n: int, rumors: int, seed: int,
     using the multi-rumor fused kernel (hw PRNG — distributionally equal to
     but a different stream from the threefry path).  ``fault`` enables
     the kernel's static fault masks; the cond switches to the
-    alive-weighted coverage (fused_mr_cov_fn)."""
+    alive-weighted coverage (fused_mr_cov_fn).
+
+    The seed is a runtime operand, ``loop(init, seed)`` with ``seed`` an
+    int32 scalar: it is not a constant of the program, so one executable
+    serves a whole seed study (the compile layer's key is the lowered
+    text, which no seed changes)."""
     target = jnp.float32(target_coverage)
     drop_threshold = drop_threshold_for(fault)
     has_alive = fault is not None and bool(fault.node_death_rate)
     cov = fused_mr_cov_fn(n, rumors, fault, origin)
 
-    def step(st: FusedState) -> FusedState:
+    def step(st: FusedState, seed: jax.Array) -> FusedState:
         # alive words rebuilt IN-TRACE (loop-invariant, hoisted): no
         # O(N) constant baked into the donated jit below
         alive_words = (fault_masks_word(fault, n, origin)[0]
@@ -1132,29 +1138,30 @@ def compiled_until_fused_multirumor(n: int, rumors: int, seed: int,
                           msgs=st.msgs + 2.0 * fanout * n)
 
     @functools.partial(jax.jit, donate_argnums=0)
-    def loop(st: FusedState) -> FusedState:
+    def loop(st: FusedState, seed: jax.Array) -> FusedState:
         def cond(s):
             with jax.named_scope(COVERAGE):
                 return (cov(s.table) < target) & (s.round < max_rounds)
-        return jax.lax.while_loop(cond, step, st)
+        return jax.lax.while_loop(cond, lambda s: step(s, seed), st)
 
     return loop, init_multirumor_state(n, rumors, origin)
 
 
-def compiled_curve_fused_multirumor(n: int, rumors: int, seed: int,
+def compiled_curve_fused_multirumor(n: int, rumors: int,
                                     fanout: int = 1, max_rounds: int = 128,
                                     origin: int = 0,
                                     interpret: bool = False, fault=None):
     """(scan, init): the curve twin of
     :func:`compiled_until_fused_multirumor` — fixed-length scan
     recording per-round min-over-rumors coverage (alive-weighted under
-    deaths)."""
+    deaths), with the seed a runtime operand: ``scan(init, seed)``,
+    one executable for every seed."""
     drop_threshold = drop_threshold_for(fault)
     has_alive = fault is not None and bool(fault.node_death_rate)
     cov = fused_mr_cov_fn(n, rumors, fault, origin)
 
     @functools.partial(jax.jit, donate_argnums=0)
-    def scan(st: FusedState):
+    def scan(st: FusedState, seed: jax.Array):
         def body(s, _):
             alive_words = (fault_masks_word(fault, n, origin)[0]
                            if has_alive else None)
@@ -1237,7 +1244,7 @@ def fused_cov_fn(n: int, fault=None, origin: int = 0):
     return cov
 
 
-def compiled_until_fused(n: int, seed: int, fanout: int = 1,
+def compiled_until_fused(n: int, fanout: int = 1,
                          target_coverage: float = 0.99,
                          max_rounds: int = 128, origin: int = 0,
                          interpret: bool = False, fault=None):
@@ -1250,13 +1257,18 @@ def compiled_until_fused(n: int, seed: int, fanout: int = 1,
     dropped and dead-partner pulls likewise).  ``fault`` (round 4)
     enables the kernel's static fault masks; the loop's target compare
     switches to the alive-weighted coverage (fused_cov_fn).
+
+    The seed is a runtime operand, ``loop(init, seed)`` with ``seed`` an
+    int32 scalar: it is not a constant of the program, so one executable
+    serves a whole seed study (the compile layer's key is the lowered
+    text, which no seed changes).
     """
     target = jnp.float32(target_coverage)
     drop_threshold = drop_threshold_for(fault)
     has_alive = fault is not None and bool(fault.node_death_rate)
     cov = fused_cov_fn(n, fault, origin)
 
-    def step(st: FusedState) -> FusedState:
+    def step(st: FusedState, seed: jax.Array) -> FusedState:
         # alive bitmap rebuilt IN-TRACE (loop-invariant, hoisted): no
         # O(N) constant baked into the donated jit below
         alive_tab = (fault_masks_node_packed(fault, n, origin)[0]
@@ -1269,10 +1281,10 @@ def compiled_until_fused(n: int, seed: int, fanout: int = 1,
                           msgs=st.msgs + 2.0 * fanout * n)
 
     @functools.partial(jax.jit, donate_argnums=0)
-    def loop(st: FusedState) -> FusedState:
+    def loop(st: FusedState, seed: jax.Array) -> FusedState:
         def cond(s):
             with jax.named_scope(COVERAGE):
                 return (cov(s.table) < target) & (s.round < max_rounds)
-        return jax.lax.while_loop(cond, step, st)
+        return jax.lax.while_loop(cond, lambda s: step(s, seed), st)
 
     return loop, init_fused_state(n, origin)

@@ -67,11 +67,30 @@ def _fits(compiled):
 def test_fused_loop_compiles_with_kernel(one_chip, n, rumors):
     from gossip_tpu.ops import pallas_round as PR
     if rumors == 1:
-        loop, init = PR.compiled_until_fused(n, seed=0)
+        loop, init = PR.compiled_until_fused(n)
     else:
-        loop, init = PR.compiled_until_fused_multirumor(n, rumors, seed=0)
-    text = _fits(loop.lower(_shapes(init, one_chip)).compile())
+        loop, init = PR.compiled_until_fused_multirumor(n, rumors)
+    seed = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    text = _fits(loop.lower(_shapes(init, one_chip), seed).compile())
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n,rumors", [(1_000_000, 1), (10_000_000, 8)],
+                         ids=["fused-1M", "fused-mr-10Mx8"])
+def test_fused_loop_lowering_with_kernel_is_seed_free(one_chip, n, rumors):
+    """The chip's lowering (the Mosaic kernels, the staged path at 10M x
+    8) has no seed in it: two seeds give one program text, so one
+    executable serves a seed study."""
+    from gossip_tpu.ops import pallas_round as PR
+    if rumors == 1:
+        loop, init = PR.compiled_until_fused(n)
+    else:
+        loop, init = PR.compiled_until_fused_multirumor(n, rumors)
+    state = _shapes(init, one_chip)
+    texts = {loop.lower(state, jnp.int32(s)).as_text()
+             for s in (5, 2**31 - 2)}
+    assert len(texts) == 1
+    assert "tpu_custom_call" in texts.pop()
 
 
 def test_packed_xla_loop_compiles(one_chip):

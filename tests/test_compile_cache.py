@@ -21,6 +21,7 @@ Three layers of proof:
      layer on whole processes).
 """
 
+import functools
 import json
 import os
 import subprocess
@@ -349,6 +350,43 @@ def test_rumor_report_meta_says_the_memo_served_a_fresh_seed(own_cache,
     assert [m["exec_memo"] for m in metas] == [False, True]
     for m in metas:
         assert m["compile_s"] >= 0.0 and m["steady_wall_s"] > 0.0
+
+
+def test_fused_report_meta_says_the_memo_served_a_fresh_seed(
+        own_cache, fresh_memo, monkeypatch):
+    """The fused multi-rumor loop takes the seed as an operand: on its
+    staged big-table path (the 10M x 8 route, here at a small n with
+    the kernels in interpret mode), a second simulation on a fresh seed
+    is served by the memo from the store entry the first one wrote."""
+    from gossip_tpu import backend
+    from gossip_tpu.backend import run_simulation
+    from gossip_tpu.config import TopologyConfig
+    from gossip_tpu.ops import pallas_round as PR
+    real_reason = backend._fused_ineligible_reason
+
+    def reason(*a, **kw):
+        r = real_reason(*a, **kw)
+        return None if r and "needs a TPU" in r else r
+
+    monkeypatch.setattr(backend, "_fused_ineligible_reason", reason)
+    monkeypatch.setattr(PR, "_mr_wants_big", lambda *a: True)
+    monkeypatch.setattr(PR, "compiled_until_fused_multirumor",
+                        functools.partial(PR.compiled_until_fused_multirumor,
+                                          interpret=True))
+    PR._fused_mr_round_jit.clear_cache()
+    proto = ProtocolConfig(mode="pull", fanout=1, rumors=8)
+    tc = TopologyConfig(family="complete", n=4096)
+    try:
+        reps, caches = [], []
+        for seed in (1_234_567_891, 987_654_321):
+            reps.append(run_simulation("jax-tpu", proto, tc,
+                                       RunConfig(seed=seed, max_rounds=8)))
+            caches.append(compile_cache.last_compile()["cache"])
+    finally:
+        PR._fused_mr_round_jit.clear_cache()
+    assert [r.meta["engine"] for r in reps] == ["fused-pallas"] * 2
+    assert [r.meta["exec_memo"] for r in reps] == [False, True]
+    assert caches == ["miss", "hit"]
 
 
 # -- warm-vs-cold bitwise equality, driver by driver -------------------

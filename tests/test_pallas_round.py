@@ -264,9 +264,8 @@ class TestHardwarePRNGMultirumor:
 
     def test_mr_curve_matches_mean_field(self):
         n, r = 1 << 18, 8
-        loop, init = compiled_until_fused_multirumor(n, r, seed=0,
-                                                     max_rounds=64)
-        final = loop(init)
+        loop, init = compiled_until_fused_multirumor(n, r, max_rounds=64)
+        final = loop(init, jnp.int32(0))
         got = int(final.round)
         c, want = 1.0 / n, 0
         while c < 0.99:
@@ -303,8 +302,8 @@ class TestHardwarePRNG:
         (c' = 1-(1-c)^2 from c0=1/N) within +/-3 rounds, like the threefry
         pull path does."""
         n = 1 << 18
-        loop, init = compiled_until_fused(n, seed=0, max_rounds=64)
-        final = loop(init)
+        loop, init = compiled_until_fused(n, max_rounds=64)
+        final = loop(init, jnp.int32(0))
         got = int(final.round)
         c, want = 1.0 / n, 0
         while c < 0.99:
@@ -315,10 +314,10 @@ class TestHardwarePRNG:
 
     def test_fanout_two_converges_faster(self):
         n = 1 << 18
-        l1, i1 = compiled_until_fused(n, seed=1, fanout=1, max_rounds=64)
-        l2, i2 = compiled_until_fused(n, seed=1, fanout=2, max_rounds=64)
-        r1 = int(l1(i1).round)
-        r2 = int(l2(i2).round)
+        l1, i1 = compiled_until_fused(n, fanout=1, max_rounds=64)
+        l2, i2 = compiled_until_fused(n, fanout=2, max_rounds=64)
+        r1 = int(l1(i1, jnp.int32(1)).round)
+        r2 = int(l2(i2, jnp.int32(1)).round)
         assert r2 < r1
 
 
@@ -524,9 +523,9 @@ def test_compiled_until_fused_fault_semantics():
     from gossip_tpu.ops.pallas_round import NODES_PER_ROW
     n = 4096 * 8
     fault = FaultConfig(node_death_rate=0.3, seed=11)
-    loop, init = compiled_until_fused(n, seed=0, max_rounds=3,
+    loop, init = compiled_until_fused(n, max_rounds=3,
                                       interpret=True, fault=fault)
-    final = loop(init)
+    final = loop(init, jnp.int32(0))
     from gossip_tpu.models.state import alive_mask
     alive = np.asarray(alive_mask(fault, n, 0))
     inf = np.asarray(node_unpack(final.table, n))
@@ -537,9 +536,9 @@ def test_compiled_until_fused_fault_semantics():
 
     # drop everything: nothing ever spreads
     frozen = FaultConfig(drop_prob=1.0, seed=1)
-    loop2, init2 = compiled_until_fused(n, seed=0, max_rounds=3,
+    loop2, init2 = compiled_until_fused(n, max_rounds=3,
                                         interpret=True, fault=frozen)
-    final2 = loop2(init2)
+    final2 = loop2(init2, jnp.int32(0))
     assert float(coverage_node_packed(final2.table, n)) * n == 1.0
     assert int(final2.round) == 3
 
@@ -558,21 +557,21 @@ class TestHardwarePRNGFaultMasks:
             coverage_node_packed_alive, fault_masks_node_packed)
         n = 1 << 18
         fault = FaultConfig(node_death_rate=0.2, seed=7)
-        loop, init = compiled_until_fused(n, seed=3, max_rounds=64,
+        loop, init = compiled_until_fused(n, max_rounds=64,
                                           fault=fault)
-        final = loop(init)
+        final = loop(init, jnp.int32(3))
         alive = np.asarray(alive_mask(fault, n, 0))
         inf = np.asarray(node_unpack(final.table, n))
         assert not np.any(inf & ~alive)
         alive_tab, _ = fault_masks_node_packed(fault, n, 0)
         assert float(coverage_node_packed_alive(final.table,
                                                 alive_tab)) >= 0.99
-        l0, i0 = compiled_until_fused(n, seed=3, max_rounds=64)
-        r0 = int(l0(i0).round)
+        l0, i0 = compiled_until_fused(n, max_rounds=64)
+        r0 = int(l0(i0, jnp.int32(3)).round)
         drop = FaultConfig(drop_prob=0.5, seed=2)
-        ld, idr = compiled_until_fused(n, seed=3, max_rounds=64,
+        ld, idr = compiled_until_fused(n, max_rounds=64,
                                        fault=drop)
-        rd = int(ld(idr).round)
+        rd = int(ld(idr, jnp.int32(3)).round)
         assert rd > r0, (rd, r0)    # half the pulls dropped: more rounds
 
 
@@ -703,10 +702,10 @@ class TestHardwarePRNGFaultMasksMultirumor:
             compiled_until_fused_multirumor, word_unpack)
         n, r = 1 << 16, 8
         fault = FaultConfig(node_death_rate=0.2, drop_prob=0.1, seed=4)
-        loop, init = compiled_until_fused_multirumor(n, r, seed=5,
+        loop, init = compiled_until_fused_multirumor(n, r,
                                                      max_rounds=48,
                                                      fault=fault)
-        final = loop(init)
+        final = loop(init, jnp.int32(5))
         alive = np.asarray(alive_mask(fault, n, 0))
         seen = np.asarray(word_unpack(final.table, n, r))
         # dead nodes ACQUIRE nothing, but their own state stays put
@@ -821,9 +820,9 @@ def test_compiled_curve_fused_matches_stepwise():
         fault_masks_node_packed, fused_cov_fn, fused_mr_cov_fn)
     n, rounds = 4096 * 8, 3
     fault = FaultConfig(node_death_rate=0.25, seed=3)
-    scan, init = compiled_curve_fused(n, seed=0, max_rounds=rounds,
+    scan, init = compiled_curve_fused(n, max_rounds=rounds,
                                       interpret=True, fault=fault)
-    final, covs = scan(init)
+    final, covs = scan(init, jnp.int32(0))
     assert covs.shape == (rounds,) and int(final.round) == rounds
     # stepwise twin
     alive_tab, thresh = fault_masks_node_packed(fault, n, 0)
@@ -837,8 +836,8 @@ def test_compiled_curve_fused_matches_stepwise():
 
     n_mr, r = 128 * 16, 8
     scan_mr, init_mr = compiled_curve_fused_multirumor(
-        n_mr, r, seed=0, max_rounds=rounds, interpret=True)
-    final_mr, covs_mr = scan_mr(init_mr)
+        n_mr, r, max_rounds=rounds, interpret=True)
+    final_mr, covs_mr = scan_mr(init_mr, jnp.int32(0))
     assert covs_mr.shape == (rounds,)
     tab = init_multirumor_state(n_mr, r, 0).table
     cov_mr = fused_mr_cov_fn(n_mr, r)
@@ -848,3 +847,97 @@ def test_compiled_curve_fused_matches_stepwise():
         assert float(covs_mr[t]) == float(cov_mr(tab)), t
     np.testing.assert_array_equal(np.asarray(final_mr.table),
                                   np.asarray(tab))
+
+
+# ---------------------------------------------------------------------------
+# The seed is a runtime operand of the fused loops: one lowered program (one
+# compile-layer key, one executable) for every seed, and the same
+# trajectories as when the seed was a constant of the program.
+
+@pytest.fixture
+def staged_route(monkeypatch):
+    """Route multi-rumor tables to the staged big-table path.  The
+    round's jit cache is emptied on both sides: a cached trace keeps
+    the route it was traced with, whatever ``_mr_wants_big`` says now."""
+    from gossip_tpu.ops import pallas_round as PR
+    PR._fused_mr_round_jit.clear_cache()
+    monkeypatch.setattr(PR, "_mr_wants_big", lambda *a: True)
+    yield
+    PR._fused_mr_round_jit.clear_cache()
+
+
+def _fused_builder(kind, n):
+    from gossip_tpu.ops.pallas_round import (
+        compiled_curve_fused, compiled_curve_fused_multirumor)
+    if kind == "until":
+        return compiled_until_fused(n, max_rounds=4, interpret=True)
+    if kind == "curve":
+        return compiled_curve_fused(n, max_rounds=4, interpret=True)
+    if kind == "until_mr":
+        return compiled_until_fused_multirumor(n, 8, max_rounds=4,
+                                               interpret=True)
+    return compiled_curve_fused_multirumor(n, 8, max_rounds=4,
+                                           interpret=True)
+
+
+@pytest.mark.parametrize("kind,staged", [
+    ("until", False), ("curve", False), ("until_mr", False),
+    ("curve_mr", False), ("until_mr", True), ("curve_mr", True)])
+def test_fused_loop_lowering_is_seed_free(kind, staged, request):
+    """Two seeds lower the same program text: the seed is an operand,
+    so the compile layer's key (the lowered text) and its executable
+    serve a whole seed study."""
+    if staged:
+        request.getfixturevalue("staged_route")
+    loop, init = _fused_builder(kind, 4096)
+    texts = {loop.lower(init, jnp.int32(s)).as_text()
+             for s in (3, 2**31 - 2)}
+    assert len(texts) == 1
+
+
+def _baked_seed_loop(n, rumors, seed, max_rounds, target=0.99):
+    """The multi-rumor until loop as it was built while the seed was a
+    Python constant of the program (no fault masks)."""
+    from gossip_tpu.ops.pallas_round import fused_mr_cov_fn
+    cov = fused_mr_cov_fn(n, rumors)
+    target = jnp.float32(target)
+
+    def step(st):
+        tab = fused_multirumor_pull_round(st.table, seed, st.round, n, 1,
+                                          True)
+        return FusedState(table=tab, round=st.round + 1,
+                          msgs=st.msgs + 2.0 * n)
+
+    @jax.jit
+    def loop(st):
+        return jax.lax.while_loop(
+            lambda s: (cov(s.table) < target) & (s.round < max_rounds),
+            step, st)
+    return loop
+
+
+@pytest.mark.parametrize("seed", [2, 918_273_645, 2**31 - 2])
+def test_seed_operand_loop_bitwise_matches_baked_seed(seed, staged_route):
+    """On the staged big-table path the seed drives the threefry shift
+    bits even off the chip: the operand loop's final table, round and
+    msgs equal the baked-seed loop's bit for bit, and the seed does
+    reach the trajectory (another seed ends elsewhere)."""
+    n, r, rounds = 4096, 8, 6
+    loop, _ = compiled_until_fused_multirumor(n, r, max_rounds=rounds,
+                                              interpret=True)
+    got = loop(init_multirumor_state(n, r), jnp.int32(seed))
+    baked = _baked_seed_loop(n, r, seed, rounds)
+    want = baked(init_multirumor_state(n, r))
+    np.testing.assert_array_equal(np.asarray(got.table),
+                                  np.asarray(want.table))
+    assert int(got.round) == int(want.round)
+    assert (np.asarray(got.msgs).tobytes()
+            == np.asarray(want.msgs).tobytes())
+    other = loop(init_multirumor_state(n, r), jnp.int32(seed ^ 1))
+    assert not np.array_equal(np.asarray(other.table),
+                              np.asarray(got.table))
+    # the baked form really was a program per seed
+    init = init_multirumor_state(n, r)
+    assert (baked.lower(init).as_text()
+            != _baked_seed_loop(n, r, seed ^ 1, rounds).lower(init)
+            .as_text())

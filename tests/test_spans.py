@@ -186,7 +186,7 @@ def test_fused_staged_reference_loop_carries_the_device_scopes(
     # the staged big-table path (the 10M x 8-rumor route) at a small n
     monkeypatch.setattr(PR, "_mr_wants_big", lambda table_bytes, f: True)
     loop, init = PR.compiled_until_fused_multirumor(
-        4096, 8, seed=11, max_rounds=8, interpret=True)
-    text = loop.lower(init).compile().as_text()
+        4096, 8, max_rounds=8, interpret=True)
+    text = loop.lower(init, jnp.int32(11)).compile().as_text()
     for scope in SCOPES:
         assert f"/{scope}/" in text, scope
